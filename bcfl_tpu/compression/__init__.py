@@ -14,6 +14,7 @@ from bcfl_tpu.compression.codecs import (
     corrupt_payload,
     decode_tree,
     encode_tree,
+    kernel_plan,
     payload_nbytes,
     roundtrip,
     wire_format,
@@ -28,6 +29,7 @@ __all__ = [
     "corrupt_payload",
     "decode_tree",
     "encode_tree",
+    "kernel_plan",
     "payload_nbytes",
     "roundtrip",
     "wire_format",

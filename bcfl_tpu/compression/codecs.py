@@ -58,7 +58,8 @@ Tree = Any
 KINDS = ("none", "int8", "topk", "int8+topk")
 
 #: kernel impl selection for the codec hot loop (PERF.md "Custom kernels"):
-#: "auto" = Pallas on TPU / XLA elsewhere, or force either. Every impl
+#: "auto" = Pallas where one TPU chip is visible / XLA elsewhere
+#: (registry.pallas_by_default), or force either. Every impl
 #: produces byte-identical payloads (the registry's declared parity for
 #: the codec ops), so this NEVER appears in :func:`wire_format`.
 KERNEL_IMPLS = registry.IMPLS
@@ -84,10 +85,11 @@ class CompressionConfig:
     stochastic: bool = True
     # carry the per-client compression error into the next round's encode
     error_feedback: bool = True
-    # codec kernel impl: "auto" (Pallas on TPU, XLA elsewhere), "xla", or
-    # "pallas" (interpret mode off-TPU). Payload bytes are identical under
-    # every value — deliberately NOT part of wire_format(), so a resume
-    # may switch impls freely
+    # codec kernel impl: "auto" (Pallas where one TPU chip is visible, XLA
+    # elsewhere), "xla", or "pallas" (interpret mode off-TPU; refused at
+    # lowering inside a multi-chip GSPMD program). Payload bytes are
+    # identical under every value — deliberately NOT part of
+    # wire_format(), so a resume may switch impls freely
     kernel_impl: str = "auto"
 
     def __post_init__(self):
@@ -215,17 +217,13 @@ def encode_tree_unfused(comp: CompressionConfig, delta: Tree, key) -> dict:
 
 
 def _run_op(name: str, impl: str, *args, **kwargs):
-    """Resolve a codec kernel op through the harness and run it. A Pallas
-    impl that declines the shape (``NotImplementedError`` — e.g. a top-k
-    row wider than the single-block VMEM budget) degrades to the XLA
-    reference for that group: the declared parity is bit-identical, so the
-    fallback is invisible on the wire."""
-    fn, resolved = registry.resolve(name, impl)
-    if resolved == "pallas":
-        try:
-            return fn(*args, **kwargs)
-        except NotImplementedError:
-            return registry.get_op(name).xla(*args, **kwargs)
+    """Select a codec kernel op through the harness and run it. The choice
+    is made before the call, from the impl request, the backend and the
+    op's static shape predicate (a top-k row wider than the single-block
+    VMEM budget is served by the XLA reference — the declared parity is
+    bit-identical, so the wire never sees which impl ran). A kernel that
+    fails after being selected is an error, not a fallback."""
+    fn, _ = registry.select(name, impl, *args, **kwargs)
     return fn(*args, **kwargs)
 
 
@@ -356,6 +354,42 @@ def encode_tree(comp: CompressionConfig, delta: Tree, key) -> dict:
             out[p] = {"q": q, "s": s}
         return out
     raise ValueError(f"unknown compression kind {comp.kind!r}")
+
+
+def kernel_plan(comp: CompressionConfig, template: Tree,
+                num_clients: int) -> dict:
+    """Which kernel impl :func:`encode_tree` selects for each leaf group,
+    from shapes alone (no trace, no device): ``{op: {group: impl}}`` with
+    the groups exactly as the fused encode forms them — top-k rows grouped
+    by flattened width, int8 grids by chunk size. ``template`` is the
+    unstacked trainable tree (shapes only are read)."""
+    C = num_clients
+    widths = [int(leaf.size) for leaf in jax.tree.leaves(template)]
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    plan: dict = {}
+    int8_groups: dict = {}  # chunk -> total chunk-grid rows
+    if comp.kind in ("topk", "int8+topk"):
+        by_n: dict = {}
+        for n in widths:
+            by_n[n] = by_n.get(n, 0) + 1
+        topk = plan["topk_select"] = {}
+        for n, leaves in sorted(by_n.items()):
+            k = _leaf_k(comp, n)
+            _, topk[f"n={n} k={k} x{leaves}"] = registry.select(
+                "topk_select", comp.kernel_impl, f32(leaves * C, n), k=k)
+            if comp.kind == "int8+topk":
+                ck = min(comp.chunk, k)
+                int8_groups[ck] = int8_groups.get(ck, 0) + leaves * -(-k // ck)
+    elif comp.kind == "int8":
+        int8_groups[comp.chunk] = sum(-(-n // comp.chunk) for n in widths)
+    if int8_groups:
+        int8 = plan["int8_quantize"] = {}
+        for ck, m in sorted(int8_groups.items()):
+            g = f32(C, m, ck)
+            _, int8[f"chunk={ck} M={m}"] = registry.select(
+                "int8_quantize", comp.kernel_impl, g,
+                g if comp.stochastic else None, stochastic=comp.stochastic)
+    return plan
 
 
 def decode_tree(comp: CompressionConfig, payload: dict, like: Tree) -> Tree:

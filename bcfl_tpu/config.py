@@ -721,8 +721,7 @@ class FedConfig:
     # fuse up to this many federated rounds into ONE XLA dispatch when the
     # host isn't needed between them (sync server FedAvg or sync parallel
     # serverless gossip — not faithful mode; no ledger, no anomaly filter) —
-    # amortizes dispatch/transfer overhead, which dominates on tunnelled or
-    # high-latency hosts. Chunks never cross an eval or checkpoint boundary,
+    # amortizes the per-dispatch host round trip. Chunks never cross an eval or checkpoint boundary,
     # so observable cadence is unchanged.
     rounds_per_dispatch: int = 1
     # True  = example-weighted FedAvg (Flower's aggregate, server mode)

@@ -7,6 +7,11 @@ never wedges it. Every spawned process is tracked in a module-level
 registry with an ``atexit`` hook (and the test conftest calls
 :func:`reap_all` at session teardown), so an interrupted supervisor cannot
 leave orphan peers burning CPU behind a CI job.
+
+One process owns a chip. The supervisor therefore never initializes a jax
+backend, and on platform ``tpu`` every peer is pinned to one chip of its
+own through the chip-visibility environment libtpu reads
+(:func:`_peer_env`); more peers than chips is refused at launch.
 """
 
 from __future__ import annotations
@@ -59,7 +64,41 @@ def free_ports(n: int, host: str = "127.0.0.1") -> List[int]:
     return ports
 
 
-def _peer_env(platform: Optional[str]) -> Dict[str, str]:
+def resolved_platform(platform: Optional[str]) -> Optional[str]:
+    """The platform the peers will run on, as far as the supervisor can
+    tell without touching jax: the explicit request, else the first entry
+    of ``JAX_PLATFORMS``; None when neither says."""
+    if platform:
+        return platform
+    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    return first or None
+
+
+def _backend_initialized() -> bool:
+    """Has THIS process initialized a jax backend? False without importing
+    jax when nothing else has."""
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    return bool(bridge is not None and bridge.backends_are_initialized())
+
+
+def probe_devices() -> tuple:
+    """``(platform, device_count)`` as a fresh interpreter's jax reports
+    them. Runs in a child that exits before any peer starts, so the
+    supervisor itself never holds a chip."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.devices(); print(d[0].platform, len(d))"],
+        capture_output=True, text=True, timeout=300)
+    if out.returncode != 0:
+        raise RuntimeError(
+            f"device probe failed (rc={out.returncode}): "
+            f"{out.stderr.strip()[-800:]}")
+    platform, count = out.stdout.split()[-2:]
+    return platform, int(count)
+
+
+def _peer_env(platform: Optional[str],
+              chip: Optional[int] = None) -> Dict[str, str]:
     env = dict(os.environ)
     # the peers build their own single-host meshes: the test conftest's
     # 8-virtual-device XLA flag must not leak in (it would 8x every compile
@@ -71,13 +110,21 @@ def _peer_env(platform: Optional[str]) -> Dict[str, str]:
             if "xla_force_host_platform_device_count" not in f)
     if platform:
         env["JAX_PLATFORMS"] = platform
+    if chip is not None:
+        # one chip per process (libtpu 0.0.34, checked on a 2x2 v5e host —
+        # PERF.md "Bring-up"): the process sees exactly this chip, as a
+        # 1x1x1 topology of its own, under device id 0
+        env["TPU_VISIBLE_CHIPS"] = str(chip)
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+        env["TPU_PROCESS_BOUNDS"] = "1,1,1"
     return env
 
 
 def spawn_peer(cfg_path: str, peer_id: int, ports: List[int], run_dir: str,
                resume: bool = False, bootstrap: bool = False,
                platform: Optional[str] = None,
-               repo_root: Optional[str] = None) -> subprocess.Popen:
+               repo_root: Optional[str] = None,
+               chip: Optional[int] = None) -> subprocess.Popen:
     log_path = os.path.join(run_dir, f"peer{peer_id}.log")
     cmd = [sys.executable, "-m", "bcfl_tpu.dist",
            "--config", cfg_path, "--peer-id", str(peer_id),
@@ -92,7 +139,7 @@ def spawn_peer(cfg_path: str, peer_id: int, ports: List[int], run_dir: str,
     log = open(log_path, "ab")
     proc = subprocess.Popen(
         cmd, stdout=log, stderr=subprocess.STDOUT,
-        env=_peer_env(platform), cwd=repo_root or os.getcwd())
+        env=_peer_env(platform, chip), cwd=repo_root or os.getcwd())
     proc._bcfl_log = log  # keep the handle; closed at reap/collect
     _LIVE.add(proc)
     return proc
@@ -105,7 +152,8 @@ def run_dist(cfg, run_dir: str, deadline_s: Optional[float] = None,
              restart_delay_s: float = 2.0,
              restart_killed: bool = True,
              churn: Optional[Dict] = None,
-             limp: Optional[Dict] = None) -> Dict:
+             limp: Optional[Dict] = None,
+             tpu_chips: Optional[int] = None) -> Dict:
     """Run one full dist federation: spawn ``cfg.dist.peers`` peer
     processes, supervise them under a hard deadline, optionally SIGKILL
     ``kill_peer`` mid-run once its checkpoint has reached
@@ -156,13 +204,34 @@ def run_dist(cfg, run_dir: str, deadline_s: Optional[float] = None,
     land under ``result["limp"]``. Composes freely with ``churn`` as
     long as they target different peers.
 
+    On platform ``tpu`` peer ``p`` is pinned to chip ``p`` and a fleet
+    larger than the host's chip count raises ``ValueError`` before
+    anything is spawned — never a CPU fallback for the losers, never a
+    hang until the peer deadline. ``tpu_chips`` injects the chip count;
+    left None it comes from :func:`probe_devices`.
+
     Returns ``{"ok", "returncodes", "reports", "run_dir", ...}``; raises
     nothing on peer failure — the caller inspects the result (and the logs
     under ``run_dir``)."""
     from bcfl_tpu.dist.launch import cfg_to_json
 
-    os.makedirs(run_dir, exist_ok=True)
     n = cfg.dist.peers
+    plat = resolved_platform(platform)
+    if tpu_chips is None and plat in (None, "tpu"):
+        plat, count = probe_devices()
+        tpu_chips = count if plat == "tpu" else None
+    pin = plat == "tpu"
+    if pin and n > tpu_chips:
+        raise ValueError(
+            f"{n} dist peers need {n} TPU chips (one process owns a chip), "
+            f"but this host has {tpu_chips}: lower dist.peers or run the "
+            f"peers on platform='cpu'")
+    if pin and _backend_initialized():
+        raise RuntimeError(
+            "this process has initialized a jax backend and so holds the "
+            "TPU chips its dist peers need (one process owns a chip): "
+            "launch the fleet from a process that has not touched jax")
+    os.makedirs(run_dir, exist_ok=True)
     ports = ([cfg.dist.base_port + i for i in range(n)]
              if cfg.dist.base_port else free_ports(n, cfg.dist.host))
     cfg_path = os.path.join(run_dir, "config.json")
@@ -170,8 +239,11 @@ def run_dist(cfg, run_dir: str, deadline_s: Optional[float] = None,
         f.write(cfg_to_json(cfg))
     deadline_s = deadline_s or (cfg.dist.peer_deadline_s + 60.0)
 
-    procs = {p: spawn_peer(cfg_path, p, ports, run_dir, platform=platform)
-             for p in range(n)}
+    def spawn(p, **kw):
+        return spawn_peer(cfg_path, p, ports, run_dir, platform=platform,
+                          chip=p if pin else None, **kw)
+
+    procs = {p: spawn(p) for p in range(n)}
     rcs: Dict[int, Optional[int]] = {p: None for p in range(n)}
     killed_restarted = False
     kill_record = None
@@ -205,9 +277,7 @@ def run_dist(cfg, run_dir: str, deadline_s: Optional[float] = None,
                                "restarted": restart_killed}
                 if restart_killed:
                     time.sleep(restart_delay_s)
-                    procs[kill_peer] = spawn_peer(
-                        cfg_path, kill_peer, ports, run_dir, resume=True,
-                        platform=platform)
+                    procs[kill_peer] = spawn(kill_peer, resume=True)
                     rcs[kill_peer] = None
                 else:
                     rcs[kill_peer] = proc.returncode
@@ -257,10 +327,9 @@ def run_dist(cfg, run_dir: str, deadline_s: Optional[float] = None,
                         except (OSError, ValueError) as e:
                             damage = {"cls": cls, "error": str(e)}
                     time.sleep(float(churn.get("downtime_s", 2.0)))
-                    procs[cp] = spawn_peer(
-                        cfg_path, cp, ports, run_dir, resume=True,
-                        bootstrap=bool(churn.get("bootstrap")),
-                        platform=platform)
+                    procs[cp] = spawn(
+                        cp, resume=True,
+                        bootstrap=bool(churn.get("bootstrap")))
                     churn_records.append(
                         {"peer": cp, "cycle": len(churn_records) + 1,
                          "killed_at_s": round(time.time() - t0, 3),
@@ -330,6 +399,9 @@ def run_dist(cfg, run_dir: str, deadline_s: Optional[float] = None,
 
     return {
         "ok": ok,
+        # one process owns a chip: a supervisor that had touched a backend
+        # would have been holding every chip its peers needed
+        "supervisor_backend_initialized": _backend_initialized(),
         "process_count": n,
         "returncodes": {str(p): rcs[p] for p in range(n)},
         "reports": reports,

@@ -216,6 +216,15 @@ class PeerRuntime:
 
         self.eng = FedEngine(_peer_engine_cfg(cfg, k))
         self._jax = jax
+        # which device this peer ran on, for its report. Under the harness's
+        # one-chip-per-process pin every peer sees ITS chip as device id 0;
+        # the chip's identity on the host is the pinned index
+        dev = self.eng.mesh.mesh.devices.flat[0]
+        self._device = {
+            "platform": dev.platform, "device_kind": dev.device_kind,
+            "id": int(dev.id), "count": len(jax.devices()),
+            "visible_chip": os.environ.get("TPU_VISIBLE_CHIPS"),
+        }
         if self.eng._comp is not None:
             self.eng._ef = self.eng.progs.ef_init(self.eng.trainable0)
 
@@ -2034,6 +2043,7 @@ class PeerRuntime:
             "peers": self.peers,
             "status": status,
             "pid": os.getpid(),
+            "device": self._device,
             "resumed": self._resumed,
             "final_version": int(self.version),
             "local_rounds": int(self.local_round),
@@ -2118,6 +2128,9 @@ def peer_main(argv=None) -> int:
     logging.basicConfig(
         level=logging.INFO,
         format=f"[peer {args.peer_id}] %(levelname)s %(message)s")
+    from bcfl_tpu.core.hostenv import compile_cache
+
+    compile_cache()
     if args.platform:
         import jax
 

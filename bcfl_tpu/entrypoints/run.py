@@ -23,6 +23,8 @@ def run(cfg: FedConfig, resume: bool = False, verbose: bool = True,
     if verbose:
         print("\n".join(_header(cfg)), flush=True)
     engine = FedEngine(cfg, fused_tamper=fused_tamper)
+    if verbose:
+        print(device_line(engine.mesh), flush=True)
     result = engine.run(resume=resume,
                         on_round=_print_round if verbose else None)
     if verbose:
@@ -41,6 +43,20 @@ def _header(cfg: FedConfig) -> list:
         f"mode={cfg.mode} sync={cfg.sync} {clients} "
         f"rounds={cfg.num_rounds} model={cfg.model} dataset={cfg.dataset}",
     ]
+
+
+def device_line(mesh) -> str:
+    """``devices=<used>/<visible> x <device_kind> (<platform>)`` plus the
+    mesh shape. ``client_mesh`` takes the largest divisor of the client
+    count that fits, so 10 clients on 4 chips use 2 of them: printed in
+    every run header, under-use is never silent."""
+    import jax
+
+    devs = mesh.mesh.devices
+    shape = ", ".join(f"{k}={v}" for k, v in mesh.mesh.shape.items())
+    return (f"devices={devs.size}/{jax.device_count()} x "
+            f"{devs.flat[0].device_kind} ({devs.flat[0].platform}) "
+            f"mesh=({shape}) clients_per_device={mesh.per_device}")
 
 
 def _round_line(r) -> str:

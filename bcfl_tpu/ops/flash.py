@@ -6,8 +6,11 @@ Two implementations behind one signature:
   blocks via ``lax.scan``. O(S) memory in the sequence instead of the O(S^2)
   score matrix; runs on any backend (and is the CPU-mesh test oracle).
 - :func:`flash_attention_pallas` — TPU Pallas kernel (see
-  ``/opt/skills/guides/pallas_guide.md``), used automatically on TPU backends
-  when shapes allow; falls back to the XLA version elsewhere.
+  ``/opt/skills/guides/pallas_guide.md``), selected where one TPU chip is
+  visible (``registry.pallas_by_default``) for the bias forms it takes
+  (:func:`pallas_supported`, decided from shapes before the call); the XLA
+  version serves everything else. A kernel failure is an error, never a
+  silent switch.
 
 Both support ``causal=True`` (decoder masking) computed from block indices —
 no dense ``[S, S]`` bias ever exists, which is what lets the Llama decoder
@@ -21,10 +24,8 @@ attention in :mod:`bcfl_tpu.parallel` composes it across chips.
 
 from __future__ import annotations
 
-import warnings
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -115,7 +116,15 @@ def flash_attention_pallas(q, k, v, bias=None, causal: bool = False,
     return _pl(q, k, v, bias, causal, block_q, block_k)
 
 
-_pallas_fallback_warned = False
+def pallas_supported(q, k, v, bias=None, **_) -> bool:
+    """Static predicate (``KernelOp.supports``): the Pallas kernel takes a
+    key-side bias only — None, ``[B, Sk]`` or ``[B, 1, 1, Sk]``. A dense
+    per-(head, query) bias is served by the XLA blockwise path."""
+    if bias is None:
+        return True
+    B, Sk = q.shape[0], k.shape[2]
+    return bias.shape in ((B, Sk), (B, 1, 1, Sk))
+
 
 # registry entry (PERF.md "Custom kernels"): flash is the harness's
 # tolerance-parity client — online-softmax reassociation makes the Pallas
@@ -132,37 +141,25 @@ FLASH_ATTENTION = registry.register_op(registry.KernelOp(
         {"label": "llama-decode-B1-S2048", "B": 1, "H": 8, "S": 2048,
          "D": 64},
     ),
+    supports=pallas_supported,
 ))
 
 
 def flash_attention(q, k, v, bias=None, causal: bool = False,
                     block_size: int = DEFAULT_BLOCK):
-    """Dispatch: Pallas on TPU when available, XLA blockwise elsewhere —
-    impl selection through the kernel registry (``resolve("auto")`` =
-    pallas iff the backend is a TPU), with the warn-once degradation kept
-    here: an unsupported shape/bias falls back to the XLA reference.
+    """Dispatch through the kernel registry: Pallas where ``auto`` selects
+    it (one visible TPU chip) for the bias forms the kernel takes, XLA
+    blockwise elsewhere. The choice is made here, from the backend and the
+    argument shapes; whichever impl is chosen either runs or raises.
 
     ``bias`` here is key-side only ([B, Sk] or [B, 1, 1, Sk]) so both paths
     stay O(S) in memory; use :func:`flash_attention_xla` directly for an
     arbitrary dense bias.
     """
-    global _pallas_fallback_warned
-    _, impl = registry.resolve("flash_attention", "auto")
+    _, impl = registry.select("flash_attention", "auto", q, k, v, bias)
     if impl == "pallas":
-        try:
-            # the module global (not the registry's captured callable), so
-            # tests can monkeypatch the kernel under the dispatcher
-            return flash_attention_pallas(q, k, v, bias, causal=causal)
-        except (ValueError, NotImplementedError, TypeError,
-                jax.errors.JaxRuntimeError) as e:
-            # Expected degradations only (unsupported shape/bias, lowering
-            # gap); anything else propagates. Warn ONCE so a silently slower
-            # fallback never hides a kernel regression.
-            if not _pallas_fallback_warned:
-                _pallas_fallback_warned = True
-                warnings.warn(
-                    f"pallas flash kernel unavailable ({e!r}); falling back "
-                    "to the XLA blockwise implementation",
-                    RuntimeWarning, stacklevel=2)
+        # the module global (not the registry's captured callable), so
+        # tests can monkeypatch the kernel under the dispatcher
+        return flash_attention_pallas(q, k, v, bias, causal=causal)
     return flash_attention_xla(q, k, v, bias, block_size=block_size,
                                causal=causal)

@@ -31,10 +31,10 @@ Block legalization and interpret-mode detection come from the shared
 harness (:mod:`bcfl_tpu.ops.registry`): blocks keep the (8, 128) Mosaic
 rule by using 128-multiple (or whole-dim) row blocks, and off-TPU the
 kernels run in interpret mode so CPU CI executes the exact kernel bodies.
-Oversized top-k rows (a single block must hold the whole row) raise
-``NotImplementedError`` and the codec falls back to the XLA reference for
-that group — payloads are bit-identical either way, so the fallback is
-invisible on the wire.
+Oversized top-k rows (a single block must hold the whole row) are turned
+away BEFORE the call by the static :func:`topk_supported` predicate the
+registry consults: that group is served by the XLA reference, and the
+resolved impl name says so. Payloads are bit-identical either way.
 
 Kernel playbook: ``/opt/skills/guides/pallas_guide.md``.
 """
@@ -50,8 +50,10 @@ from jax.experimental import pallas as pl
 from bcfl_tpu.ops import registry
 
 #: one [br, N] row block (plus its abs/iota/onehot temporaries) must fit
-#: VMEM; rows wider than this fall back to the XLA reference top_k.
-#: ~6 live [br, N] f32/int32 buffers at br=8: 10 MB / (8*4*6) ≈ 54k lanes.
+#: VMEM; rows wider than this are served by the XLA reference top_k.
+#: ~6 live [br, N] f32/int32 buffers at br=8: 10 MB / (8*4*6) ≈ 54k lanes
+#: (the widest admitted row, 54613, compiles and runs on a v5e — PERF.md
+#: "Bring-up").
 TOPK_VMEM_BUDGET_BYTES = 10 << 20
 _TOPK_LIVE_BUFFERS = 6
 
@@ -164,20 +166,32 @@ def _topk_kernel(x_ref, val_ref, idx_ref, *, k: int, n: int):
     idx_ref[...] = idxs
 
 
-def _topk_select_pallas(x, *, k: int, block_r: int = 8):
+def _topk_block_rows(R: int) -> int:
+    return registry.legal_block(8, R, registry.SUBLANES)
+
+
+def topk_supported(x, *, k: int) -> bool:
+    """Static shape predicate (``KernelOp.supports``): does one row block
+    of ``x`` [R, N] fit the kernel's VMEM budget?"""
+    del k  # the loop count does not change what a block holds
+    R, N = x.shape
+    need = _topk_block_rows(R) * N * 4 * _TOPK_LIVE_BUFFERS
+    return need <= TOPK_VMEM_BUDGET_BYTES
+
+
+def _topk_select_pallas(x, *, k: int):
     """Row-blocked magnitude top-k: grid ``(R/br,)``, each block holds br
     whole rows (the N axis == array dim, always legal) and runs k rounds
     of first-occurrence argmax selection — O(k*N) VPU work with zero HBM
     round-trips per round, vs the full sort ``lax.top_k`` lowers to. Wins
     at adapter widths / small k; the microbench records where it does not."""
     R, N = x.shape
-    (br,) = registry.legal_block_sizes(((block_r, R, registry.SUBLANES),))
-    need = br * N * 4 * _TOPK_LIVE_BUFFERS
-    if need > TOPK_VMEM_BUDGET_BYTES:
-        raise NotImplementedError(
-            f"topk_select row block ({br}x{N}) needs ~{need >> 20} MB VMEM "
-            f"(> {TOPK_VMEM_BUDGET_BYTES >> 20} MB budget); caller should "
-            f"fall back to the XLA reference")
+    br = _topk_block_rows(R)
+    if not topk_supported(x, k=k):
+        raise ValueError(
+            f"topk_select row block ({br}x{N}) exceeds the "
+            f"{TOPK_VMEM_BUDGET_BYTES >> 20} MB VMEM budget; dispatch "
+            f"through registry.select, which consults topk_supported")
     val, idx = pl.pallas_call(
         functools.partial(_topk_kernel, k=k, n=N),
         grid=(pl.cdiv(R, br),),
@@ -250,6 +264,7 @@ TOPK_SELECT = registry.register_op(registry.KernelOp(
     pallas=_topk_select_pallas,
     parity="bit-identical",
     bench_shapes=TOPK_BENCH_SHAPES,
+    supports=topk_supported,
 ))
 
 INT8_DEQUANT = registry.register_op(registry.KernelOp(

@@ -12,14 +12,20 @@ kernel is one :class:`KernelOp` registration away:
   WITHOUT a Pallas impl serves its XLA reference under every ``impl``
   request ("reject nothing": selection degrades, it never errors).
 - **impl selection** (:func:`resolve`) — ``impl="xla" | "pallas" | "auto"``;
-  ``auto`` = Pallas on a real TPU backend, XLA elsewhere. An explicit
+  ``auto`` = Pallas in a process that sees ONE TPU chip, XLA elsewhere
+  (:func:`pallas_by_default`: Mosaic kernels cannot be partitioned
+  automatically, so the GSPMD round programs of a multi-chip mesh are
+  served by the references). An explicit
   ``"pallas"`` off-TPU runs the kernel body in interpret mode, so CI
   exercises the exact kernel everywhere (SURVEY.md §4's
-  distributed-without-hardware strategy applied to kernels).
-- **one interpret-mode knob** (:func:`interpret_mode`) —
-  ``BCFL_PALLAS_INTERPRET=1|0`` overrides the backend auto-detection for
-  EVERY kernel; the pre-harness per-kernel variable is honored as a
-  deprecated alias.
+  distributed-without-hardware strategy applied to kernels). The choice is
+  made BEFORE the call, from the request, the backend and the op's static
+  ``supports`` predicate over argument shapes: a kernel failure is an
+  error, never a silent switch to the reference at call time.
+- **one interpret-mode knob** (:func:`interpret_mode`) — kernels compile
+  on a TPU and interpret everywhere else; ``BCFL_PALLAS_INTERPRET=1`` on
+  a TPU is the one explicit debugging override, and asking for a compiled
+  kernel (``=0``) off-TPU is an error.
 - **block legalization** (:func:`legal_block` / :func:`legal_block_sizes`)
   — the (8, 128) Mosaic divisibility rule, generalized: real-TPU Mosaic
   requires the last two dims of every block to divide the dtype's
@@ -45,19 +51,15 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import warnings
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 
-#: the one interpret-mode knob (satellite of ISSUE 19): "1"/"true" forces
-#: interpret mode even on TPU (kernel-body debugging on silicon hosts),
-#: "0"/"false" forces compiled Mosaic lowering, unset = auto (interpret
-#: off-TPU so CPU CI runs the exact kernel bodies).
+#: the one interpret-mode knob: unset = compiled Mosaic on a TPU, interpret
+#: off-TPU (so CPU CI runs the exact kernel bodies). "1"/"true" forces
+#: interpret mode on a TPU (kernel-body debugging on silicon hosts);
+#: "0"/"false" off-TPU is an error — nothing there can compile a kernel.
 INTERPRET_ENV = "BCFL_PALLAS_INTERPRET"
-#: pre-harness spelling (pallas_flash's private toggle); honored with a
-#: DeprecationWarning so existing driver scripts keep working one cycle.
-INTERPRET_ENV_DEPRECATED = "BCFL_FLASH_INTERPRET"
 
 IMPLS = ("auto", "xla", "pallas")
 
@@ -68,22 +70,38 @@ SUBLANES = 8
 LANES = 128
 
 
+def on_tpu() -> bool:
+    """Is the default backend a TPU? Decides interpret mode."""
+    return jax.default_backend() == "tpu"
+
+
+def pallas_by_default() -> bool:
+    """Does ``auto`` select the Pallas impls? On a TPU, in a process that
+    sees exactly one device (a single chip, or a dist peer pinned to its
+    own). With more, the round programs are GSPMD programs over a
+    multi-chip mesh, and a ``pallas_call`` inside one is refused at
+    lowering (v5e 2x2, jax 0.9.0: "NotImplementedError: Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map.") — so there ``auto`` serves the XLA references. An explicit
+    ``"pallas"`` request still reaches the kernel, and fails loudly."""
+    return on_tpu() and jax.device_count() == 1
+
+
 def interpret_mode() -> bool:
-    """Should Pallas kernels run in interpret mode? One knob for every
-    kernel: ``BCFL_PALLAS_INTERPRET`` overrides, else interpret exactly
-    when the backend is not a TPU (same kernel bodies on the CPU mesh)."""
-    val = os.environ.get(INTERPRET_ENV)
-    if val is None:
-        old = os.environ.get(INTERPRET_ENV_DEPRECATED)
-        if old is not None:
-            warnings.warn(
-                f"{INTERPRET_ENV_DEPRECATED} is deprecated; use "
-                f"{INTERPRET_ENV} (one knob for every Pallas kernel)",
-                DeprecationWarning, stacklevel=2)
-            val = old
-    if val is not None and val != "":
-        return val.lower() not in ("0", "false", "no")
-    return jax.default_backend() != "tpu"
+    """Should Pallas kernels run in interpret mode? Compiled on a TPU,
+    interpreted elsewhere (same kernel bodies on the CPU mesh).
+    ``BCFL_PALLAS_INTERPRET=1`` forces interpret mode on a TPU; ``=0``
+    off-TPU raises instead of silently interpreting."""
+    val = os.environ.get(INTERPRET_ENV, "")
+    if val == "":
+        return not on_tpu()
+    want = val.lower() not in ("0", "false", "no")
+    if not want and not on_tpu():
+        raise RuntimeError(
+            f"{INTERPRET_ENV}={val} asks for compiled Pallas kernels, but "
+            f"the backend is {jax.default_backend()!r}: Mosaic only "
+            "compiles for a TPU (unset the variable to interpret)")
+    return want
 
 
 # ------------------------------------------------------------ block sizing
@@ -128,6 +146,11 @@ class KernelOp:
     parity: str = "bit-identical"
     #: static description of the microbench sweep, op-specific format
     bench_shapes: Tuple = ()
+    #: static predicate over the call's ``(*args, **kwargs)`` (shapes and
+    #: dtypes only — it runs at trace time): may the Pallas impl take this
+    #: call? None = every call. Where it says no, :func:`select` serves the
+    #: XLA reference and says so in the resolved impl name.
+    supports: Optional[Callable[..., bool]] = None
 
     @property
     def has_pallas(self) -> bool:
@@ -165,18 +188,31 @@ def list_ops() -> Tuple[str, ...]:
 def resolve(name: str, impl: str = "auto") -> Tuple[Callable, str]:
     """``(callable, resolved_impl)`` for an op under an impl request.
 
-    ``auto`` = pallas iff the op has a Pallas impl AND the backend is a
-    TPU; an explicit ``pallas`` request on an op with a Pallas impl runs
-    it even off-TPU (interpret mode — how tier-1 pins kernel parity). An
-    op without a Pallas impl serves its XLA reference under EVERY request:
-    selection never errors, payloads never change."""
+    ``auto`` = pallas iff the op has a Pallas impl AND
+    :func:`pallas_by_default`; an explicit ``pallas`` request on an op with
+    a Pallas impl runs it even off-TPU (interpret mode — how tier-1 pins
+    kernel parity). An op without a Pallas impl serves its XLA reference
+    under EVERY request: selection never errors, payloads never change."""
     op = get_op(name)
     if impl not in IMPLS:
         raise ValueError(f"unknown kernel impl {impl!r} for op {name!r} "
                          f"(one of {IMPLS})")
     if impl == "auto":
-        impl = ("pallas" if op.has_pallas
-                and jax.default_backend() == "tpu" else "xla")
+        impl = "pallas" if op.has_pallas and pallas_by_default() else "xla"
     if impl == "pallas" and not op.has_pallas:
         impl = "xla"
     return (op.pallas if impl == "pallas" else op.xla), impl
+
+
+def select(name: str, impl: str, *args, **kwargs) -> Tuple[Callable, str]:
+    """:func:`resolve` for one concrete call: the op's static ``supports``
+    predicate sees the call's shapes, and a Pallas impl that does not take
+    them is replaced by the XLA reference HERE, before anything runs. The
+    resolved name then reads ``"xla(unsupported shape)"`` so a log can say
+    which impl served which call."""
+    fn, resolved = resolve(name, impl)
+    op = get_op(name)
+    if (resolved == "pallas" and op.supports is not None
+            and not op.supports(*args, **kwargs)):
+        return op.xla, "xla(unsupported shape)"
+    return fn, resolved

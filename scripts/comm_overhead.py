@@ -52,6 +52,10 @@ def main(argv=None):
     ap.add_argument("--out", default="results/comm_overhead.json")
     args = ap.parse_args(argv)
 
+    from bcfl_tpu.core.hostenv import compile_cache
+
+    compile_cache()
+
     if args.platform:
         import jax
 

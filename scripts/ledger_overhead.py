@@ -29,6 +29,10 @@ def main(argv=None):
                     help="also measure the fused (rounds_per_dispatch) path")
     args = ap.parse_args(argv)
 
+    from bcfl_tpu.core.hostenv import compile_cache
+
+    compile_cache()
+
     if args.platform:
         import jax
 

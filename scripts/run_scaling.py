@@ -83,11 +83,14 @@ def main(argv=None):
 
     # multi-client CPU meshes on a loaded host abort when a device thread
     # lags >40s behind the XLA collective rendezvous; raise the timeouts
-    # BEFORE the backend initializes (same setup as run_results.py; the
-    # helper is version-gated — jaxlib 0.4.x FATALs on unknown XLA flags)
-    from bcfl_tpu.core.hostenv import raise_cpu_collective_timeouts
+    # BEFORE the backend initializes (same setup as run_results.py)
+    from bcfl_tpu.core.hostenv import (
+        compile_cache,
+        raise_cpu_collective_timeouts,
+    )
 
     raise_cpu_collective_timeouts()
+    compile_cache()
 
     if args.platform:
         import jax

@@ -2,7 +2,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from bcfl_tpu.core.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from bcfl_tpu.core import client_mesh

@@ -286,29 +286,6 @@ def test_program_cache_keys_on_codec_params():
     assert d is not a
 
 
-def test_codec_name_lists_stay_in_sync():
-    """bench.py and scripts/tpu_perf.py keep LITERAL copies of the codec
-    names (they must not import the package — and with it jax — before
-    their backend-init watchdogs are armed). A codec added to KINDS but
-    missing from a copy would be silently unselectable from that surface;
-    this pin turns the gap into a loud failure. The CLI and comm_overhead
-    import KINDS directly, so they cannot drift."""
-    import importlib.util
-    import os
-
-    from bcfl_tpu.compression import KINDS
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for rel, attr in (("bench.py", "COMPRESS_KINDS"),
-                      (os.path.join("scripts", "tpu_perf.py"),
-                       "COMPRESS_CODECS")):
-        spec = importlib.util.spec_from_file_location(
-            rel.replace(os.sep, "_"), os.path.join(root, rel))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        assert tuple(getattr(mod, attr)) == tuple(KINDS), rel
-
-
 def test_shard_map_impl_rejects_compression():
     from bcfl_tpu.core.mesh import client_mesh
     from bcfl_tpu.models import build

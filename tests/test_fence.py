@@ -1,8 +1,7 @@
-"""core.fence: the real completion fence for tunnelled backends.
+"""core.fence: the one device-completion seam (``jax.block_until_ready``).
 
-On CPU the readback is trivially correct; these tests pin the API contract
-(arbitrary trees: params, PRNG keys, empty, sharded) so the engine/bench
-call sites can rely on it everywhere block_until_ready used to be.
+These tests pin the API contract — arbitrary trees: params, PRNG keys,
+empty, host-only leaves, sharded — that the engine/bench call sites rely on.
 """
 
 import jax
@@ -37,8 +36,6 @@ def test_fence_int_and_bool():
 
 def test_fence_zero_size_leaf():
     fence(jnp.zeros((0, 4)))
-    # an empty FIRST leaf must not satisfy the fence (a 0-byte fetch waits
-    # for nothing); the readback has to fall through to a non-empty leaf
     fence({"a": jnp.zeros((0,)), "b": jax.jit(lambda: jnp.ones((8, 8)))()})
 
 
@@ -46,9 +43,7 @@ def test_fence_complex_dtype():
     fence(jnp.ones((4,), jnp.complex64))
 
 
-def test_fence_skips_host_leaves():
-    # a host numpy leaf must not satisfy the fence — the readback has to
-    # target a device (jax.Array) leaf
+def test_fence_mixed_host_and_device_leaves():
     import numpy as np
 
     fence({"step": np.asarray(3), "params": jax.jit(lambda: jnp.ones(4))()})

@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from bcfl_tpu.ops.attention import attention_bias_from_mask, dot_product_attention
 from bcfl_tpu.ops.flash import flash_attention_xla
@@ -134,35 +135,25 @@ def test_dense_bias_fallback_matches_dense_attention():
     np.testing.assert_allclose(np.asarray(flash), np.asarray(dense), atol=3e-5)
 
 
-def test_dispatcher_narrow_fallback_warns_once(monkeypatch):
-    """If the Pallas kernel raises an expected error on a TPU backend, the
-    dispatcher warns ONCE and falls back to XLA; unexpected errors propagate."""
-    import warnings as _warnings
+def test_dispatcher_selects_before_the_call_and_never_falls_back(monkeypatch):
+    """Where ``auto`` means Pallas (one TPU chip), the kernel is chosen from shapes alone, before
+    the call: a dense per-(head, query) bias goes to the XLA path without
+    the kernel ever being entered, and a kernel that fails after being
+    chosen is an error — no call-time switch to the reference."""
+    from bcfl_tpu.ops import flash as flash_mod, registry
 
-    from bcfl_tpu.ops import flash as flash_mod
-
-    monkeypatch.setattr(flash_mod.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(flash_mod, "_pallas_fallback_warned", False)
+    monkeypatch.setattr(registry, "pallas_by_default", lambda: True)
 
     def boom(*a, **kw):
-        raise ValueError("unsupported bias")
+        raise ValueError("mosaic refused the kernel")
 
     monkeypatch.setattr(flash_mod, "flash_attention_pallas", boom)
     q = jnp.ones((1, 2, 64, 8), jnp.float32)
-    with _warnings.catch_warnings(record=True) as w:
-        _warnings.simplefilter("always")
-        out1 = flash_mod.flash_attention(q, q, q)
-        out2 = flash_mod.flash_attention(q, q, q)
-    assert sum(issubclass(x.category, RuntimeWarning) for x in w) == 1
-    np.testing.assert_allclose(np.asarray(out1), np.asarray(out2))
-
-    def unexpected(*a, **kw):
-        raise KeyError("bug in kernel")
-
-    monkeypatch.setattr(flash_mod, "flash_attention_pallas", unexpected)
-    try:
-        flash_mod.flash_attention(q, q, q)
-    except KeyError:
-        pass
-    else:
-        raise AssertionError("unexpected error type must propagate")
+    for key_bias in (None, jnp.zeros((1, 64)), jnp.zeros((1, 1, 1, 64))):
+        with pytest.raises(ValueError, match="mosaic refused"):
+            flash_mod.flash_attention(q, q, q, key_bias)
+    dense_bias = jnp.zeros((1, 2, 64, 64), jnp.float32)
+    out = flash_mod.flash_attention(q, q, q, dense_bias)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(flash_mod.flash_attention_xla(q, q, q, dense_bias)))

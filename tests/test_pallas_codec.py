@@ -15,11 +15,14 @@ always jitted; a receiver authenticates the bytes it received and never
 re-encodes, so cross-program identity is not a wire requirement.
 
 Harness contracts ride along: unknown ops reject loudly, ``kernel_impl``
-never reaches the wire format (resume may switch impls freely), the
-VMEM-budget decline degrades to the reference invisibly, and the
-interpret knob honors ``BCFL_PALLAS_INTERPRET`` with the old flash var as
-a deprecated alias.
+never reaches the wire format (resume may switch impls freely), a top-k
+row past the VMEM budget is routed to the reference by a static shape
+predicate before anything runs, and the interpret knob honors
+``BCFL_PALLAS_INTERPRET`` (asking for compiled kernels off-TPU is an
+error).
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -36,7 +39,7 @@ from bcfl_tpu.compression import (
 from bcfl_tpu.compression.codecs import encode_tree_unfused
 from bcfl_tpu.config import FedConfig, PartitionConfig
 from bcfl_tpu.fed.engine import FedEngine
-from bcfl_tpu.ops import pallas_codec, registry
+from bcfl_tpu.ops import flash, pallas_codec, registry  # noqa: F401 — registers flash_attention
 
 pytestmark = pytest.mark.compression
 
@@ -110,21 +113,63 @@ def test_every_impl_same_payload(impl):
         np.testing.assert_array_equal(np.asarray(xa), np.asarray(xb))
 
 
-def test_topk_vmem_decline_degrades_to_reference():
-    """A top-k row wider than the single-block VMEM budget makes the
-    Pallas kernel raise NotImplementedError BEFORE launch; the codec's
-    _run_op falls back to the XLA reference, bit-identically — the decline
-    is invisible on the wire."""
-    n = pallas_codec.TOPK_VMEM_BUDGET_BYTES  # any N past budget/(4*6*br)
-    x = jax.random.normal(jax.random.key(0), (8, 60_000), jnp.float32)
-    assert 8 * 60_000 * 4 * pallas_codec._TOPK_LIVE_BUFFERS > n
-    with pytest.raises(NotImplementedError, match="VMEM"):
-        pallas_codec._topk_select_pallas(x, k=5)
+def test_topk_width_predicate_agrees_with_dispatch(monkeypatch):
+    """The static top-k width predicate IS the dispatch rule: for rows on
+    both sides of the VMEM budget, ``_run_op`` calls the Pallas impl
+    exactly where ``topk_supported`` says yes and the XLA reference where
+    it says no, ``kernel_plan`` names the same impl per group, and the
+    reference-served group is bit-identical. Called past the predicate,
+    the kernel itself is a loud error, not a fallback."""
+    from bcfl_tpu.compression import kernel_plan
     from bcfl_tpu.compression.codecs import _run_op
+
+    widest = pallas_codec.TOPK_VMEM_BUDGET_BYTES // (
+        8 * 4 * pallas_codec._TOPK_LIVE_BUFFERS)
+    calls = []
+    op = registry.get_op("topk_select")
+    spy = lambda impl, fn: (  # noqa: E731
+        lambda *a, **kw: calls.append(impl) or fn(*a, **kw))
+    monkeypatch.setitem(registry._REGISTRY, "topk_select", dataclasses.replace(
+        op, xla=spy("xla", op.xla), pallas=spy("pallas", op.pallas)))
+    for n, fits in ((96, True), (widest, True), (widest + 1, False),
+                    (60_000, False)):
+        x = jax.ShapeDtypeStruct((8, n), jnp.float32)
+        assert pallas_codec.topk_supported(x, k=5) is fits
+        calls.clear()
+        jax.eval_shape(lambda y: _run_op("topk_select", "pallas", y, k=5), x)
+        assert calls == ["pallas" if fits else "xla"], (n, calls)
+        comp = CompressionConfig(kind="topk", topk_frac=5 / n,
+                                 kernel_impl="pallas")
+        (impl,) = kernel_plan(
+            comp, {"w": jax.ShapeDtypeStruct((n,), jnp.float32)},
+            num_clients=8)["topk_select"].values()
+        assert impl == ("pallas" if fits else "xla(unsupported shape)")
+    monkeypatch.undo()
+
+    x = jax.random.normal(jax.random.key(0), (8, 60_000), jnp.float32)
+    with pytest.raises(ValueError, match="VMEM"):
+        pallas_codec._topk_select_pallas(x, k=5)
     va, ia = jax.jit(lambda y: _run_op("topk_select", "xla", y, k=5))(x)
     vb, ib = jax.jit(lambda y: _run_op("topk_select", "pallas", y, k=5))(x)
     np.testing.assert_array_equal(np.asarray(va), np.asarray(vb))
     np.testing.assert_array_equal(np.asarray(ia), np.asarray(ib))
+
+
+def test_kernel_plan_matches_fused_encode_groups():
+    """``kernel_plan`` forms the groups exactly as ``encode_tree`` does:
+    one op call per planned group, for every codec kind."""
+    from bcfl_tpu.compression import kernel_plan
+
+    tree = _tree()
+    template = jax.tree.map(lambda x: x[0], tree)
+    for kind in ("int8", "topk", "int8+topk"):
+        comp = CompressionConfig(kind=kind, chunk=16, topk_frac=0.3)
+        plan = kernel_plan(comp, template, num_clients=4)
+        jaxpr = str(jax.make_jaxpr(
+            lambda d, k: encode_tree(comp, d, k))(tree, jax.random.key(7)))
+        assert jaxpr.count(" top_k[") == len(plan.get("topk_select", {}))
+        assert jaxpr.count("convert_element_type[new_dtype=int8") == len(
+            plan.get("int8_quantize", {}))
 
 
 # ----------------------------------------------------------------- harness
@@ -144,6 +189,19 @@ def test_registry_rejects_undeclared_op():
         CompressionConfig(kind="int8", kernel_impl="cuda")
 
 
+def test_auto_selects_pallas_only_where_one_tpu_chip_is_visible(monkeypatch):
+    """Mosaic kernels cannot be partitioned automatically, so ``auto``
+    means Pallas only in a process that sees ONE TPU device; on a
+    multi-chip host the GSPMD round programs get the XLA references. An
+    explicit "pallas" request is never rewritten."""
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    for count, want in ((1, "pallas"), (4, "xla")):
+        monkeypatch.setattr(registry.jax, "device_count", lambda c=count: c)
+        for name in ("int8_quantize", "topk_select", "flash_attention"):
+            assert registry.resolve(name, "auto")[1] == want
+            assert registry.resolve(name, "pallas")[1] == "pallas"
+
+
 def test_registry_degrades_pallas_to_xla_for_xla_only_ops():
     """Explicit kernel_impl="pallas" on an op with no Pallas impl serves
     the XLA reference (decode-side ops are registered XLA-only)."""
@@ -152,23 +210,24 @@ def test_registry_degrades_pallas_to_xla_for_xla_only_ops():
     assert fn is registry.get_op("int8_dequant").xla
     # auto off-TPU is XLA even when a Pallas impl exists
     _, resolved = registry.resolve("int8_quantize", "auto")
-    assert resolved == ("pallas" if jax.default_backend() == "tpu"
-                        else "xla")
+    assert resolved == ("pallas" if registry.pallas_by_default() else "xla")
 
 
-def test_interpret_knob_and_deprecated_alias(monkeypatch):
+def test_interpret_knob(monkeypatch):
     monkeypatch.delenv(registry.INTERPRET_ENV, raising=False)
-    monkeypatch.delenv(registry.INTERPRET_ENV_DEPRECATED, raising=False)
     # auto: interpret everywhere but on a real TPU backend
     assert registry.interpret_mode() == (jax.default_backend() != "tpu")
+    monkeypatch.setenv(registry.INTERPRET_ENV, "1")
+    assert registry.interpret_mode() is True
+    # a compiled kernel off-TPU is an error, not a silent interpret...
     monkeypatch.setenv(registry.INTERPRET_ENV, "0")
+    with pytest.raises(RuntimeError, match="compiled Pallas kernels"):
+        registry.interpret_mode()
+    # ...and on a TPU both values of the knob are honored
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
     assert registry.interpret_mode() is False
     monkeypatch.setenv(registry.INTERPRET_ENV, "1")
     assert registry.interpret_mode() is True
-    monkeypatch.delenv(registry.INTERPRET_ENV)
-    monkeypatch.setenv(registry.INTERPRET_ENV_DEPRECATED, "1")
-    with pytest.warns(DeprecationWarning, match="BCFL_PALLAS_INTERPRET"):
-        assert registry.interpret_mode() is True
 
 
 def test_legal_block_sizes():

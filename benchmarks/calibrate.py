@@ -1,0 +1,134 @@
+#!/usr/bin/env python3
+"""Readings for the limits of ``correct`` (PERF.md, section 2), in one
+process: for each seed the program's first rounds against the reference
+(the lower readings), and on the first ``--controls`` seeds the control (the
+reference in fp8, put in the program's place) and the faults the cell can
+have, planted in the reference put in the program's place (the upper
+readings); ``--control-only`` reads the control alone and builds no engine.
+Not part of a benchmark run; run it on the chip at the cell's own size when
+a limit has to be set, and with ``--plumbing`` for the tests' tiny sizes.
+Writes ``chiprun_out/calibrate-<cell>.json``."""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(HERE)
+sys.path.insert(0, ROOT)
+
+
+def readings(cell_name, seeds, controls, plumbing, out_dir, control_only=False):
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import compare, harness, traffic, weights
+    from benchmarks.reference import gate
+    from benchmarks.reference import train as ref_train
+
+    cell, sizes = harness.load_cell(cell_name, plumbing)
+    stated_p, control_p = harness.precisions(sizes)
+    n = cell["check"]["rounds"]
+    rows = []
+    for i, seed in enumerate(seeds):
+        t0 = time.time()
+        run = harness.Run(cell, sizes, seed, 0.0, False, plumbing, out_dir, time.time())
+        clients = cell["traffic"]["clients"]
+        want_mask = gate.expected_mask(cell.get("gate"), clients, seed).tolist()
+        if not control_only:
+            harness.setup_engine(run)
+            res, recs, _ = harness._drive(run, n)
+            first = [harness._rec_dict(r) for r in recs]
+            prog = weights.from_program(jax.device_get(res.trainable), sizes)
+            del res
+            harness.release(run)
+        else:
+            # the control's reading needs no program: the reference stands
+            # in its place, under the mask a sound run has
+            run.batches, run.n_ex = traffic.make(cell["traffic"], sizes["vocab_size"],
+                                                 sizes["num_labels"], seed)
+            first = [{"mask": want_mask, "auth": [1.0] * clients, "train_loss": 0.0}] * n
+        masks = [r["mask"] for r in first]
+        batches = jax.tree.map(jnp.asarray, run.batches)
+        start = weights.make(sizes, seed)
+        start_h = jax.device_get(start)
+
+        def ref(**kw):
+            losses, out, gn = ref_train.run_rounds(
+                start, sizes, sizes["training"], batches, seed, masks, run.n_ex, **kw)
+            return [float(x) for x in losses], jax.device_get(out), jax.device_get(gn)
+
+        def against(losses, params):
+            v, notes = compare.numbers(losses, ref_losses, params, ref_params, start_h,
+                                       ref_gn, first, True, len(first) * clients,
+                                       clients, 0, stated=stated, expected_mask=want_mask)
+            v = {k: x for k, x in v.items() if k.startswith(("loss_", "dparam_", "turn_"))}
+            v["worst_leaf"] = notes["dparam_worst_leaf"]
+            return v
+
+        ref_losses, ref_params, ref_gn = ref()
+        stated = ref(precision=stated_p)[1]
+        row = {"seed": seed, "masks": masks}
+        if not control_only:
+            row["program"] = against([r["train_loss"] for r in first], prog)
+            row["correct"] = compare.judge(
+                {k: v for k, v in row["program"].items() if k != "worst_leaf"},
+                {k: v for k, v in cell["limits"].items() if k in row["program"]})[1]
+        if i < controls:
+            row["control"] = against(*ref(precision=control_p)[:2])
+            if not control_only:
+                row["fault_half_batch"] = against(*ref(half_batch=True)[:2])
+                live = [c for c, m in enumerate(masks[0]) if m > 0]
+                if len(live) > 1:
+                    row["fault_client_left_out"] = against(*ref(drop_client=live[-1])[:2])
+        row["seconds"] = time.time() - t0
+        rows.append(row)
+        harness.log(json.dumps(row))
+    return rows
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", required=True, help="comma-separated")
+    ap.add_argument("--controls", type=int, default=3)
+    ap.add_argument("--control-only", action="store_true",
+                    help="the control's readings alone: no engine is built and no fault planted")
+    ap.add_argument("--plumbing", action="store_true")
+    args = ap.parse_args()
+    from benchmarks import harness
+
+    harness.place_compile_cache()
+    import jax
+
+    out_dir = os.path.join(ROOT, "bench_out", args.workload + ".calibrate")
+    os.makedirs(out_dir, exist_ok=True)
+    seeds = [int(s) for s in args.seeds.split(",")]
+    rows = readings(args.workload, seeds, args.controls, args.plumbing, out_dir,
+                    control_only=args.control_only)
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    path = os.path.join(ROOT, "chiprun_out", f"calibrate-{args.workload}"
+                        f"{'-plumbing' if args.plumbing else ''}.json")
+    with open(path, "w") as f:
+        json.dump({"workload": args.workload, "device": str(jax.devices()[0].device_kind),
+                   "plumbing": args.plumbing, "rows": rows}, f, indent=1)
+    # the summary: largest program reading, smallest control / fault reading
+    kinds = ("program", "control", "fault_half_batch", "fault_client_left_out")
+    keys = sorted({k for r in rows for kind in kinds for k in r.get(kind, {}) if k != "worst_leaf"})
+    for k in keys:
+        line = k + ":"
+        vals = [r["program"][k] for r in rows if "program" in r]
+        if vals:
+            line += f" program max {max(vals):.3g}"
+        for kind in kinds[1:]:
+            vals = [r[kind][k] for r in rows if kind in r]
+            if vals:
+                line += f" | {kind} min {min(vals):.3g}"
+        print(line, flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

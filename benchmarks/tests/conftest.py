@@ -1,0 +1,10 @@
+"""CPU tests of the benchmark's own code (not part of tier-1's tests/).
+Run: ``JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q``."""
+
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")

@@ -48,6 +48,7 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from bcfl_tpu.metrics.tracing import scope
 # importing pallas_codec registers the codec kernel ops (int8_quantize,
 # topk_select, int8_dequant, topk_scatter) with the kernel harness
 from bcfl_tpu.ops import pallas_codec  # noqa: F401
@@ -287,6 +288,7 @@ def _topk_parts_batched(ys, k: int, impl: str = "xla"):
             for i in range(L)]
 
 
+@scope("codec.encode")
 def encode_tree(comp: CompressionConfig, delta: Tree, key) -> dict:
     """Stacked [C, ...] f32 delta tree -> payload dict keyed by leaf path.
 
@@ -392,6 +394,7 @@ def kernel_plan(comp: CompressionConfig, template: Tree,
     return plan
 
 
+@scope("codec.decode")
 def decode_tree(comp: CompressionConfig, payload: dict, like: Tree) -> Tree:
     """payload -> stacked f32 delta tree shaped like ``like`` ([C, ...])."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(like)
@@ -439,6 +442,7 @@ def zero_residual(trainable: Tree, num_clients: int) -> Tree:
         lambda x: jnp.zeros((num_clients,) + x.shape, jnp.float32), trainable)
 
 
+@scope("transport")
 def corrupt_payload(payload: dict, scales: jnp.ndarray) -> dict:
     """Transport corruption of a compressed payload: add the per-client
     scale to every FLOAT part (quantization scales / top-k values). Integer

@@ -35,6 +35,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from bcfl_tpu.compression import CompressionConfig, codecs as cc
 from bcfl_tpu.core.mesh import ClientMesh
 from bcfl_tpu.ledger.fingerprint import client_fingerprint, tree_fingerprint
+from bcfl_tpu.metrics.tracing import scope
 from bcfl_tpu.models import lora as lora_lib
 from bcfl_tpu.parallel import gspmd
 from bcfl_tpu.parallel.collectives import gossip_mix, masked_weighted_mean
@@ -61,7 +62,8 @@ def _merge(trainable: Tree, frozen: Optional[Tree]) -> Tree:
     the frozen base."""
     if frozen is None:
         return trainable
-    return lora_lib.apply_lora(frozen, trainable)
+    with scope("lora_merge"):
+        return lora_lib.apply_lora(frozen, trainable)
 
 
 def make_loss_fn(model, task: str = "classification") -> Callable:
@@ -75,14 +77,17 @@ def make_loss_fn(model, task: str = "classification") -> Callable:
 
     def _forward(trainable, frozen, batch, rng):
         params = _merge(trainable, frozen)
-        return model.apply(
-            {"params": params}, batch["ids"], batch["mask"],
-            deterministic=rng is None,
-            rngs=None if rng is None else {"dropout": rng},
-        )
+        # under value_and_grad the backward pass of everything in here is
+        # named transpose(jvp(fed.forward))
+        with scope("forward"):
+            return model.apply(
+                {"params": params}, batch["ids"], batch["mask"],
+                deterministic=rng is None,
+                rngs=None if rng is None else {"dropout": rng},
+            )
 
-    def loss_cls(trainable, frozen, batch, rng):
-        logits = _forward(trainable, frozen, batch, rng)
+    @scope("loss")
+    def _loss_cls(logits, batch):
         labels = batch["labels"]
         ex = batch["example_mask"].astype(jnp.float32)
         per_ex = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
@@ -91,8 +96,11 @@ def make_loss_fn(model, task: str = "classification") -> Callable:
         correct = ((jnp.argmax(logits, -1) == labels).astype(jnp.float32) * ex).sum()
         return loss, (correct, ex.sum())
 
-    def loss_lm(trainable, frozen, batch, rng):
-        logits = _forward(trainable, frozen, batch, rng)  # [B, S, V]
+    def loss_cls(trainable, frozen, batch, rng):
+        return _loss_cls(_forward(trainable, frozen, batch, rng), batch)
+
+    @scope("loss")
+    def _loss_lm(logits, batch):  # logits [B, S, V]
         targets = batch["ids"][:, 1:]
         logits = logits[:, :-1]
         w = (batch["mask"][:, 1:].astype(jnp.float32)
@@ -104,6 +112,9 @@ def make_loss_fn(model, task: str = "classification") -> Callable:
         correct = ((jnp.argmax(logits, -1) == targets).astype(jnp.float32)
                    * w).sum()
         return loss, (correct, w.sum())
+
+    def loss_lm(trainable, frozen, batch, rng):
+        return _loss_lm(_forward(trainable, frozen, batch, rng), batch)
 
     if task == "classification":
         return loss_cls
@@ -181,7 +192,8 @@ def make_local_train(tx, loss_fn) -> Callable:
     (:mod:`bcfl_tpu.parallel.fed_tp`)."""
 
     def local_train(trainable, frozen, batches, rng):
-        opt_state = tx.init(trainable)
+        with scope("optimizer_init"):
+            opt_state = tx.init(trainable)
         steps = batches["ids"].shape[0]
         step_rngs = jax.random.split(rng, steps)
 
@@ -190,8 +202,9 @@ def make_local_train(tx, loss_fn) -> Callable:
             batch, r = xs
             (loss, (correct, n)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(t, frozen, batch, r)
-            updates, opt = tx.update(grads, opt, t)
-            t = optax.apply_updates(t, updates)
+            with scope("optimizer"):
+                updates, opt = tx.update(grads, opt, t)
+                t = optax.apply_updates(t, updates)
             return (t, opt), jnp.stack([loss * n, correct, n])
 
         (trainable, _), stats = lax.scan(
@@ -491,6 +504,10 @@ def _build_programs_dispatch(
     # ---- one client's local round: fresh opt state, scan over batches ----
     local_train = make_local_train(tx, loss_fn)
 
+    @scope("aggregate")
+    def agg(stacked_t, weights, fallback):
+        return masked_weighted_mean(stacked_t, weights, axis, fallback=fallback)
+
     # ---- server mode: everyone trains from the SAME global trainable ----
     # single source of truth for one FedAvg round; the per-round program and
     # the scanned multi-round fast path below both apply exactly this body
@@ -500,8 +517,7 @@ def _build_programs_dispatch(
 
         new_t, stats = jax.vmap(per_client)(batches, rngs)
         # all-masked round -> keep the round's starting params, don't zero them
-        avg = masked_weighted_mean(new_t, weights, axis, fallback=global_t)
-        return avg, stats
+        return agg(new_t, weights, global_t), stats
 
     server_round = jax.jit(
         shard_map(
@@ -514,6 +530,7 @@ def _build_programs_dispatch(
     )
 
     # ---- serverless mode: per-client params persist, ring gossip after ----
+    @scope("aggregate")
     def _mix(new_t, mask, fallback):
         """Post-train serverless aggregation. gossip_steps == 0 -> exact
         mask-weighted all-client mean, the reference-faithful serverless
@@ -705,8 +722,7 @@ def _build_programs_dispatch(
     # the rejected updates.
     collapse = jax.jit(
         shard_map(
-            lambda t, w, fallback: masked_weighted_mean(t, w, axis, fallback=fallback),
-            mesh=jmesh,
+            agg, mesh=jmesh,
             in_specs=(shard, shard, repl), out_specs=repl, check_vma=False,
         )
     )
@@ -798,9 +814,9 @@ def _build_programs_gspmd(
     # deltas), so which client trains at which rank never retraces
     rmask = (None if lora_ranks is None
              else lora_lib.rank_mask(lora_ranks))
-    agg = gspmd.make_aggregator(aggregator, aggregator_trim,
-                                hierarchical_groups=groups,
-                                rank_mask=rmask)
+    agg = scope("aggregate")(gspmd.make_aggregator(
+        aggregator, aggregator_trim, hierarchical_groups=groups,
+        rank_mask=rmask))
     tx = make_optimizer(optimizer, learning_rate, max_grad_norm)
     loss_fn = make_loss_fn(model, task)
     unstack = lambda r: _unstack_rng(r, prng_impl)  # noqa: E731
@@ -858,6 +874,7 @@ def _build_programs_gspmd(
         server_round = jax.jit(server_body_comp, donate_argnums=_don(0),
                                out_shardings=((repl, cl), cl))
 
+    @scope("transport")
     def _transport(new_t, c_row):
         """Simulated transport of a client-stacked update tree: the buffer
         that reaches aggregation is ``new_t + c_row`` (per-client scalar,
@@ -870,6 +887,7 @@ def _build_programs_gspmd(
             lambda x: x + c_row.reshape((-1,) + (1,) * (x.ndim - 1))
             .astype(x.dtype), new_t)
 
+    @scope("fingerprint")
     def _fp_auth(new_t, c_row):
         """(sent_t, fp_commit, fp_recv, auth): fingerprint the update before
         and after simulated transport and compare in-graph. ``auth`` [C] is
@@ -887,6 +905,7 @@ def _build_programs_gspmd(
         # stream never touches — identical on the per-round and fused paths
         return cc.codec_key(unstack(rngs))
 
+    @scope("codec.encode")
     def _compress_stage(new_t, ref_t, resid, rngs):
         """Sender side of one wire exchange: ``(payload, decoded, resid')``
         for ``delta = new_t - ref_t`` (+ the carried error-feedback
@@ -905,6 +924,7 @@ def _build_programs_gspmd(
         payload, dec, resid = cc.roundtrip(comp, delta, resid, _ckey(rngs))
         return _c(payload, cl), dec, _c(resid, cl)
 
+    @scope("codec.decode")
     def _recon(ref_t, dec, like_t):
         """Receiver-side reconstruction ``ref + decoded delta``, cast back to
         the param dtype — the stacked tree the aggregator/mix consumes."""
@@ -912,6 +932,7 @@ def _build_programs_gspmd(
             lambda g, d, n: (g.astype(jnp.float32) + d).astype(n.dtype),
             ref_t, dec, like_t), cl)
 
+    @scope("fingerprint")
     def _fp_auth_payload(payload, c_row):
         """Compressed twin of ``_fp_auth``: fingerprints are taken over the
         COMPRESSED payload (the bytes actually on the wire), transport
@@ -985,6 +1006,7 @@ def _build_programs_gspmd(
     server_rounds_fp = _make_server_rounds(static=False, with_fp=True)
     server_rounds_static_fp = _make_server_rounds(static=True, with_fp=True)
 
+    @scope("aggregate")
     def _mix_g(new_t, mask, fallback):
         # same semantics as the shard_map _mix (see its docstring); the
         # exact-mean path rides the configured aggregator
@@ -993,6 +1015,7 @@ def _build_programs_gspmd(
             return _exact_mean_spread(avg, new_t, mask)
         return gspmd.gossip_mix(new_t, mask, gossip_alpha, steps=gossip_steps)
 
+    @scope("aggregate")
     def _mix_g_recv(self_t, recv_t, mask, fallback):
         # transport-aware twin of _mix_g: neighbor/aggregate terms come from
         # the TRANSPORTED tree, the self-term (and a masked client's kept

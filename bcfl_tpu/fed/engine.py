@@ -57,6 +57,7 @@ device memory is bounded by the cohort, not the registry.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import logging
 import os
@@ -92,6 +93,7 @@ from bcfl_tpu.metrics import (
     RunMetrics,
     StepClock,
     model_size_gb,
+    scope,
     trace,
 )
 from bcfl_tpu.models import TextClassifier, lora as lora_lib
@@ -168,12 +170,17 @@ _tree_wsum = jax.jit(
 # float identity) — the same corruption model the fused *_fp programs apply
 # in-graph (client_step._transport), so per-round and fused chaos runs are
 # comparable
-_tree_corrupt = jax.jit(
+_tree_corrupt = jax.jit(scope("transport")(
     lambda t, s: jax.tree.map(
         lambda x: x + s.reshape((-1,) + (1,) * (x.ndim - 1)).astype(x.dtype),
-        t))
+        t)))
 
 logger = logging.getLogger(__name__)
+
+
+def _nbytes(*trees) -> int:
+    """Bytes of every array in ``trees`` (None leaves count nothing)."""
+    return sum(int(x.nbytes) for x in jax.tree.leaves(trees))
 
 
 class FedEngine:
@@ -502,6 +509,64 @@ class FedEngine:
             jnp.asarray, central_eval_batches(self.cache, cfg.batch_size,
                                               max_batches=cfg.max_eval_batches))
         self._static_batches = None  # cache when the partition is round-static
+        # the run's StepClock: a fresh one per ``run``; None until then, so
+        # the dist runtime's calls into the wire seam open no spans
+        self.clock: Optional[StepClock] = None
+
+    # ------------------------------------------------------------------ spans
+
+    def _span(self, name: str, **kw):
+        """A child span of whatever phase is open (``round_program/inputs``,
+        ``ledger/chain``, ...; OBSERVABILITY.md §7). No span may
+        add a synchronisation: ``wait`` wraps only a block the engine makes
+        anyway."""
+        if self.clock is None:
+            return contextlib.nullcontext({})
+        return self.clock.span(name, **kw)
+
+    @contextlib.contextmanager
+    def _enqueued(self, program: str):
+        """``with self._enqueued(name) as prog: out = prog(...)``: the call
+        into ``self.progs.<name>`` under an ``enqueue`` span that lasts until
+        the callable returns (the dispatch is asynchronous). ``compiled`` is
+        1 when the callable's jit cache grew during the call: which step
+        recompiled. The call is made from the round body's own frame, as
+        deep as the engine made it before there were spans: where JAX's
+        lowering sits on CPython's data stack decides seconds of a big
+        program's first call (PERF.md section 6, PR 25)."""
+        fn = getattr(self.progs, program)
+        size = getattr(fn, "_cache_size", None)
+        with self._span("enqueue", program=program) as counts:
+            before = size() if size is not None else 0
+            yield fn
+            if size is not None:
+                counts["compiled"] = int(size() > before)
+
+
+    def _ef_gather(self, ids) -> None:
+        """Cohort mode: the cohort's error-feedback rows onto the device
+        (under no phase: stream and profiler only)."""
+        with self._span("ef_gather") as counts:
+            self._ef = self.mesh.shard_clients(jax.tree.map(
+                jnp.asarray, self._ef_reg.gather(ids)))
+            counts["h2d_bytes"] = _nbytes(self._ef)
+
+    def _ef_scatter(self, ids) -> None:
+        """Cohort mode: the updated rows back into the registry's store."""
+        with self._span("ef_scatter") as counts:
+            self._ef_reg.scatter(ids, jax.device_get(self._ef))
+            counts["d2h_bytes"] = _nbytes(self._ef)
+
+    def _fetch(self, *arrays):
+        """Device results as numpy arrays: the ``wait`` is the block that
+        the ``np.asarray`` behind it would make anyway, the ``fetch`` the
+        copy to the host."""
+        with self._span("wait"):
+            jax.block_until_ready(arrays)
+        with self._span("fetch") as counts:
+            out = [np.asarray(a) for a in arrays]
+            counts["d2h_bytes"] = _nbytes(out)
+        return out
 
     # ------------------------------------------------------------------ utils
 
@@ -709,24 +774,39 @@ class FedEngine:
         # inside this phase's first blocking transfer and gets billed to
         # the ledger (observed: a "90% ledger" reading that was ~95%
         # training wait)
-        fence(stacked if sent is None else sent)
+        with self._span("wait"):
+            fence(stacked if sent is None else sent)
         with self.clock.phase("ledger"):
             if self.faults.host_tamper is not None:
-                host = jax.device_get(stacked)
-                for c in range(C):
-                    self.ledger.append(rnd, c,
-                                       jax.tree.map(lambda x: x[c], host))
-                return self._ledger_authenticate(rnd, host)
-            fp = np.asarray(self.progs.fingerprint(stacked))
-            self._ledger_commit_rows(rnd, kind, fp)
-            if sent is None or sent is stacked:
-                # the committed HBM buffer IS the aggregated one: re-running
-                # the fingerprint program would reproduce `fp` bit-for-bit
-                # (device arrays are immutable), so auth re-derives digests
-                # from it directly
-                return self._ledger_auth_rows(rnd, kind, fp)
-            fp_recv = np.asarray(self.progs.fingerprint(sent))
-            return self._ledger_auth_rows(rnd, kind, fp_recv)
+                with self._span("fetch") as counts:
+                    host = jax.device_get(stacked)
+                    counts["d2h_bytes"] = _nbytes(host)
+                with self._span("chain"):
+                    for c in range(C):
+                        self.ledger.append(
+                            rnd, c, jax.tree.map(lambda x: x[c], host))
+                    return self._ledger_authenticate(rnd, host)
+            fp = self._fingerprint("fingerprint", stacked)
+            with self._span("chain"):
+                self._ledger_commit_rows(rnd, kind, fp)
+                if sent is None or sent is stacked:
+                    # the committed HBM buffer IS the aggregated one:
+                    # re-running the fingerprint program would reproduce
+                    # `fp` bit-for-bit (device arrays are immutable), so
+                    # auth re-derives digests from it directly
+                    return self._ledger_auth_rows(rnd, kind, fp)
+            fp_recv = self._fingerprint("fingerprint", sent)
+            with self._span("chain"):
+                return self._ledger_auth_rows(rnd, kind, fp_recv)
+
+    def _fingerprint(self, program: str, tree) -> np.ndarray:
+        """``ledger/fingerprint``: the device's digest program, its wait and
+        the copy of its few floats to the host, apart from the host's
+        hashing (``ledger/chain``)."""
+        with self._span("fingerprint", program=program) as counts:
+            fp = np.asarray(getattr(self.progs, program)(tree))
+            counts["d2h_bytes"] = fp.nbytes
+        return fp
 
     # ------------------------------------------------------- fault utilities
 
@@ -763,23 +843,25 @@ class FedEngine:
             return ExchangeResult(sent=sent, recon=sent, auth=auth, fp=fp,
                                   wire_kind="stacked")
         if mode == "async":
-            payload, self._ef = self.progs.encode_deltas_async(
-                new_t, ref_t, self._ef, rngs)
+            with self._enqueued("encode_deltas_async") as prog:
+                payload, self._ef = prog(new_t, ref_t, self._ef, rngs)
             recon = None
         else:
-            enc = (self.progs.encode_deltas if mode == "global"
-                   else self.progs.encode_deltas_local)
-            payload, recon, self._ef = enc(new_t, ref_t, self._ef, rngs)
+            with self._enqueued("encode_deltas" if mode == "global"
+                                else "encode_deltas_local") as prog:
+                payload, recon, self._ef = prog(new_t, ref_t, self._ef, rngs)
         if scales is None:
             sent_p = payload
         else:
-            sent_p = self.progs.corrupt_payload(
-                payload, self.mesh.shard_clients(jnp.asarray(scales)))
+            with self._enqueued("corrupt_payload") as prog:
+                sent_p = prog(
+                    payload, self.mesh.shard_clients(jnp.asarray(scales)))
             if recon is not None:
                 # a corrupted wire yields a corrupted reconstruction —
                 # re-decode the TRANSPORTED payload (the clean-path recon
                 # came fused with the encode)
-                recon = self.progs.decode_recon(sent_p, ref_t, new_t)
+                with self._enqueued("decode_recon") as prog:
+                    recon = prog(sent_p, ref_t, new_t)
         auth = fp = None
         if self.ledger is not None:
             if commit:
@@ -842,17 +924,18 @@ class FedEngine:
         programs fed runtime masks/weights: zero per-round retraces."""
         cfg = self.cfg
         C = self.C
-        batches, n_ex = self._round_batches(rnd)
-        rngs = self._rngs(rnd)
+        batches, n_ex, rngs, scales = self._round_inputs(rnd)
         if stacked is None:
             # span entry from server mode: every client starts the span
             # from the last whole-mesh global
-            stacked = self.progs.broadcast(trainable)
+            with self._enqueued("broadcast") as prog:
+                stacked = prog(trainable)
         start = stacked
-        stacked, stats = self.progs.local_updates(
-            stacked, self.frozen, batches, rngs)
-        rec = self._stats_to_rec(rnd, stats)
-        scales = self._transport_scales(rnd)
+        with self._enqueued("local_updates") as prog:
+            stacked, stats = prog(stacked, self.frozen, batches, rngs)
+        stats, = self._fetch(stats)
+        with self._span("records"):
+            rec = self._stats_to_rec(rnd, stats)
         # wire exchange through the shared seam: the wire quantity is the
         # encoded delta vs the client's round-start params (mode="local")
         # when compression is on, the stacked tree itself otherwise
@@ -883,23 +966,23 @@ class FedEngine:
                     "round %d: partition component %d fully eliminated — "
                     "keeping its previous model", rnd, ci)
                 continue
-            comp_mean = self.progs.collapse(
-                agg_src, self.mesh.shard_clients(jnp.asarray(wc)), trainable)
+            with self._enqueued("collapse") as prog:
+                comp_mean = prog(agg_src, self._shard_mask(wc), trainable)
             if cfg.mode == "server":
                 pull = cm  # every member receives the component model
             else:
                 # serverless: masked clients keep their own carried state
                 pull = cm * (np.asarray(mask) > 0)
-            out = self.progs.adopt(
-                out, comp_mean, self.mesh.shard_clients(jnp.asarray(
-                    pull, jnp.float32)))
+            with self._enqueued("adopt") as prog:
+                out = prog(out, comp_mean, self._shard_mask(pull))
         # robust consensus ACROSS components (participation-weighted
         # collapse over the per-client component models): the span's
         # eval/checkpoint view and what the heal round reconciles onto
-        consensus = self.progs.collapse(
-            out, self.mesh.shard_clients(jnp.asarray(w)), trainable)
-        rec.partition = part_id.tolist()
-        self._note_degraded(rec, mask)
+        with self._enqueued("collapse") as prog:
+            consensus = prog(out, self._shard_mask(w), trainable)
+        with self._span("records"):
+            rec.partition = part_id.tolist()
+            self._note_degraded(rec, mask)
         return consensus, out, rec
 
     def _heal_partition(self, trainable, stacked, mask):
@@ -1000,7 +1083,7 @@ class FedEngine:
                            clients=self.C, rounds=cfg.num_rounds)
         status = "crashed"
         try:
-            with trace(self.cfg.profile_dir):
+            with trace(cfg.profile_dir):
                 out = self._run(resume, on_round)
             status = "ok"
             return out
@@ -1177,6 +1260,7 @@ class FedEngine:
                 # --resume on a fresh checkpoint dir must still crash, or
                 # the chaos experiment silently never happens
                 raise SimulatedCrash(rnd)
+            clock.round = rnd
             chunk = self._chunk_rounds(rnd)
             if chunk > 1:
                 t0 = time.time()
@@ -1187,21 +1271,31 @@ class FedEngine:
                     else:
                         stacked, trainable, recs = self._serverless_chunk(
                             rnd, stacked, trainable, chunk)
-                self._annotate_chunk(recs, time.time() - t0)
-                if self._eff_rank is not None and recs:
-                    # fused dispatch: only the chunk's FINAL global exists
-                    # host-side; the guard statistic lands on its record
-                    recs[-1].effective_rank = float(self._eff_rank(trainable))
-                last_rnd = rnd + chunk - 1
-                self._maybe_eval(last_rnd, recs[-1], trainable, stacked, clock)
-                metrics.rounds.extend(recs)
-                self._maybe_checkpoint(last_rnd, trainable, stacked)
-                for r in recs:
-                    telemetry.emit("round", round=r.round, wall_s=r.wall_s,
-                                   fused=True, degraded=r.degraded)
-                if on_round is not None:
+                # host work between phases: under no phase, so it reaches
+                # the stream and the profiler and stays out of summary().
+                # On a round where they are due, the eval phase and the
+                # checkpoint save run inside it as well
+                with clock.span("post_round"):
+                    self._annotate_chunk(recs, time.time() - t0)
+                    if self._eff_rank is not None and recs:
+                        # fused dispatch: only the chunk's FINAL global
+                        # exists host-side; the guard statistic lands on its
+                        # record
+                        recs[-1].effective_rank = float(
+                            self._eff_rank(trainable))
+                    last_rnd = rnd + chunk - 1
+                    self._maybe_eval(last_rnd, recs[-1], trainable, stacked,
+                                     clock)
+                    metrics.rounds.extend(recs)
+                    self._maybe_checkpoint(last_rnd, trainable, stacked)
                     for r in recs:
-                        on_round(r)
+                        telemetry.emit("round", round=r.round,
+                                       wall_s=r.wall_s, fused=True,
+                                       degraded=r.degraded)
+                if on_round is not None:
+                    with clock.span("on_round"):
+                        for r in recs:
+                            on_round(r)
                 rnd += chunk
                 continue
 
@@ -1223,7 +1317,8 @@ class FedEngine:
             ids = self._cohort_ids(rnd)
             comps = self.faults.partition_components(rnd)
             with clock.phase("control_plane"):
-                gate = self._participation(rnd, comps)
+                with clock.span("gate"):
+                    gate = self._participation(rnd, comps)
                 mask = gate["mask"].astype(np.float32)
                 # chaos dropout composes with the anomaly gate exactly like
                 # a second filter: the mesh never reshapes, dropped clients
@@ -1247,7 +1342,8 @@ class FedEngine:
                 # reduced vote weight (bcfl_tpu.reputation; registry-sized,
                 # cohort-sliced)
                 if self.reputation is not None:
-                    mask = mask * cohort_view(self.reputation.gate(), ids)
+                    with clock.span("reputation"):
+                        mask = mask * cohort_view(self.reputation.gate(), ids)
                 healed = False
                 if (comps is None and stacked is not None and rnd > 0
                         and self.faults.partition_components(rnd - 1)
@@ -1266,8 +1362,7 @@ class FedEngine:
                 # gather the cohort's error-feedback residual rows from the
                 # per-registry store (zeros for never-sampled clients) —
                 # the compiled codec programs see the usual [C, ...] carry
-                self._ef = self.mesh.shard_clients(jax.tree.map(
-                    jnp.asarray, self._ef_reg.gather(ids)))
+                self._ef_gather(ids)
             with clock.phase("round_program"):
                 if comps is not None:
                     trainable, stacked, rec = self._partitioned_round(
@@ -1287,58 +1382,59 @@ class FedEngine:
                 # scatter the updated residual rows back by registry id
                 # BEFORE eval/checkpoint, so the checkpointed store matches
                 # the uninterrupted run's at every boundary
-                self._ef_reg.scatter(ids, jax.device_get(self._ef))
+                self._ef_scatter(ids)
+            with clock.span("post_round"):
+                rec.mask = mask.tolist()
+                if ids is not None:
+                    rec.cohort = ids.tolist()
+                rec.anomalies = list(gate["anomalies"])
+                rec.healed = healed
+                if dropped is not None:
+                    rec.dropped = dropped
+                if alive is not None:
+                    rec.churn_alive = alive.tolist()
+                if delays is not None:
+                    rec.straggler_s = delays.tolist()
+                # info passing: during a partition the source informs only its
+                # own component; churned-out clients are not targets either
+                # (the source itself always stays in the restricted set — a
+                # departed source degenerates to informing whoever remains,
+                # which with everyone else gone is (0, 0), not a crash)
+                restrict = None
+                if comps is not None:
+                    restrict = list(next(
+                        c for c in comps if self.info_source in c))
+                if alive is not None:
+                    base = (restrict if restrict is not None
+                            else range(self.C))
+                    restrict = [c for c in base
+                                if alive[c] > 0 or c == self.info_source]
+                sync_t, async_t = self.graph.info_passing_time(
+                    0.0, source=self.info_source, anomalies=gate["anomalies"],
+                    extra_delay=delays,
+                    payload_bytes=self._comms_payload_bytes(),
+                    restrict=restrict,
+                )
+                rec.info_passing_sync_s = sync_t
+                rec.info_passing_async_s = async_t
+                rec.wall_s = time.time() - t0
+                if self._eff_rank is not None:
+                    rec.effective_rank = float(self._eff_rank(trainable))
 
-            rec.mask = mask.tolist()
-            if ids is not None:
-                rec.cohort = ids.tolist()
-            rec.anomalies = list(gate["anomalies"])
-            rec.healed = healed
-            if dropped is not None:
-                rec.dropped = dropped
-            if alive is not None:
-                rec.churn_alive = alive.tolist()
-            if delays is not None:
-                rec.straggler_s = delays.tolist()
-            # info passing: during a partition the source informs only its
-            # own component; churned-out clients are not targets either
-            # (the source itself always stays in the restricted set — a
-            # departed source degenerates to informing whoever remains,
-            # which with everyone else gone is (0, 0), not a crash)
-            restrict = None
-            if comps is not None:
-                restrict = list(next(
-                    c for c in comps if self.info_source in c))
-            if alive is not None:
-                base = (restrict if restrict is not None
-                        else range(self.C))
-                restrict = [c for c in base
-                            if alive[c] > 0 or c == self.info_source]
-            sync_t, async_t = self.graph.info_passing_time(
-                0.0, source=self.info_source, anomalies=gate["anomalies"],
-                extra_delay=delays,
-                payload_bytes=self._comms_payload_bytes(),
-                restrict=restrict,
-            )
-            rec.info_passing_sync_s = sync_t
-            rec.info_passing_async_s = async_t
-            rec.wall_s = time.time() - t0
-            if self._eff_rank is not None:
-                rec.effective_rank = float(self._eff_rank(trainable))
-
-            if self.reputation is not None:
-                # evidence folds in BEFORE eval/checkpoint so the
-                # checkpointed tracker state matches the uninterrupted
-                # run's at every checkpoint boundary
-                self._reputation_observe(rnd, rec, gate)
-            self._maybe_eval(rnd, rec, trainable, stacked, clock)
-            metrics.rounds.append(rec)
-            self._maybe_checkpoint(rnd, trainable, stacked)
-            telemetry.emit("round", round=rnd, wall_s=rec.wall_s,
-                           degraded=rec.degraded, healed=rec.healed,
-                           partitioned=rec.partition is not None)
+                if self.reputation is not None:
+                    # evidence folds in BEFORE eval/checkpoint so the
+                    # checkpointed tracker state matches the uninterrupted
+                    # run's at every checkpoint boundary
+                    self._reputation_observe(rnd, rec, gate)
+                self._maybe_eval(rnd, rec, trainable, stacked, clock)
+                metrics.rounds.append(rec)
+                self._maybe_checkpoint(rnd, trainable, stacked)
+                telemetry.emit("round", round=rnd, wall_s=rec.wall_s,
+                               degraded=rec.degraded, healed=rec.healed,
+                               partitioned=rec.partition is not None)
             if on_round is not None:
-                on_round(rec)
+                with clock.span("on_round"):
+                    on_round(rec)
             rnd += 1
 
         params = _merge(trainable, self.frozen)
@@ -1532,13 +1628,13 @@ class FedEngine:
         transport stage), then authenticate the POST-transport fingerprints
         against the chain. The two trees differ whenever transport corrupted
         an update (``fused_tamper``), so this auth can genuinely fail — and
-        the in-graph aggregation already excluded exactly those clients."""
-        fps_commit = np.asarray(fps_commit)  # blocks on the fused dispatch
-        fps_recv = np.asarray(fps_recv)
+        the in-graph aggregation already excluded exactly those clients.
+        Both arrive as host arrays (the caller's ``_fetch``): this phase is
+        the host's hashing alone."""
         # compressed fused rounds fingerprint the PAYLOAD (client_step
         # _fp_auth_payload), so the chain entry binds the payload structure
         kind = "stacked" if self._comp is None else "payload"
-        with self.clock.phase("ledger"):
+        with self.clock.phase("ledger"), self._span("chain"):
             for i in range(k):
                 self._ledger_commit_rows(rnd + i, kind, fps_commit[i])
             for i, rec in enumerate(recs):
@@ -1556,36 +1652,45 @@ class FedEngine:
                     corr[i] = np.asarray(row, np.float32)
         return self.mesh.shard_round_clients(jnp.asarray(corr))
 
+    def _server_chunk_inputs(self, rnd: int, k: int):
+        """``(program name, its arguments after the carry and the frozen
+        tree)`` for one fused server dispatch, staged under ``inputs``."""
+        cfg = self.cfg
+        with self._span("inputs") as counts:
+            fresh = self._static_batches is None
+            static, batches, rrngs, n_ex_list = self._chunk_inputs(rnd, k)
+            rweights = self.mesh.shard_round_clients(jnp.asarray(np.stack([
+                np.full((self.C,),
+                        n_ex if cfg.weighted_agg else 1.0, np.float32)
+                for n_ex in n_ex_list])))
+            corrupts = (self._chunk_corrupts(rnd, k)
+                        if self.ledger is not None else None)
+            counts["h2d_bytes"] = _nbytes(
+                batches if fresh else None, rrngs, rweights, corrupts)
+        name = "server_rounds_static" if static else "server_rounds"
+        if self.ledger is not None:
+            return name + "_fp", (batches, rweights, rrngs, corrupts)
+        return name, (batches, rweights, rrngs)
+
     def _server_chunk(self, rnd: int, trainable, k: int):
         """Run rounds [rnd, rnd+k) in ONE XLA dispatch via server_rounds."""
-        cfg = self.cfg
-        static, batches, rrngs, n_ex_list = self._chunk_inputs(rnd, k)
-        rweights = self.mesh.shard_round_clients(jnp.asarray(np.stack([
-            np.full((self.C,),
-                    n_ex if cfg.weighted_agg else 1.0, np.float32)
-            for n_ex in n_ex_list])))
+        name, inputs = self._server_chunk_inputs(rnd, k)
         # compressed programs carry (params, error-feedback residual)
         carry = trainable if self._comp is None else (trainable, self._ef)
-        if self.ledger is not None:
-            prog = (self.progs.server_rounds_static_fp if static
-                    else self.progs.server_rounds_fp)
-            carry, (stats, fpc, fpr, _auth) = prog(
-                carry, self.frozen, batches, rweights, rrngs,
-                self._chunk_corrupts(rnd, k))
-            if self._comp is not None:
-                carry, self._ef = carry
-            stats = np.asarray(stats)
-            recs = [self._stats_to_rec(rnd + i, stats[i]) for i in range(k)]
-            self._commit_chunk_fps(rnd, k, fpc, fpr, recs)
-            return carry, recs
-        prog = (self.progs.server_rounds_static if static
-                else self.progs.server_rounds)
-        carry, stats = prog(carry, self.frozen, batches, rweights, rrngs)
+        with self._enqueued(name) as prog:
+            carry, out = prog(carry, self.frozen, *inputs)
         if self._comp is not None:
             carry, self._ef = carry
-        stats = np.asarray(stats)  # [k, C, 3]
-        return carry, [self._stats_to_rec(rnd + i, stats[i])
-                       for i in range(k)]
+        if self.ledger is None:
+            stats, = self._fetch(out)  # [k, C, 3]
+            with self._span("records"):
+                return carry, [self._stats_to_rec(rnd + i, stats[i])
+                               for i in range(k)]
+        stats, fpc, fpr = self._fetch(*out[:3])  # out[3]: in-graph auth
+        with self._span("records"):
+            recs = [self._stats_to_rec(rnd + i, stats[i]) for i in range(k)]
+        self._commit_chunk_fps(rnd, k, fpc, fpr, recs)
+        return carry, recs
 
     def _serverless_chunk(self, rnd, stacked, prev_consensus, k):
         """Run gossip rounds [rnd, rnd+k) in ONE dispatch via gossip_rounds.
@@ -1596,22 +1701,26 @@ class FedEngine:
         consensus values it skips are unobservable (no eval inside a
         chunk)."""
         cfg = self.cfg
-        static, batches, rrngs, _ = self._chunk_inputs(rnd, k)
-        masks = self.mesh.shard_round_clients(
-            jnp.ones((k, self.C), jnp.float32))
-        fps = None
+        with self._span("inputs") as counts:
+            fresh = self._static_batches is None
+            static, batches, rrngs, _ = self._chunk_inputs(rnd, k)
+            masks = self.mesh.shard_round_clients(
+                jnp.ones((k, self.C), jnp.float32))
+            corrupts = (self._chunk_corrupts(rnd, k)
+                        if self.ledger is not None else None)
+            counts["h2d_bytes"] = _nbytes(
+                batches if fresh else None, rrngs, masks, corrupts)
+        fps = ()
         carry = stacked if self._comp is None else (stacked, self._ef)
+        name = "gossip_rounds_static" if static else "gossip_rounds"
         if self.ledger is not None:
-            prog = (self.progs.gossip_rounds_static_fp if static
-                    else self.progs.gossip_rounds_fp)
-            carry, (stats, fpc, fpr, _auth) = prog(
-                carry, self.frozen, batches, masks, rrngs,
-                self._chunk_corrupts(rnd, k))
+            with self._enqueued(name + "_fp") as prog:
+                carry, (stats, fpc, fpr, _auth) = prog(
+                    carry, self.frozen, batches, masks, rrngs, corrupts)
             fps = (fpc, fpr)
         else:
-            prog = (self.progs.gossip_rounds_static if static
-                    else self.progs.gossip_rounds)
-            carry, stats = prog(carry, self.frozen, batches, masks, rrngs)
+            with self._enqueued(name) as prog:
+                carry, stats = prog(carry, self.frozen, batches, masks, rrngs)
         if self._comp is None:
             stacked = carry
         else:
@@ -1628,12 +1737,13 @@ class FedEngine:
                 and (last + 1) % cfg.checkpoint_every == 0))
         consensus = prev_consensus
         if observed:
-            m = self.mesh.shard_clients(
-                jnp.ones((self.C,), jnp.float32))
-            consensus = self.progs.collapse(stacked, m, prev_consensus)
-        stats = np.asarray(stats)  # [k, C, 3]
-        recs = [self._stats_to_rec(rnd + i, stats[i]) for i in range(k)]
-        if fps is not None:
+            m = self._shard_mask(np.ones((self.C,), np.float32))
+            with self._enqueued("collapse") as prog:
+                consensus = prog(stacked, m, prev_consensus)
+        stats, *fps = self._fetch(stats, *fps)  # stats [k, C, 3]
+        with self._span("records"):
+            recs = [self._stats_to_rec(rnd + i, stats[i]) for i in range(k)]
+        if fps:
             self._commit_chunk_fps(rnd, k, fps[0], fps[1], recs)
         return stacked, consensus, recs
 
@@ -1689,31 +1799,58 @@ class FedEngine:
             raise ValueError(
                 f"non-finite aggregation weights at round mask={mask!r} "
                 f"n_ex={n_ex!r}")
-        return self.mesh.shard_clients(jnp.asarray(w, jnp.float32))
+        return self._shard_mask(w)
+
+    def _round_inputs(self, rnd):
+        """The round's batches, example counts, rngs and transport scales
+        under one ``inputs`` span (PERF.md's ``data`` phase)."""
+        with self._span("inputs") as counts:
+            fresh = self._static_batches is None
+            batches, n_ex = self._round_batches(rnd)
+            rngs = self._rngs(rnd)
+            scales = self._transport_scales(rnd)
+            counts["h2d_bytes"] = _nbytes(batches if fresh else None, rngs)
+        return batches, n_ex, rngs, scales
+
+    def _shard_mask(self, mask) -> jnp.ndarray:
+        """A per-client mask or weight row placed on the clients axis."""
+        with self._span("inputs") as counts:
+            m = self.mesh.shard_clients(jnp.asarray(mask, jnp.float32))
+            counts["h2d_bytes"] = _nbytes(m)
+        return m
+
+    def _round_record(self, rnd, stats, mask, auth=None) -> RoundRecord:
+        """``records``: the round's statistics (already on the host) as its
+        RoundRecord, with the ledger's auth mask and the degraded mark."""
+        with self._span("records"):
+            rec = self._stats_to_rec(rnd, stats)
+            if auth is not None:
+                rec.auth = auth.tolist()
+            self._note_degraded(rec, mask)
+        return rec
 
     def _server_round(self, rnd, trainable, mask):
-        batches, n_ex = self._round_batches(rnd)
-        rngs = self._rngs(rnd)
-        scales = self._transport_scales(rnd)
+        batches, n_ex, rngs, scales = self._round_inputs(rnd)
         if self.ledger is None and scales is None:
             w = self._weights(mask, n_ex)
             if self._comp is None:
-                trainable, stats = self.progs.server_round(
-                    trainable, self.frozen, batches, w, rngs)
+                with self._enqueued("server_round") as prog:
+                    trainable, stats = prog(
+                        trainable, self.frozen, batches, w, rngs)
             else:
                 # compressed carry: (params, error-feedback residual)
-                (trainable, self._ef), stats = self.progs.server_round(
-                    (trainable, self._ef), self.frozen, batches, w, rngs)
-            rec = self._stats_to_rec(rnd, stats)
-            self._note_degraded(rec, mask)
-            return trainable, rec
+                with self._enqueued("server_round") as prog:
+                    (trainable, self._ef), stats = prog(
+                        (trainable, self._ef), self.frozen, batches, w, rngs)
+            stats, = self._fetch(stats)
+            return trainable, self._round_record(rnd, stats, mask)
         # split-phase flow: train -> (ledger commit) -> transport ->
         # (ledger verify) -> aggregate; if every update is eliminated the
         # round keeps its starting params (collapse fallback). Without the
         # ledger a corrupted update reaches the aggregation rule — the
         # robust aggregators (cfg.aggregator) are the defense there.
-        stacked, stats = self.progs.client_updates(
-            trainable, self.frozen, batches, rngs)
+        with self._enqueued("client_updates") as prog:
+            stacked, stats = prog(trainable, self.frozen, batches, rngs)
         # the wire quantity is the compressed payload when a codec is on
         # (the ledger commits/authenticates ITS fingerprints, transport
         # corruption perturbs IT) and the stacked tree otherwise; either
@@ -1724,26 +1861,26 @@ class FedEngine:
         if auth is not None:
             mask = mask * auth
         w = self._weights(mask, n_ex)
-        trainable = self.progs.collapse(ex.recon, w, trainable)
-        rec = self._stats_to_rec(rnd, stats)
-        if auth is not None:
-            rec.auth = auth.tolist()
-        self._note_degraded(rec, mask)
-        return trainable, rec
+        with self._enqueued("collapse") as prog:
+            trainable = prog(ex.recon, w, trainable)
+        # no wait on ``collapse``: the next round's client_updates consumes
+        # its result while the host does its post-round work
+        stats, = self._fetch(stats)
+        return trainable, self._round_record(rnd, stats, mask, auth)
 
     def _serverless_round(self, rnd, stacked, prev_consensus, mask):
-        batches, n_ex = self._round_batches(rnd)
-        rngs = self._rngs(rnd)
-        m = self.mesh.shard_clients(jnp.asarray(mask, jnp.float32))
+        batches, n_ex, rngs, scales = self._round_inputs(rnd)
+        m = self._shard_mask(mask)
         auth = None
-        scales = self._transport_scales(rnd)
         if self.ledger is None and scales is None:
             if self._comp is None:
-                stacked, stats = self.progs.gossip_round(
-                    stacked, self.frozen, batches, m, rngs)
+                with self._enqueued("gossip_round") as prog:
+                    stacked, stats = prog(
+                        stacked, self.frozen, batches, m, rngs)
             else:
-                (stacked, self._ef), stats = self.progs.gossip_round(
-                    (stacked, self._ef), self.frozen, batches, m, rngs)
+                with self._enqueued("gossip_round") as prog:
+                    (stacked, self._ef), stats = prog(
+                        (stacked, self._ef), self.frozen, batches, m, rngs)
         else:
             # split-phase: peers ship their update (the encoded delta vs
             # their own round-start params under a codec, the stacked tree
@@ -1752,14 +1889,14 @@ class FedEngine:
             # its honest post-train tree (mix_recv). An untouched wire
             # (clean, uncompressed) keeps the one-buffer mix_only path.
             start = stacked  # pre-train params: what an all-rejected round keeps
-            stacked, stats = self.progs.local_updates(
-                stacked, self.frozen, batches, rngs)
+            with self._enqueued("local_updates") as prog:
+                stacked, stats = prog(stacked, self.frozen, batches, rngs)
             ex = self._exchange_updates(rnd, stacked, start, rngs, scales,
                                         mode="local")
             auth = ex.auth
             if auth is not None:
                 mask = mask * auth
-                m = self.mesh.shard_clients(jnp.asarray(mask, jnp.float32))
+                m = self._shard_mask(mask)
             if ex.recon is not stacked:
                 # corruption/codec reconstruction poisons only the RECEIVED
                 # copies: neighbor and aggregate terms come from the
@@ -1768,16 +1905,16 @@ class FedEngine:
                 # configs whose impl has no mix_recv, so this cannot
                 # silently fall through to a mix that rewrites the sender's
                 # state with the corruption)
-                stacked = self.progs.mix_recv(stacked, ex.recon, m, start)
+                with self._enqueued("mix_recv") as prog:
+                    stacked = prog(stacked, ex.recon, m, start)
             else:
-                stacked = self.progs.mix_only(stacked, m, start)
+                with self._enqueued("mix_only") as prog:
+                    stacked = prog(stacked, m, start)
         # consensus view for eval/checkpoint (mask-weighted aggregation)
-        consensus = self.progs.collapse(stacked, m, prev_consensus)
-        rec = self._stats_to_rec(rnd, stats)
-        if auth is not None:
-            rec.auth = auth.tolist()
-        self._note_degraded(rec, mask)
-        return stacked, consensus, rec
+        with self._enqueued("collapse") as prog:
+            consensus = prog(stacked, m, prev_consensus)
+        stats, = self._fetch(stats)
+        return stacked, consensus, self._round_record(rnd, stats, mask, auth)
 
     def _faithful_round(self, rnd, trainable, mask):
         """Reference-exact serverless semantics: clients sequentially mutate a
@@ -1789,9 +1926,10 @@ class FedEngine:
         exactly as in the parallel paths. An all-excluded round keeps the
         round's starting params instead of zeroing the model."""
         cfg = self.cfg
-        batches, n_ex = self._round_batches(rnd)
-        keys = client_round_keys(
-            jax.random.fold_in(self.root_key, 4), self.C, rnd)
+        with self._span("inputs"):
+            batches, n_ex = self._round_batches(rnd)
+            keys = client_round_keys(
+                jax.random.fold_in(self.root_key, 4), self.C, rnd)
         snapshots, host_snaps, snap_fps, all_stats = [], [], [], []
         fp_mode = self.ledger is not None and self.faults.host_tamper is None
         # Pin the sequential path to ONE device when the model fits on one.
@@ -1822,28 +1960,34 @@ class FedEngine:
         for c in range(self.C):
             cb = (jax.tree.map(lambda x: x[c], dev_b) if pin
                   else jax.tree.map(lambda x: jnp.asarray(x[c]), host_b))
-            shared, stats = self.progs.single_update(shared, frozen, cb,
-                                                     keys[c])
+            with self._enqueued("single_update") as prog:
+                shared, stats = prog(shared, frozen, cb, keys[c])
             if fp_mode:
                 # device-side digest: K floats cross the link, not the tree
-                fence(shared)  # single_update is async; see _ledger_verify
+                with self._span("wait"):
+                    fence(shared)  # single_update is async; see _ledger_verify
                 with self.clock.phase("ledger"):
-                    fp = np.asarray(self.progs.fingerprint_one(shared))
+                    fp = self._fingerprint("fingerprint_one", shared)
                     snap_fps.append(fp)
-                    self.ledger.append_digest(
-                        rnd, c, self._entry_digest("one", fp),
-                        self._client_payload_bytes)
+                    with self._span("chain"):
+                        self.ledger.append_digest(
+                            rnd, c, self._entry_digest("one", fp),
+                            self._client_payload_bytes)
             elif self.ledger is not None:
                 with self.clock.phase("ledger"):
-                    snap = jax.device_get(shared)
-                    self.ledger.append(rnd, c, snap)
+                    with self._span("fetch") as counts:
+                        snap = jax.device_get(shared)
+                        counts["d2h_bytes"] = _nbytes(snap)
+                    with self._span("chain"):
+                        self.ledger.append(rnd, c, snap)
                     host_snaps.append(snap)
             snapshots.append(shared)
-            all_stats.append(np.asarray(stats))
-        rec = self._stats_to_rec(rnd, np.stack(all_stats))
+            all_stats.extend(self._fetch(stats))
+        with self._span("records"):
+            rec = self._stats_to_rec(rnd, np.stack(all_stats))
         w = np.asarray(mask, np.float32)
         if fp_mode:
-            with self.clock.phase("ledger"):
+            with self.clock.phase("ledger"), self._span("chain"):
                 # reuse the commit-time fingerprints: the snapshots are
                 # immutable device buffers, so recomputing would reproduce
                 # them bit-for-bit at 2x the fingerprint cost
@@ -1851,7 +1995,7 @@ class FedEngine:
             rec.auth = auth.tolist()
             w = w * auth
         elif self.ledger is not None:
-            with self.clock.phase("ledger"):
+            with self.clock.phase("ledger"), self._span("chain"):
                 stacked_host = jax.tree.map(
                     lambda *xs: np.stack(xs), *host_snaps)
                 auth = self._ledger_authenticate(rnd, stacked_host)
@@ -1908,13 +2052,15 @@ class FedEngine:
         cfg = self.cfg
         K = cfg.async_buffer or self.C
         if stacked is None:
-            stacked = self.progs.broadcast(trainable)
+            with self._enqueued("broadcast") as prog:
+                stacked = prog(trainable)
         base = stacked  # each client's round-start params (delta reference)
-        batches, n_ex = self._round_batches(rnd)
-        rngs = self._rngs(rnd)
-        stacked, stats = self.progs.local_updates(
-            stacked, self.frozen, batches, rngs)
-        rec = self._stats_to_rec(rnd, stats)
+        batches, n_ex, rngs, _ = self._round_inputs(rnd)
+        with self._enqueued("local_updates") as prog:
+            stacked, stats = prog(stacked, self.frozen, batches, rngs)
+        stats, = self._fetch(stats)
+        with self._span("records"):
+            rec = self._stats_to_rec(rnd, stats)
 
         # chaos stragglers: an affected client's completion slips by the
         # injected delay, so it arrives later and accumulates staleness —
@@ -1967,15 +2113,18 @@ class FedEngine:
             alpha = alpha * n_ex
 
         if arrived:
-            deltas = (_tree_sub(ex.sent, base) if self._comp is None
-                      else self.progs.decode_delta(ex.sent, stacked))
+            if self._comp is None:
+                deltas = _tree_sub(ex.sent, base)
+            else:
+                with self._enqueued("decode_delta") as prog:
+                    deltas = prog(ex.sent, stacked)
             zero = jax.tree.map(jnp.zeros_like, trainable)
             # collapse is a weight-NORMALIZED mean (divides by sum(alpha)), so
             # on its own the staleness decay would cancel out of the update
             # magnitude; rescale by sum(alpha)/sum(un-decayed weights) so a
             # stale delta really is applied smaller, FedBuff-style.
-            merged_delta = self.progs.collapse(
-                deltas, self.mesh.shard_clients(jnp.asarray(alpha)), zero)
+            with self._enqueued("collapse") as prog:
+                merged_delta = prog(deltas, self._shard_mask(alpha), zero)
             scale = self._async_merge_scale(alpha, arrived, n_ex)
             trainable = _tree_axpy(
                 trainable, merged_delta, cfg.async_server_lr * scale)
@@ -1984,8 +2133,8 @@ class FedEngine:
             # materialized [C, ...] broadcast buffer)
             pull = np.zeros((self.C,), np.float32)
             pull[arrived] = 1.0
-            pull_d = self.mesh.shard_clients(jnp.asarray(pull))
-            stacked = self.progs.adopt(stacked, trainable, pull_d)
+            with self._enqueued("adopt") as prog:
+                stacked = prog(stacked, trainable, self._shard_mask(pull))
             st["global_version"] += 1
             for c in arrived:
                 st["version"][c] = st["global_version"]

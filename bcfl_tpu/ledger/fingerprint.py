@@ -53,6 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bcfl_tpu.metrics.tracing import scope
+
 Tree = Any
 
 K = 4  # fingerprint floats per client; 16 bytes of content evidence/entry
@@ -80,6 +82,7 @@ def _projection(k: int) -> jnp.ndarray:
                              jnp.float32)
 
 
+@scope("fingerprint")
 def client_fingerprint(stacked: Tree, k: int = K) -> jnp.ndarray:
     """``[C, k]`` float32 fingerprint of a client-stacked tree (leaves
     ``[C, ...]``). Traceable — jit it once per structure; inside a scanned

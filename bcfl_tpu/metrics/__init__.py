@@ -4,4 +4,4 @@ from bcfl_tpu.metrics.metrics import (  # noqa: F401
     RunMetrics,
     model_size_gb,
 )
-from bcfl_tpu.metrics.tracing import StepClock, annotate, trace  # noqa: F401
+from bcfl_tpu.metrics.tracing import StepClock, scope, trace  # noqa: F401

@@ -420,6 +420,21 @@ def test_profile_dir_writes_trace(tmp_path):
     trace_files = [os.path.join(r, f)
                    for r, _, fs in os.walk(tmp_path / "tr") for f in fs]
     assert trace_files, "profiler trace directory is empty"
+    # the engine's spans sit in the trace as fed.* host events, on the
+    # profiler's clock, each with its round (and, on an enqueue, its program)
+    from jax.profiler import ProfileData
+
+    (pb,) = [f for f in trace_files if f.endswith(".xplane.pb")]
+    spans = [(ev.name, dict(ev.stats))
+             for plane in ProfileData.from_file(pb).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("fed.")]
+    names = {n for n, _ in spans}
+    assert {"fed.control_plane", "fed.round_program", "fed.round_program/enqueue",
+            "fed.round_program/wait", "fed.post_round"} <= names
+    assert all(st["round"] == 0 for _, st in spans)
+    assert {st["program"] for n, st in spans
+            if n == "fed.round_program/enqueue"} == {"server_round"}
 
 
 def test_ledger_fused_transport_corruption_fails_auth():

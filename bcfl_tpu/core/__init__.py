@@ -6,4 +6,8 @@ from bcfl_tpu.core.mesh import (  # noqa: F401
     pod_client_mesh,
     pod_devices,
 )
-from bcfl_tpu.core.prng import client_round_keys, fold_round  # noqa: F401
+from bcfl_tpu.core.prng import (  # noqa: F401
+    client_key_data,
+    client_round_keys,
+    fold_round,
+)

@@ -12,6 +12,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 
 def fold_round(key: jax.Array, round_idx: int) -> jax.Array:
@@ -29,3 +30,28 @@ def client_round_keys(key: jax.Array, clients, round_idx: int) -> jax.Array:
     ids = (jnp.arange(clients) if isinstance(clients, (int, np.integer))
            else jnp.asarray(np.asarray(clients), jnp.int32))
     return jax.vmap(lambda c: jax.random.fold_in(rk, c))(ids)
+
+
+@jax.jit
+def client_key_data(key: jax.Array, ids: jax.Array,
+                    rounds: jax.Array) -> jax.Array:
+    """The key data of :func:`client_round_keys` for a whole dispatch, as
+    ONE program: ``(key, ids[k, C] int32, rounds[k] int32) -> [k, C, K]
+    uint32``, row ``i`` being ``key_data(client_round_keys(key, ids[i],
+    rounds[i]))`` bit for bit; a scalar ``rounds`` with ``ids[C]`` gives the
+    one round's ``[C, K]``. Rounds and ids are operands, so one executable
+    per ``(k, C, key implementation)`` serves every dispatch of a run,
+    where the eager definition launches a handful of small device programs
+    a round with the chip idle (PERF.md section 6, PR 26).
+
+    The rounds go through ``lax.map``, not a second ``vmap``:
+    ``unsafe_rbg``'s ``fold_in`` batches differently under a nested vmap
+    and would give other keys."""
+    def one_round(rnd, row):
+        rk = jax.random.fold_in(key, rnd)
+        return jax.random.key_data(
+            jax.vmap(lambda c: jax.random.fold_in(rk, c))(row))
+
+    if rounds.ndim == 0:
+        return one_round(rounds, ids)
+    return lax.map(lambda x: one_round(*x), (rounds, ids))

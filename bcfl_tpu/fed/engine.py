@@ -72,7 +72,7 @@ import numpy as np
 from bcfl_tpu.checkpoint import restore_latest, save_checkpoint
 from bcfl_tpu.compression import codecs as cc
 from bcfl_tpu.config import FedConfig
-from bcfl_tpu.core import client_mesh, client_round_keys, pod_devices
+from bcfl_tpu.core import client_key_data, client_mesh, pod_devices
 from bcfl_tpu.core.fence import fence
 from bcfl_tpu.data import (
     Partitioner,
@@ -246,6 +246,8 @@ class FedEngine:
         # checkpoints written before the name existed
         self._prng_code = int(jax.random.key_data(self.root_key).shape[-1])
         self._prng_name = str(jax.random.key_impl(self.root_key))
+        # lane 4 of the root key: the clients' dropout/codec streams
+        self._client_key = jax.random.fold_in(self.root_key, 4)
 
         # --- data (tokenize once; SURVEY.md §3.2 fixes the 200x re-tokenize) ---
         self.dataset = load_dataset(
@@ -632,14 +634,25 @@ class FedEngine:
         )
         return self.mesh.shard_clients(jax.tree.map(jnp.asarray, tree))
 
+    def _key_data(self, rnd: int, k: Optional[int] = None):
+        """Client key data for round ``rnd`` ([C, K] uint32) or, given
+        ``k``, for rounds [rnd, rnd+k) ([k, C, K]): ONE call of the cached
+        key program (``client_key_data``), its rounds and ids built on the
+        host. Keyed by REGISTRY id in cohort mode: a client's dropout/codec
+        stream depends on (seed, id, round), never on its cohort slot."""
+        rounds = np.arange(rnd, rnd + (k or 1), dtype=np.int32)
+        ids = np.empty((len(rounds), self.C), np.int32)
+        for i, r in enumerate(rounds):
+            cohort = self._cohort_ids(int(r))
+            ids[i] = np.arange(self.C) if cohort is None else cohort
+        if k is None:
+            ids, rounds = ids[0], rounds[0]
+        if self.clock is not None:
+            self.clock.count("key_programs")
+        return client_key_data(self._client_key, ids, rounds)
+
     def _rngs(self, rnd: int):
-        # keyed by REGISTRY id in cohort mode: a client's dropout/codec
-        # stream depends on (seed, id, round), never on its cohort slot
-        ids = self._cohort_ids(rnd)
-        keys = client_round_keys(
-            jax.random.fold_in(self.root_key, 4),
-            ids if ids is not None else self.C, rnd)
-        return self.mesh.shard_clients(jax.random.key_data(keys))
+        return self.mesh.shard_clients(self._key_data(rnd))
 
     def _participation(self, rnd: int, components=None) -> Dict:
         if components is not None:
@@ -1607,14 +1620,12 @@ class FedEngine:
         partition cache hit — stacking k identical copies would be a k-fold
         HBM blowup for no information), else ``batches`` is the stacked
         [k, C, ...] tree."""
-        batch_list, rng_list, n_ex_list = [], [], []
+        batch_list, n_ex_list = [], []
         for r in range(rnd, rnd + k):
             b, n_ex = self._round_batches(r)
             batch_list.append(b)
             n_ex_list.append(n_ex)
-            rng_list.append(self._rngs(r))
-        rrngs = self.mesh.shard_round_clients(
-            jnp.stack([jnp.asarray(r) for r in rng_list]))
+        rrngs = self.mesh.shard_round_clients(self._key_data(rnd, k))
         if all(b is batch_list[0] for b in batch_list):
             return True, batch_list[0], rrngs, n_ex_list
         rbatches = self.mesh.shard_round_clients(
@@ -1928,8 +1939,8 @@ class FedEngine:
         cfg = self.cfg
         with self._span("inputs"):
             batches, n_ex = self._round_batches(rnd)
-            keys = client_round_keys(
-                jax.random.fold_in(self.root_key, 4), self.C, rnd)
+            keys = jax.random.wrap_key_data(
+                self._key_data(rnd), impl=cfg.resolved_prng_impl)
         snapshots, host_snaps, snap_fps, all_stats = [], [], [], []
         fp_mode = self.ledger is not None and self.faults.host_tamper is None
         # Pin the sequential path to ONE device when the model fits on one.

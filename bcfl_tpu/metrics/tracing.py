@@ -48,7 +48,9 @@ class StepClock:
     opens (``ledger`` opens inside ``round_program`` and is still
     ``ledger``) and records that parent beside it. ``span`` yields a dict
     for the span's integer counts (``h2d_bytes``, ``d2h_bytes``,
-    ``compiled``), filled in by the caller before the span closes.
+    ``compiled``), filled in by the caller before the span closes;
+    ``count`` adds to the innermost open span's from the code that does the
+    counted work (``key_programs``).
 
     Every completed span also feeds the run's event stream as a typed
     ``phase`` event (bcfl_tpu.telemetry, OBSERVABILITY.md) — a no-op unless
@@ -72,7 +74,8 @@ class StepClock:
         self._children: Dict[str, Dict[str, List[float]]] = defaultdict(
             lambda: defaultdict(list))
         self._counts: Dict[tuple, Counter] = defaultdict(Counter)
-        self._stack: List[str] = []
+        # the open spans, innermost last: (name, counts)
+        self._stack: List[tuple] = []
         # the round the open spans belong to, set by the round loop: an id
         # on every annotation, so a trace's spans can be told apart by round
         self.round: Optional[int] = None
@@ -81,17 +84,24 @@ class StepClock:
         return self._open(name, name, None, {})
 
     def span(self, name: str, program: Optional[str] = None, **counts):
-        parent = self._stack[-1] if self._stack else None
+        parent = self._stack[-1][0] if self._stack else None
         path = name if parent is None else f"{parent}/{name}"
         return self._open(path, None, program, counts)
 
+    def count(self, key: str) -> None:
+        """Add one to count ``key`` of the innermost open span (no span
+        open: nothing is counted)."""
+        if self._stack:
+            counts = self._stack[-1][1]
+            counts[key] = counts.get(key, 0) + 1
+
     @contextlib.contextmanager
     def _open(self, name, phase, program, counts):
-        parent = self._stack[-1] if self._stack else None
+        parent = self._stack[-1][0] if self._stack else None
         ids = {} if self.round is None else {"round": int(self.round)}
         if program is not None:
             ids["program"] = program
-        self._stack.append(name)
+        self._stack.append((name, counts))
         t0_ns = time.time_ns()
         t0 = time.perf_counter()
         try:

@@ -4,7 +4,8 @@ process: for each seed the program's first rounds against the reference
 (the lower readings), and on the first ``--controls`` seeds the control (the
 reference in fp8, put in the program's place) and the faults the cell can
 have, planted in the reference put in the program's place (the upper
-readings); ``--control-only`` reads the control alone and builds no engine.
+readings); everything that depends on the model comes from the
+configuration's family (benchmarks/families), found by its name; ``--control-only`` reads the control alone and builds no engine.
 Not part of a benchmark run; run it on the chip at the cell's own size when
 a limit has to be set, and with ``--plumbing`` for the tests' tiny sizes.
 Writes ``chiprun_out/calibrate-<cell>.json``."""
@@ -22,14 +23,13 @@ sys.path.insert(0, ROOT)
 
 def readings(cell_name, seeds, controls, plumbing, out_dir, control_only=False):
     import jax
-    import jax.numpy as jnp
 
-    from benchmarks import compare, harness, traffic, weights
+    from benchmarks import compare, families, harness, traffic
     from benchmarks.reference import gate
-    from benchmarks.reference import train as ref_train
 
     cell, sizes = harness.load_cell(cell_name, plumbing)
-    stated_p, control_p = harness.precisions(sizes)
+    fam = families.of(sizes)
+    stated_p, control_p = fam.precisions(sizes)
     n = cell["check"]["rounds"]
     rows = []
     for i, seed in enumerate(seeds):
@@ -41,35 +41,33 @@ def readings(cell_name, seeds, controls, plumbing, out_dir, control_only=False):
             harness.setup_engine(run)
             res, recs, _ = harness._drive(run, n)
             first = [harness._rec_dict(r) for r in recs]
-            prog = weights.from_program(jax.device_get(res.trainable), sizes)
+            prog = fam.from_program(jax.device_get(res.trainable), sizes)
             del res
             harness.release(run)
         else:
             # the control's reading needs no program: the reference stands
             # in its place, under the mask a sound run has
-            run.batches, run.n_ex = traffic.make(cell["traffic"], sizes["vocab_size"],
-                                                 sizes["num_labels"], seed)
+            program = fam.program(sizes)
+            run.batches, run.n_ex = traffic.make(
+                cell["traffic"], program["vocab_size"], program.get("num_labels", 2), seed,
+                job=program.get("task", "classification"))
             first = [{"mask": want_mask, "auth": [1.0] * clients, "train_loss": 0.0}] * n
         masks = [r["mask"] for r in first]
-        batches = jax.tree.map(jnp.asarray, run.batches)
-        start = weights.make(sizes, seed)
-        start_h = jax.device_get(start)
 
         def ref(**kw):
-            losses, out, gn = ref_train.run_rounds(
-                start, sizes, sizes["training"], batches, seed, masks, run.n_ex, **kw)
-            return [float(x) for x in losses], jax.device_get(out), jax.device_get(gn)
+            return fam.reference(sizes, seed, run.batches, masks, run.n_ex, **kw)
 
         def against(losses, params):
-            v, notes = compare.numbers(losses, ref_losses, params, ref_params, start_h,
-                                       ref_gn, first, True, len(first) * clients,
-                                       clients, 0, stated=stated, expected_mask=want_mask)
+            v, notes = compare.numbers(losses, sound["losses"], params, sound["trained"],
+                                       sound["start"], sound["grad_norms"], first, True,
+                                       len(first) * clients, clients, 0, stated=stated,
+                                       expected_mask=want_mask)
             v = {k: x for k, x in v.items() if k.startswith(("loss_", "dparam_", "turn_"))}
             v["worst_leaf"] = notes["dparam_worst_leaf"]
             return v
 
-        ref_losses, ref_params, ref_gn = ref()
-        stated = ref(precision=stated_p)[1]
+        sound = ref()
+        stated = ref(precision=stated_p)["trained"]
         row = {"seed": seed, "masks": masks}
         if not control_only:
             row["program"] = against([r["train_loss"] for r in first], prog)
@@ -77,15 +75,23 @@ def readings(cell_name, seeds, controls, plumbing, out_dir, control_only=False):
                 {k: v for k, v in row["program"].items() if k != "worst_leaf"},
                 {k: v for k, v in cell["limits"].items() if k in row["program"]})[1]
         if i < controls:
-            row["control"] = against(*ref(precision=control_p)[:2])
+            def read(**kw):
+                r = ref(**kw)
+                return against(r["losses"], r["trained"])
+
+            row["control"] = read(precision=control_p)
             if not control_only:
-                row["fault_half_batch"] = against(*ref(half_batch=True)[:2])
+                row["fault_half_batch"] = read(fault={"half_batch": True})
                 live = [c for c, m in enumerate(masks[0]) if m > 0]
                 if len(live) > 1:
-                    row["fault_client_left_out"] = against(*ref(drop_client=live[-1])[:2])
+                    row["fault_client_left_out"] = read(fault={"drop_client": live[-1]})
         row["seconds"] = time.time() - t0
         rows.append(row)
         harness.log(json.dumps(row))
+        # every row as it comes: a call that is cut keeps what it read
+        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(ROOT, "chiprun_out", f"calibrate-{cell_name}.rows.jsonl"), "a") as f:
+            f.write(json.dumps(row) + "\n")
     return rows
 
 

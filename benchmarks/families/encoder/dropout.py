@@ -23,7 +23,7 @@ from __future__ import annotations
 import jax
 from flax.core.scope import LazyRng
 
-from . import encoder
+from . import model as encoder
 
 DROPOUT_LANE = 4  # FedEngine._rngs folds 4 into the root key for this stream
 

@@ -5,7 +5,7 @@ clients. It follows the published post-LayerNorm encoder
 departures that the configuration's file lists under ``reduced``.
 
 It imports nothing of the program. Parameters are a FLAT dict of float32
-arrays named as below (``benchmarks/weights.py`` makes them from the seed):
+arrays named as below (``weights.py`` beside this file makes them from the seed):
 
   emb.word [V, E]  emb.pos [P, E]  emb.type [T, E]  emb.ln.g/.b [E]
   emb.proj.w [E, H] emb.proj.b [H]           (only when E != H: ALBERT)

@@ -12,7 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import dropout, encoder
+from . import dropout
+from . import model as encoder
 
 
 @functools.partial(jax.jit, static_argnames=("sizes_key", "precision", "half_batch"))

@@ -1,6 +1,6 @@
-"""The benchmark's weights: made on the device in ONE jitted call from the
+"""The encoder family's weights: made on the device in ONE jitted call from the
 seed, float32 (the type the configuration states for parameters), in the
-reference's flat naming (benchmarks/reference/encoder.py). ``to_program``
+reference's flat naming (benchmarks/families/encoder/model.py). ``to_program``
 lays the same arrays out as the program's parameter tree, so the program and
 the reference start from the same numbers and neither takes the other's."""
 

@@ -1,20 +1,32 @@
 """The benchmark's harness: one cell, one seed, one process.
 
 It finds everything by name: the cell's file (benchmarks/workloads), its
-configuration's file (benchmarks/configs) and the per-layer metrics' files
-(benchmarks/metrics, each naming its reader under benchmarks/readers). A
-later PR adds a cell, a configuration or a metric by adding such files and
-entries in BENCHMARK.json, and edits nothing here.
+configuration's file (benchmarks/configs), the configuration's model family
+(benchmarks/families/<family>, named by the file's ``family`` key) and the
+per-layer metrics' files (benchmarks/metrics, each naming its reader under
+benchmarks/readers). A later PR adds a cell, a configuration, a metric or a
+whole model family by adding such files and entries in BENCHMARK.json, and
+edits nothing here. A family is a package that answers, for its models,
+everything that depends on the model (benchmarks/families/__init__.py lists
+the interface): the weights from the seed and their layout as the program's
+``(trainable, frozen)`` trees, the plain reference of the first rounds and
+its precisions' names, the required operations, and the ``FedConfig`` fields
+and the job kind its configurations map to. Nothing in this file knows a
+model: a new family brings ``families/<name>/`` (its ``__init__.py`` and
+whatever it splits off), a configuration ``configs/<name>.json``, a cell
+``workloads/<name>.json``, a metric ``metrics/<name>.json`` and its reader.
 
 What it drives is the product's entry, ``FedEngine.run`` on an engine built
 from a ``FedConfig`` as ``bcfl_tpu.entrypoints.run`` builds it. From the
-seed it makes the weights (benchmarks/weights.py) and the round's batches
-(benchmarks/traffic.py) and hands both to the engine; the reference gets the
-same arrays from the same generators and nothing from the program.
+seed the family makes the weights and benchmarks/traffic.py the round's
+batches, and the harness hands both to the engine; the reference makes the
+same arrays from the same generators and takes nothing from the program.
 
 One engine object goes through three ``run`` calls: the first rounds (from
 the seed; these are compared with the reference), a second warm dispatch,
-and the measured window. See PERF.md, Layers, for what each metric reads.
+and the measured window. With ``--trace 1`` the traced bracket is reduced,
+by scope too, and the per-layer readers run while the trace is still on
+disk. See PERF.md, Layers, for what each metric reads.
 """
 
 from __future__ import annotations
@@ -61,14 +73,6 @@ def place_compile_cache():
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_compilation_cache_max_size", -1)
     return CACHE_DIR
-
-
-def precisions(sizes):
-    """``(stated, control)``: the reference's names (reference/encoder.py)
-    for the precision the configuration states and for the nearest one
-    below it, from the configuration's file."""
-    p = sizes["training"]["reference_precisions"]
-    return p["stated"], p["control"]
 
 
 # ----------------------------------------------------------------- the files
@@ -119,21 +123,25 @@ def metrics_for(bench, cell_name, kind):
 # ------------------------------------------------------------ the FedConfig
 
 def build_cfg(cell, sizes, seed, telemetry_dir):
-    """``FedConfig`` from the two files and the seed. The cell's ``fed``
-    object carries every field the cell sets; nested objects become the
-    nested config classes by the type of the field's default."""
+    """``FedConfig`` from the two files and the seed. The fields that depend
+    on the model (``model``, ``vocab_size``, ``num_labels``, ``task``, ...)
+    are the family's ``program(sizes)``; the cell's ``fed`` object carries
+    every field the cell sets; nested objects become the nested config
+    classes by the type of the field's default."""
     from bcfl_tpu.config import FedConfig
+
+    from benchmarks import families
 
     t, tr = cell["traffic"], sizes["training"]
     fields = dict(
-        name=cell["name"], seed=int(seed), model=sizes["program_model"],
-        vocab_size=sizes["vocab_size"], num_labels=sizes["num_labels"],
+        name=cell["name"], seed=int(seed),
         seq_len=t["seq"], batch_size=t["batch"], num_clients=t["clients"],
         max_local_batches=t["local_batches"],
         param_dtype=tr["param_dtype"], compute_dtype=tr["compute_dtype"],
         optimizer=tr["optimizer"], learning_rate=tr["learning_rate"],
         telemetry_dir=telemetry_dir,
     )
+    fields.update(families.of(sizes).program(sizes))
     fields.update(cell["fed"])
     defaults = {f.name: f for f in dataclasses.fields(FedConfig)}
     for k, v in list(fields.items()):
@@ -169,6 +177,53 @@ def _rec_dict(rec):
                                "anomalies", "reputation_state")}
 
 
+def _leaves(tree):
+    """``{path: (shape, dtype)}`` of a tree's leaves."""
+    import jax
+
+    return {jax.tree_util.keystr(k): (tuple(x.shape), str(x.dtype))
+            for k, x in jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def hand_over(engine, trainable, frozen, sizes):
+    """The benchmark's weights in place of the engine's own initial draw.
+    Both trees have to be the engine's own leaf for leaf, in shape and in
+    type, and the model's parameters (the frozen base; for full fine-tuning
+    the trained tree) in the ``training.param_dtype`` the configuration
+    states: anything else raises, so a program whose trees or types change is
+    refused at set-up and nothing is substituted. What is trained over a
+    frozen base has the type the PROGRAM draws it in (models/lora.py: the
+    base's), and the family writes it so. Each tree is placed as the engine
+    places its own: the trained tree replicated, the frozen base once, with
+    the sharding of the leaf it replaces."""
+    import jax
+
+    stated = sizes["training"]["param_dtype"]
+    for what, mine, own in (("trainable", trainable, engine.trainable0),
+                            ("frozen", frozen, engine.frozen)):
+        if (mine is None) != (own is None):
+            raise RuntimeError(f"the family hands over {'no' if mine is None else 'a'} "
+                               f"{what} tree and the program has {'none' if own is None else 'one'}")
+        if mine is None:
+            continue
+        want, have = _leaves(own), _leaves(mine)
+        if want != have:
+            odd = sorted(k for k in set(want) | set(have) if want.get(k) != have.get(k))
+            raise RuntimeError(
+                f"the program's {what} tree is not the configuration's: {len(odd)} leaves differ, "
+                f"the first {[(k, want.get(k), have.get(k)) for k in odd[:3]]} (program, family)")
+        if what == "frozen" or frozen is None:
+            off = sorted({d for _, d in want.values()} - {stated})
+            if off:
+                raise RuntimeError(f"the program holds its parameters (the {what} tree) in "
+                                   f"{off} where the configuration states {stated}")
+    engine.trainable0 = engine.mesh.replicate(trainable)
+    if frozen is not None:
+        where = jax.tree.map(lambda x: x.sharding, engine.frozen)
+        engine.frozen = None  # the engine's own draw goes before ours is placed
+        engine.frozen = jax.device_put(frozen, where)
+
+
 def setup_engine(run, prepare=None):
     """Weights and traffic from the seed, the engine, and both handed over.
     ``prepare(engine)`` is the tests' hook to break the timed path."""
@@ -177,34 +232,28 @@ def setup_engine(run, prepare=None):
 
     from bcfl_tpu.fed.engine import FedEngine
 
-    from benchmarks import traffic, weights
+    from benchmarks import families, traffic
 
     cell, sizes = run.cell, run.sizes
+    fam = families.of(sizes)
     tele = os.path.join(run.out_dir, "telemetry")
     shutil.rmtree(tele, ignore_errors=True)
     cfg = build_cfg(cell, sizes, run.seed, tele)
     run.cfg = cfg
-    t = cell["traffic"]
-    batches, n_ex = traffic.make(t, sizes["vocab_size"], sizes["num_labels"], run.seed)
+    batches, n_ex = traffic.make(cell["traffic"], cfg.vocab_size, cfg.num_labels, run.seed,
+                                 job=cfg.task)
     run.batches, run.n_ex = batches, n_ex
-    flat = weights.make(sizes, run.seed)
-    tree = weights.to_program(flat, sizes)
+    flat = fam.make_weights(sizes, run.seed)
+    trainable, frozen = fam.to_program(flat, sizes)
     engine = FedEngine(cfg)
-    want = jax.tree.map(lambda x: (x.shape, str(x.dtype)), engine.trainable0)
-    have = jax.tree.map(lambda x: (x.shape, str(x.dtype)), tree)
-    if want != have:
-        raise RuntimeError(
-            "the program's parameter tree is not the configuration's: "
-            f"{jax.tree.structure(want)} against {jax.tree.structure(have)}")
-    # the benchmark's weights in place of the engine's own initial draw, laid
-    # out as the engine lays its own out; the benchmark's batches as the
-    # round-static batch cache (iid partition without resampling: the engine
-    # reuses one batch tree every round, this one)
-    engine.trainable0 = engine.mesh.replicate(tree)
+    # the benchmark's weights in place of the engine's own; the benchmark's
+    # batches as the round-static batch cache (iid partition without
+    # resampling: the engine reuses one batch tree every round, this one)
+    hand_over(engine, trainable, frozen, sizes)
     engine._static_batches = (
         engine.mesh.shard_clients(jax.tree.map(jnp.asarray, batches)),
         np.asarray(n_ex))
-    del flat, tree
+    del flat, trainable, frozen
     if prepare is not None:
         prepare(engine)
     run.engine = engine
@@ -280,9 +329,6 @@ def window(run):
             jax.profiler.start_trace(run.trace_dir)
             state["t_on"] = time.perf_counter()
             state["start_cost"] = state["t_on"] - t
-            # the wall clock in a span's name puts the host's spans on the trace's clock
-            with jax.profiler.TraceAnnotation(f"bench.mark#{time.time_ns()}"):
-                pass
         elif state["disp"] == tr["skip_dispatches"] + tr["dispatches"]:
             state["t_off"] = time.perf_counter()
             jax.profiler.stop_trace()
@@ -300,6 +346,9 @@ def window(run):
     run.ledger_summary = res.metrics.ledger or {}
     run.chain_len = len(run.engine.ledger) if run.engine.ledger is not None else 0
     run.bracket = state
+    # what the program holds its frozen base in once the window has run
+    run.frozen_dtypes = (None if run.engine.frozen is None else
+                         [str(x.dtype) for x in jax.tree.leaves(run.engine.frozen)])
     devs = jax.devices()
     stats = [d.memory_stats() or {} for d in devs]
     run.memory_stats = {k: int(v) for k, v in stats[0].items() if isinstance(v, (int, float))}
@@ -331,42 +380,43 @@ def release(run):
 
 
 def reference_check(run):
-    """Run the reference over the first rounds, in float32 and once more in
-    the stated precision, and compare. Returns the judged rows, ``correct``
-    and the notes."""
-    import jax
-    import jax.numpy as jnp
-
-    from benchmarks import compare, weights
+    """The family's reference over the first rounds, in float32 and once
+    more in the stated precision, and the comparison. Returns the judged
+    rows, ``correct`` and the notes. The harness holds no weights here: the
+    family makes them from the seed and decides what is on the device at
+    once."""
+    from benchmarks import compare, families
     from benchmarks.reference import gate
-    from benchmarks.reference import train as ref_train
 
     sizes = run.sizes
+    fam = families.of(sizes)
     clients = run.cell["traffic"]["clients"]
     n = run.cell["check"]["rounds"]
     masks = [r["mask"] for r in run.first_records[:n]]
-    start = weights.make(sizes, run.seed)
     t0 = time.perf_counter()
-    batches = jax.tree.map(jnp.asarray, run.batches)
-    losses, ref, gnorm = ref_train.run_rounds(
-        start, sizes, sizes["training"], batches, run.seed, masks, run.n_ex)
-    ref = jax.device_get(ref)
+    ref = fam.reference(sizes, run.seed, run.batches, masks, run.n_ex)
     # the same rounds once more in the precision the configuration states
-    _, stated, _ = ref_train.run_rounds(
-        start, sizes, sizes["training"], batches, run.seed, masks, run.n_ex,
-        precision=precisions(sizes)[0])
-    stated = jax.device_get(stated)
+    stated = fam.reference(sizes, run.seed, run.batches, masks, run.n_ex,
+                           precision=fam.precisions(sizes)[0])
     run.reference_s = time.perf_counter() - t0
-    prog = weights.from_program(run.after_first, sizes)
+    prog = fam.from_program(run.after_first, sizes)
     recs = run.first_records + run.warm_records + run.window_records
     values, notes = compare.numbers(
-        [r["train_loss"] for r in run.first_records[:n]], losses, prog, ref,
-        jax.device_get(start), jax.device_get(gnorm), recs,
+        [r["train_loss"] for r in run.first_records[:n]], ref["losses"], prog,
+        ref["trained"], ref["start"], ref["grad_norms"], recs,
         bool(run.ledger_summary.get("chain_ok", 0.0) == 1.0), run.chain_len,
-        clients, run.compile_events, stated=stated,
+        clients, run.compile_events, stated=stated["trained"],
         expected_mask=gate.expected_mask(run.cell.get("gate"), clients, run.seed))
+    if run.frozen_dtypes is not None:
+        # a frozen base held in another type than the configuration states
+        # costs other memory and is another model: leaves off it, limit 0.
+        # ``hand_over`` refuses the engine's own draw in another type; this
+        # reads the base as the window left it, so it also sees a program
+        # that converts its base once it runs
+        values["frozen_leaves_off_stated_dtype"] = float(sum(
+            1 for d in run.frozen_dtypes if d != sizes["training"]["param_dtype"]))
     rows, ok = compare.judge(values, run.cell["limits"])
-    run.ref_losses = [float(x) for x in losses]
+    run.ref_losses = [float(x) for x in ref["losses"]]
     return rows, ok, notes
 
 
@@ -385,54 +435,50 @@ def count_compiles(run):
 # --------------------------------------------------------- metrics and trace
 
 def reduce_trace(run):
-    """The traced bracket as numbers (None without a device trace)."""
+    """The traced bracket as numbers (None without a device trace): busy
+    time a device, the first device's time by scope (``scopes``, ``op_names``:
+    trace_reduce.scope_table) and its longest idle gaps named by the
+    engine's innermost ``fed.*`` host span."""
     from benchmarks import trace_reduce as tr
 
     b = run.bracket
     if not run.trace or b["t_on"] is None or b["t_off"] is None:
         return None
-    raw = tr.load_xplane(tr.find_xplane(run.trace_dir))
+    t0 = time.perf_counter()
+    path = tr.find_xplane(run.trace_dir)
+    raw = tr.load_xplane(path)
+    log(f"[bench] trace {os.path.getsize(path) / 1e6:.1f} MB, "
+        f"{sum(len(v) for v in raw['devices'].values())} device operations, "
+        f"{len(raw['host'])} engine spans, read in {time.perf_counter() - t0:.1f}s")
     if not raw["devices"]:
         return None
     per_dev = {name: tr.reduce_device(ops) for name, ops in raw["devices"].items()}
     first = sorted(per_dev)[0]
-    # host spans on the trace's clock: the mark carries the wall clock
-    offset = None
-    for name, start, _ in raw["host"]:
-        if name.startswith("bench.mark#"):
-            offset = start - float(name.split("#")[1])
-    spans = []
-    if offset is not None:
-        rank = {"ledger": 0, "control_plane": 1, "eval": 2, "round_program": 3}
-        for ev in _telemetry_events(run):
-            if ev.get("ev") != "phase":
-                continue
-            end = ev["t_wall"] * 1e9 + offset
-            label = ev["name"] if ev["name"] != "round_program" else "round_program dispatch"
-            spans.append([label, end - ev["wall_s"] * 1e9, end, rank.get(ev["name"], 4)])
     window_s = b["t_off"] - b["t_on"]
-    return {
+    rounds = run.cell["trace"]["dispatches"] * run.k
+    out = {
         "window_s": window_s,
-        "rounds": run.cell["trace"]["dispatches"] * run.k,
+        "rounds": rounds,
         "devices": per_dev,
         "first_device": first,
         "busy_s": float(np.mean([d["busy_s"] for d in per_dev.values()])),
         "worst_idle_pct": 100.0 * (1.0 - min(d["busy_s"] for d in per_dev.values()) / window_s),
-        "device_ops": tr.op_totals(raw["devices"][first]),
-        "idle_gaps": tr.name_gaps(tr.gaps(per_dev[first]["busy"]), spans),
+        # by scope and HLO name where the trace names scopes, else by HLO name
+        "device_ops": tr.op_totals(raw["devices"][first], by_scope=raw["op_names"]),
+        "idle_gaps": tr.name_gaps(tr.gaps(per_dev[first]["busy"]), tr.host_spans(raw["host"])),
+        "scopes": None, "op_names": None,
     }
-
-
-def _telemetry_events(run):
-    path = os.path.join(run.out_dir, "telemetry", "events_engine.jsonl")
-    out = []
-    if os.path.exists(path):
-        with open(path) as f:
-            for line in f:
-                try:
-                    out.append(json.loads(line))
-                except ValueError:
-                    pass
+    if raw["op_names"]:
+        out.update(tr.scope_table(tr.leaves(raw["devices"][first]), rounds))
+        ranked = sorted(out["scopes"].items(), key=lambda kv: -kv[1])
+        log(f"[bench] scopes, ms a round: {json.dumps({k: round(v, 3) for k, v in ranked})}; "
+            f"together {sum(out['scopes'].values()):.3f} of the device's "
+            f"{1e3 * per_dev[first]['busy_s'] / rounds:.3f} busy")
+    else:
+        out["scopes_error"] = (
+            f"no device operation of the trace carries the {tr.OP_NAME_STAT!r} stat in its "
+            "metadata: this libtpu names an operation's op_name otherwise, or the profiler "
+            "wrote none (benchmarks/trace_reduce.py, OP_NAME_STAT)")
     return out
 
 
@@ -457,8 +503,21 @@ def context(run, trace):
         "tokens_per_s_per_chip": steady,
         "device_kind": run.device["kind"], "platform": run.device["platform"],
         "memory_peak_bytes": run.memory_peak_bytes,
-        "trace": trace, "yardstick": yardstick,
+        "trace": trace, "trace_dir": run.trace_dir, "yardstick": yardstick,
     }
+
+
+def per_layer(run, bench, trace):
+    """The cell's per-layer metrics, each from its reader. A reader that
+    finds nothing to read returns None and the line leaves the metric out."""
+    ctx = context(run, trace)
+    metrics = {}
+    for m in metrics_for(bench, run.cell["name"], "per_layer"):
+        spec = load_json("metrics", m["name"] + ".json")
+        value = load_reader(spec["reader"])(ctx)
+        if value is not None:
+            metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    return metrics
 
 
 def run_cell(cell_name, seed, seconds, trace, plumbing=False, out_dir=None,
@@ -488,24 +547,19 @@ def run_cell(cell_name, seed, seconds, trace, plumbing=False, out_dir=None,
         f"window {run.window_rounds} rounds in {run.window_wall:.2f}s; "
         f"peak {run.memory_peak_bytes / 1e9:.2f} GB (live arrays {run.memory_live_bytes / 1e9:.2f})")
     release(run)
-    tr = reduce_trace(run) if trace else None
-    shutil.rmtree(run.trace_dir, ignore_errors=True)
-    rows, ok, notes = reference_check(run)
-
     tokens = traffic.tokens_per_round(cell["traffic"]) * run.window_rounds
     e2e = {"tokens_per_s_per_chip": tokens / run.window_wall / cell["chips"],
            "setup_s": run.setup_s}
-    metrics = {}
     if trace:
-        ctx = context(run, tr)
-        for m in metrics_for(bench, cell_name, "per_layer"):
-            spec = load_json("metrics", m["name"] + ".json")
-            value = load_reader(spec["reader"])(ctx)
-            if value is not None:
-                metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+        # the readers run while the trace is still on disk
+        tr = reduce_trace(run)
+        metrics = per_layer(run, bench, tr)
     else:
-        for m in metrics_for(bench, cell_name, "end_to_end"):
-            metrics[m["name"]] = {"value": float(e2e[m["name"]]), "unit": m["unit"]}
+        tr = None
+        metrics = {m["name"]: {"value": float(e2e[m["name"]]), "unit": m["unit"]}
+                   for m in metrics_for(bench, cell_name, "end_to_end")}
+    shutil.rmtree(run.trace_dir, ignore_errors=True)
+    rows, ok, notes = reference_check(run)
 
     records = run.window_records
     failed = sum(1 for r in records
@@ -522,6 +576,9 @@ def run_cell(cell_name, seed, seconds, trace, plumbing=False, out_dir=None,
         device["busy_s"] = tr["busy_s"]
         device["window_s"] = tr["window_s"]
         result["breakdown"] = {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
+        if tr["scopes"]:
+            result["breakdown"]["scopes_ms_per_round"] = sorted(
+                ([k, v] for k, v in tr["scopes"].items()), key=lambda kv: -kv[1])[:10]
     compared = {name: {"value": v, "limit": lim, "ok": good} for name, v, lim, good in rows}
     detail = {
         "workload": cell_name, "seed": run.seed, "trace": int(trace), "seconds": seconds,
@@ -533,7 +590,8 @@ def run_cell(cell_name, seed, seconds, trace, plumbing=False, out_dir=None,
         "window": {"rounds": run.window_rounds, "wall_s": run.window_wall, "k": run.k,
                    "round_wall_warm_s": run.round_wall, "first_wall_s": run.first_wall},
         "setup_stages_s": run.stages, "memory_stats": run.memory_stats,
-
+        # the whole table; the result's line carries its ten largest rows
+        "scopes_ms_per_round": tr["scopes"] if tr else None,
         "result": result,
     }
     with open(os.path.join(out_dir, f"run-{run.seed}-t{int(trace)}.json"), "w") as f:

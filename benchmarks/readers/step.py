@@ -6,4 +6,4 @@ def mfu_pct(ctx):
     if ctx["platform"] != "tpu":
         return None
     return ctx["yardstick"].mfu_pct(ctx["tokens_per_s_per_chip"], ctx["sizes"],
-                                    ctx["seq"], ctx["device_kind"])
+                                    ctx["seq"], ctx["device_kind"], ctx["cell"])

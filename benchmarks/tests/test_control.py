@@ -4,35 +4,33 @@ bfloat16 pipeline), put in the program's place, at a size a test run can
 hold. The stated precision itself, put in the program's place, is correct.
 On the chip at the cells' own sizes: PERF.md, section 2."""
 
-import jax
-import jax.numpy as jnp
 import pytest
 
-from benchmarks import compare, harness, traffic, weights
-from benchmarks.reference import train as ref_train
+from benchmarks import compare, families, harness, traffic
 
 
 def numbers(cell_name, seed, which):
     cell, sizes = harness.load_cell(cell_name, plumbing=True)
     t = cell["traffic"]
+    fam = families.of(sizes)
     batches, n_ex = traffic.make(t, sizes["vocab_size"], sizes["num_labels"], seed)
-    batches = jax.tree.map(jnp.asarray, batches)
     masks = [[1.0] * t["clients"]] * cell["check"]["rounds"]
-    start = weights.make(sizes, seed)
+    start = None
 
     def ref(p):
-        losses, out, gn = ref_train.run_rounds(start, sizes, sizes["training"], batches, seed,
-                                               masks, n_ex, precision=p)
-        return [float(x) for x in losses], jax.device_get(out), jax.device_get(gn)
+        nonlocal start
+        r = fam.reference(sizes, seed, batches, masks, n_ex, precision=p)
+        start = r["start"]
+        return r["losses"], r["trained"], r["grad_norms"]
 
-    ref_losses, ref_params, gnorm = ref("f32")
-    stated_p, control_p = harness.precisions(sizes)
+    ref_losses, ref_params, gnorm = ref(None)
+    stated_p, control_p = fam.precisions(sizes)
     precision = {"stated": stated_p, "control": control_p}[which]
     stated = ref(stated_p)[1]
     losses, params, _ = (None, stated, None) if which == "stated" else ref(precision)
     losses = losses or ref_losses
     recs = [{"auth": [1.0], "train_loss": x, "mask": masks[0]} for x in losses]
-    values, _ = compare.numbers(losses, ref_losses, params, ref_params, jax.device_get(start),
+    values, _ = compare.numbers(losses, ref_losses, params, ref_params, start,
                                 gnorm, recs, True, len(recs) * t["clients"], t["clients"], 0,
                                 stated=stated, expected_mask=masks[0])
     return compare.judge(values, cell["limits"])

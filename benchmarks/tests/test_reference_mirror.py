@@ -1,15 +1,16 @@
 """The reference against the program at a tiny size on the CPU, both in
 float32: same weights, same batches, same dropout draws give the same
 losses and the same parameters. This pins the one thing the reference cannot
-derive from the mathematics, the dropout draws (reference/dropout.py)."""
+derive from the mathematics, the dropout draws (families/encoder/dropout.py)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import harness, traffic, weights
-from benchmarks.reference import train as ref_train
+from benchmarks import families, harness, traffic
+from benchmarks.families.encoder import train as ref_train
+from benchmarks.families.encoder import weights
 
 SEED = 3000000011
 
@@ -64,3 +65,24 @@ def test_traffic_has_the_same_sizes_for_every_seed():
     assert b1["ids"].max() < sizes["vocab_size"] and (n1 == t["local_batches"] * t["batch"]).all()
     rows = b1["ids"].reshape(-1, t["seq"])
     assert len({r.tobytes() for r in rows}) == len(rows)  # rows that all differ
+
+
+def test_the_family_seam_gives_the_same_reference():
+    """Through the family's interface (what the harness and calibrate.py
+    call) the reference is the encoder's own ``run_rounds``, digit for digit."""
+    cell, sizes = harness.load_cell("albert-base.fedavg-s128", plumbing=True)
+    fam = families.of(sizes)
+    t = cell["traffic"]
+    batches, n_ex = traffic.make(t, sizes["vocab_size"], sizes["num_labels"], SEED)
+    masks = [[1.0] * t["clients"]] * 2
+    got = fam.reference(sizes, SEED, batches, masks, n_ex)
+    start = weights.make(sizes, SEED)
+    losses, ref, gnorm = ref_train.run_rounds(
+        start, sizes, sizes["training"], jax.tree.map(jnp.asarray, batches), SEED, masks, n_ex)
+    assert got["losses"] == [float(x) for x in losses]
+    for name in ref:
+        assert np.array_equal(got["trained"][name], np.asarray(ref[name]))
+        assert np.array_equal(got["start"][name], np.asarray(start[name]))
+        assert float(got["grad_norms"][name]) == float(gnorm[name])
+    tree, frozen = fam.to_program(start, sizes)
+    assert frozen is None and set(fam.from_program(tree, sizes)) == set(start)

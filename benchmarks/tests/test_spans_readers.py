@@ -66,7 +66,10 @@ def test_absent_reads_none(name, ctx):
 def test_new_entries_are_additions():
     b = harness.load_benchmark()
     names = [m["name"] for m in b["per_layer"]]
-    assert names[-len(NEW):] == NEW
+    assert names[8:8 + len(NEW)] == NEW  # after PR 24's eight; PR 27's five follow
+    assert names[8 + len(NEW):] == [
+        "step.forward_ms_per_round", "step.backward_ms_per_round", "step.optimizer_ms_per_round",
+        "step.dropout_ms_per_round", "aggregate.device_ms_per_round"]
     by = {m["name"]: m for m in b["per_layer"]}
     assert by["ledger.fingerprint_ms_per_round"]["workloads"] == ["albert-base.guarded-s128"]
     assert all("workloads" not in by[n] for n in NEW[:-1])

@@ -4,6 +4,7 @@ import os
 import pytest
 
 from benchmarks import harness, yardstick
+from benchmarks.families import encoder
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -19,8 +20,8 @@ def test_bert_base_required_flop_by_hand():
     s = sizes("bert-base")
     fwd = 12 * (2 * (4 * 768 * 768 + 2 * 768 * 3072) + 4 * 128 * 768)
     fwd += (2 * 768 * 768 + 2 * 768 * 2) / 128
-    assert yardstick.forward_flops_per_token(s, 128) == pytest.approx(fwd)
-    assert yardstick.train_flops_per_token(s, 128) / 1e6 == pytest.approx(524, abs=0.5)
+    assert encoder.forward_flops_per_token(s, 128) == pytest.approx(fwd)
+    assert encoder.train_flops_per_token(s, 128) / 1e6 == pytest.approx(524, abs=0.5)
 
 
 def test_albert_counts_its_shared_layer_twelve_times():
@@ -28,19 +29,19 @@ def test_albert_counts_its_shared_layer_twelve_times():
     # one parameter set, applied 12 times: the same FLOP as bert-base plus
     # the factorized embedding's projection (2 x 128 x 768 a token)
     extra = 2 * 128 * 768
-    assert yardstick.forward_flops_per_token(a, 128) == pytest.approx(
-        yardstick.forward_flops_per_token(b, 128) + extra)
+    assert encoder.forward_flops_per_token(a, 128) == pytest.approx(
+        encoder.forward_flops_per_token(b, 128) + extra)
     one_layer = dict(a, num_hidden_layers=1)
-    assert (yardstick.forward_flops_per_token(a, 128)
-            > 11 * yardstick.forward_flops_per_token(one_layer, 128))
+    assert (encoder.forward_flops_per_token(a, 128)
+            > 11 * encoder.forward_flops_per_token(one_layer, 128))
     # nothing like 6 x parameters x tokens: 11.7 M parameters would give 70 MFLOP
-    assert yardstick.train_flops_per_token(a, 128) > 500e6
+    assert encoder.train_flops_per_token(a, 128) > 500e6
 
 
 def test_embedding_lookups_count_nothing():
     s = sizes("bert-base")
-    assert (yardstick.forward_flops_per_token(dict(s, vocab_size=10 * s["vocab_size"]), 128)
-            == yardstick.forward_flops_per_token(s, 128))
+    assert (encoder.forward_flops_per_token(dict(s, vocab_size=10 * s["vocab_size"]), 128)
+            == encoder.forward_flops_per_token(s, 128))
 
 
 def test_unknown_device_kind_is_refused():
@@ -65,3 +66,30 @@ def test_roofline_says_which_peak_bounds():
     assert bound == "memory" and share == pytest.approx(25.0)
     # nothing measured: nothing reported, never 0
     assert yardstick.roofline(1e9, 1e9, 0.0, "TPU v5 lite") is None
+
+
+def test_the_count_moved_house_and_not_value():
+    """The encoder family's count is the old yardstick's to the last digit
+    (PERF.md section 4: 523.8 and 524.4 MFLOP a trained token), and so is
+    the share of the peak at PR 26's rates."""
+    assert encoder.forward_flops_per_token(sizes("bert-base"), 128) == 174597144.0
+    assert encoder.train_flops_per_token(sizes("bert-base"), 128) == 523791432.0
+    assert encoder.forward_flops_per_token(sizes("albert-base"), 128) == 174793752.0
+    assert encoder.train_flops_per_token(sizes("albert-base"), 128) == 524381256.0
+    assert yardstick.mfu_pct(145899.0, sizes("bert-base"), 128, "TPU v5 lite") == 38.79220616110051
+    assert yardstick.mfu_pct(145899.0, sizes("albert-base"), 128, "TPU v5 lite") == 38.83588876606294
+
+
+def test_a_frozen_matrix_has_no_weight_gradient_product():
+    """The rule on two matrices by hand: y = (x W1) W2 at 8 -> 16 -> 4, W1
+    frozen and W2 trained. Forward 2 x 8 x 16 + 2 x 16 x 4 = 384 FLOP a
+    token. W2: forward, activation gradient, weight gradient = 3 x 128;
+    W1: forward and activation gradient only = 2 x 256; with a rank-2
+    adapter on W1 (a: 8 x 2, b: 2 x 16, both trained) 3 x (32 + 64) more."""
+    w1, w2 = 2 * 8 * 16, 2 * 16 * 4
+    assert yardstick.train_flops(w1, trained=False) + yardstick.train_flops(w2) == 2 * 256 + 3 * 128
+    assert yardstick.train_flops(w1 + w2) == 3 * 384            # full fine-tuning: 1152
+    adapters = yardstick.train_flops(2 * 8 * 2 + 2 * 2 * 16)
+    assert adapters == 288
+    lora = yardstick.train_flops(w1, trained=False) + adapters + yardstick.train_flops(w2)
+    assert lora == 512 + 288 + 384 < yardstick.train_flops(w1 + w2) + adapters

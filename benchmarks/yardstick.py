@@ -1,6 +1,8 @@
-"""The yardstick's arithmetic: the table of peaks, the operations a training
-step REQUIRES (from the configuration's shapes, never from the program), and
-the roofline share of a kernel. Later PRs cannot change this file."""
+"""The yardstick's arithmetic: the table of peaks, the rule by which the
+operations a training step REQUIRES are counted (from the configuration's
+shapes, never from the program; the count itself lives with the model's
+family, benchmarks/families/<family>), and the roofline share of a kernel.
+Later PRs cannot change this file."""
 
 from __future__ import annotations
 
@@ -22,31 +24,28 @@ def peaks(device_kind):
     return table[device_kind]
 
 
-def forward_flops_per_token(sizes, seq):
-    """Matrix-multiplication and attention FLOP of one forward pass, per
-    token, at sequence length ``seq``. A layer counts as often as it RUNS
-    (ALBERT's shared layer num_hidden_layers times); embedding lookups are
-    gathers and count nothing; the pooler and the classifier run once a
-    sequence."""
-    H, F, E = sizes["hidden_size"], sizes["intermediate_size"], sizes["embedding_size"]
-    L = sizes["num_hidden_layers"]
-    layer = 2 * (4 * H * H + 2 * H * F)  # q, k, v, out and the two MLP products
-    attention = 4 * seq * H              # QK^T and PV over every head
-    proj = 2 * E * H if E != H else 0    # ALBERT's factorized embedding
-    head = (2 * H * H + 2 * H * sizes["num_labels"]) / seq
-    return L * (layer + attention) + proj + head
+def train_flops(forward_flops, trained=True):
+    """THE RULE, written once: count what the training result REQUIRES at the
+    sizes held here. A product with a matrix that is trained costs its
+    forward product, an activation-gradient product and a weight-gradient
+    product: three times ``forward_flops``. A matrix that is NOT trained (a
+    frozen base under adapters) has the first two and no weight-gradient
+    product: twice. A product of two activations (attention's QK^T and PV)
+    has a gradient for each operand: three times. An expert counts for the
+    tokens routed to it among the experts held, not for every token;
+    embedding lookups are gathers and count nothing; recomputation counts
+    nothing. A family's ``train_flops_per_token`` adds its products up by
+    this function."""
+    return (3.0 if trained else 2.0) * forward_flops
 
 
-def train_flops_per_token(sizes, seq):
-    """Forward, and a backward that costs twice the forward (one product for
-    the activation gradient, one for the weight gradient). Recomputation
-    counts nothing."""
-    return 3.0 * forward_flops_per_token(sizes, seq)
+def mfu_pct(tokens_per_s_per_chip, sizes, seq, device_kind, cell=None):
+    """The whole step's share of the chip's peak, over the operations the
+    family of the configuration's file counts as required."""
+    from benchmarks import families
 
-
-def mfu_pct(tokens_per_s_per_chip, sizes, seq, device_kind):
-    return 100.0 * tokens_per_s_per_chip * train_flops_per_token(sizes, seq) / (
-        peaks(device_kind)["bf16_flops_per_s"])
+    required = families.of(sizes).train_flops_per_token(sizes, seq, cell)
+    return 100.0 * tokens_per_s_per_chip * required / peaks(device_kind)["bf16_flops_per_s"]
 
 
 def roofline(flops, bytes_moved, seconds, device_kind, flops_key="bf16_flops_per_s"):

@@ -851,6 +851,15 @@ class FedConfig:
                 raise ValueError(
                     f"{feature} is not supported on runtime="
                     f"{self.runtime!r}: {verdict}")
+        # what the model's family cannot run (models.refusals): refused here
+        # with the reason, never silently replicated or skipped
+        from bcfl_tpu.models import refusals
+
+        refused = refusals(self.model, task=self.task, lora_rank=self.lora_rank,
+                           tp=self.tp, sp=self.sp,
+                           client_lora_ranks=self.client_lora_ranks)
+        if refused:
+            raise ValueError(refused)
         if self.runtime == "dist":
             if self.num_clients % self.dist.peers:
                 raise ValueError(

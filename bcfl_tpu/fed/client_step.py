@@ -66,6 +66,35 @@ def _merge(trainable: Tree, frozen: Optional[Tree]) -> Tree:
         return lora_lib.apply_lora(frozen, trainable)
 
 
+def model_variables(model, trainable: Tree, frozen: Optional[Tree]) -> dict:
+    """The variables ``model.apply`` takes. Full fine-tune: the trained
+    tree. LoRA: by the family's policy (``models.lora_policy``) the adapters
+    merged into the frozen base, ``W + a b``, or handed to the model beside
+    it as the ``lora`` collection, applied on the activations."""
+    from bcfl_tpu.models import lora_policy
+
+    if frozen is not None and lora_policy(model).on_activations:
+        return {"params": frozen, "lora": lora_lib.as_collection(trainable)}
+    return {"params": _merge(trainable, frozen)}
+
+
+def model_counters(model) -> tuple:
+    """``((name, "sum" | "max"), ...)``: what the model counts in a step
+    (a flax ``counters`` collection), sums first; ``()`` for most models."""
+    return tuple(getattr(model, "COUNTERS", ()))
+
+
+def _counter_values(state, counters):
+    """One value a counter from the ``counters`` collection a step filled:
+    every module's entry folded by the counter's kind."""
+    by_name = {name: [] for name, _ in counters}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(state)[0]:
+        by_name[getattr(path[-1], "key", str(path[-1]))].append(leaf)
+    return tuple(
+        (jnp.max if kind == "max" else jnp.sum)(jnp.stack(by_name[name]))
+        for name, kind in counters)
+
+
 def make_loss_fn(model, task: str = "classification") -> Callable:
     """Per-batch loss + (correct, n) stats, shared by train and eval.
 
@@ -73,18 +102,29 @@ def make_loss_fn(model, task: str = "classification") -> Callable:
     ``causal_lm``: next-token CE — targets are ``ids`` shifted left, token
     positions weighted by the padding mask x example mask; ``n`` counts
     TOKENS, so the engine's loss/acc normalization is per-token.
+
+    ``loss_fn(...) -> (loss, (correct, n, *counted))``: ``counted`` is one
+    value for each of the model's counters (:func:`model_counters`; none for
+    most models), which the local step carries out with its statistics.
     """
+    counters = model_counters(model)
 
     def _forward(trainable, frozen, batch, rng):
-        params = _merge(trainable, frozen)
+        variables = model_variables(model, trainable, frozen)
         # under value_and_grad the backward pass of everything in here is
         # named transpose(jvp(fed.forward))
         with scope("forward"):
-            return model.apply(
-                {"params": params}, batch["ids"], batch["mask"],
+            out = model.apply(
+                variables, batch["ids"], batch["mask"],
                 deterministic=rng is None,
                 rngs=None if rng is None else {"dropout": rng},
+                **({"mutable": ["counters"]} if counters else {}),
             )
+        if not counters:
+            return out, ()
+        logits, state = out
+        return logits, jax.lax.stop_gradient(
+            _counter_values(state["counters"], counters))
 
     @scope("loss")
     def _loss_cls(logits, batch):
@@ -96,8 +136,14 @@ def make_loss_fn(model, task: str = "classification") -> Callable:
         correct = ((jnp.argmax(logits, -1) == labels).astype(jnp.float32) * ex).sum()
         return loss, (correct, ex.sum())
 
-    def loss_cls(trainable, frozen, batch, rng):
-        return _loss_cls(_forward(trainable, frozen, batch, rng), batch)
+    def with_counted(loss_of):
+        def loss(trainable, frozen, batch, rng):
+            logits, counted = _forward(trainable, frozen, batch, rng)
+            value, aux = loss_of(logits, batch)
+            return value, aux + counted
+
+        loss.counters = counters
+        return loss
 
     @scope("loss")
     def _loss_lm(logits, batch):  # logits [B, S, V]
@@ -113,13 +159,10 @@ def make_loss_fn(model, task: str = "classification") -> Callable:
                    * w).sum()
         return loss, (correct, w.sum())
 
-    def loss_lm(trainable, frozen, batch, rng):
-        return _loss_lm(_forward(trainable, frozen, batch, rng), batch)
-
     if task == "classification":
-        return loss_cls
+        return with_counted(_loss_cls)
     if task == "causal_lm":
-        return loss_lm
+        return with_counted(_loss_lm)
     raise ValueError(f"unknown task {task!r}")
 
 
@@ -137,7 +180,7 @@ def make_eval_one(loss_fn) -> Callable:
 
     def eval_one(trainable, frozen, batches):
         def step(carry, batch):
-            loss, (correct, n) = loss_fn(trainable, frozen, batch, None)
+            loss, (correct, n, *_) = loss_fn(trainable, frozen, batch, None)
             return carry, jnp.stack([loss * n, correct, n])
 
         _, stats = lax.scan(step, 0.0, batches)
@@ -189,7 +232,12 @@ def make_local_train(tx, loss_fn) -> Callable:
     ``server_IID_IMDB.py:109``), ``lax.scan`` over static-shape batches.
     ``(trainable, frozen, batches, rng) -> (trainable, [loss*n, correct, n])``.
     Shared by the 1-D clients mesh programs and the clients x tp composition
-    (:mod:`bcfl_tpu.parallel.fed_tp`)."""
+    (:mod:`bcfl_tpu.parallel.fed_tp`). A model that counts
+    (``loss_fn.counters``) appends its counters to the statistics, summed or
+    largest over the steps by their kind: they leave the device in the fetch
+    the round makes anyway."""
+    kinds = [k for _, k in getattr(loss_fn, "counters", ())]
+    n_max = kinds.count("max")
 
     def local_train(trainable, frozen, batches, rng):
         with scope("optimizer_init"):
@@ -200,15 +248,18 @@ def make_local_train(tx, loss_fn) -> Callable:
         def step(carry, xs):
             t, opt = carry
             batch, r = xs
-            (loss, (correct, n)), grads = jax.value_and_grad(
+            (loss, (correct, n, *counted)), grads = jax.value_and_grad(
                 loss_fn, has_aux=True)(t, frozen, batch, r)
             with scope("optimizer"):
                 updates, opt = tx.update(grads, opt, t)
                 t = optax.apply_updates(t, updates)
-            return (t, opt), jnp.stack([loss * n, correct, n])
+            return (t, opt), jnp.stack([loss * n, correct, n, *counted])
 
         (trainable, _), stats = lax.scan(
             step, (trainable, opt_state), (batches, step_rngs))
+        if n_max:  # the counters of kind "max" come last
+            return trainable, jnp.concatenate(
+                [stats[:, :-n_max].sum(axis=0), stats[:, -n_max:].max(axis=0)])
         return trainable, stats.sum(axis=0)
 
     return local_train

@@ -83,7 +83,8 @@ from bcfl_tpu.data import (
 )
 from bcfl_tpu.data.pipeline import central_eval_batches
 from bcfl_tpu.faults import FaultInjector, SimulatedCrash
-from bcfl_tpu.fed.client_step import FedPrograms, build_programs, _merge
+from bcfl_tpu.fed.client_step import (
+    FedPrograms, build_programs, model_counters, _merge)
 from bcfl_tpu.fed.cohort import ClientSampler, EFRegistry, cohort_view
 from bcfl_tpu.ledger import Ledger
 from bcfl_tpu.ledger import fingerprint as fp_lib
@@ -314,6 +315,8 @@ class FedEngine:
             dtype_overrides["attention_override"] = ring_override(
                 self.mesh.mesh)
             dtype_overrides["use_flash"] = False
+        from bcfl_tpu.models import lora_policy
+
         if cfg.hf_checkpoint is not None:
             if cfg.task == "causal_lm":
                 raise ValueError(
@@ -327,6 +330,7 @@ class FedEngine:
             )
             model_cfg = dataclasses.replace(model_cfg, **dtype_overrides)
             self.model = TextClassifier(model_cfg)
+            self._lora_policy = lora_policy(self.model)
             # the importer materializes float32; the configured param dtype
             # must apply to the ARRAYS, not just the config record
             params = jax.tree.map(
@@ -342,13 +346,26 @@ class FedEngine:
                 head="lm" if cfg.task == "causal_lm" else "classifier",
                 **dtype_overrides,
             )
+            self._lora_policy = lora_policy(self.model)
             ids = jnp.ones((2, cfg.seq_len), jnp.int32)
-            params = self.model.init(
-                jax.random.fold_in(self.root_key, 2), ids, ids)["params"]
+            key = jax.random.fold_in(self.root_key, 2)
+            init = self.model.init
+            if model_size_gb(
+                    jax.eval_shape(init, key, ids, ids)["params"]) >= 1.0:
+                # a large base: the draw alone, jitted (init's forward pass
+                # is dead code there), and born with the steady-state
+                # sharding, so that pinning it makes no second copy of a
+                # base that fills half a chip. Smaller ones keep the eager
+                # draw (its values differ from the jitted one's in the last
+                # bits, and seeded runs are pinned to them)
+                init = jax.jit(init, out_shardings=self.mesh.replicated())
+            params = init(key, ids, ids)["params"]
 
         if cfg.lora_rank > 0:
-            from bcfl_tpu.models import lora_targets
-
+            policy = self._lora_policy
+            how = dict(targets=policy.targets,
+                       head_modules=policy.head_modules,
+                       dtype=policy.adapter_dtype)
             self.frozen = params
             ranks = cfg.client_lora_ranks
             if ranks is not None and len(set(ranks)) > 1:
@@ -361,14 +378,14 @@ class FedEngine:
 
                 stacked0 = lora_lib.init_lora_ranks(
                     jax.random.fold_in(self.root_key, 3), params, ranks,
-                    targets=lora_targets(cfg.model))
+                    targets=policy.targets, head_modules=policy.head_modules)
                 self.trainable0 = gspmd.rank_aware_weighted_mean(
                     stacked0, jnp.ones((len(ranks),), jnp.float32),
                     lora_lib.rank_mask(ranks))
             else:
                 self.trainable0 = lora_lib.init_lora(
                     jax.random.fold_in(self.root_key, 3), params,
-                    cfg.lora_rank, targets=lora_targets(cfg.model))
+                    cfg.lora_rank, **how)
         else:
             self.frozen = None
             self.trainable0 = params
@@ -391,6 +408,7 @@ class FedEngine:
                 self.frozen,
                 jax.tree.map(lambda s: NamedSharding(self.mesh.mesh, s),
                              specs))
+        self._counters = model_counters(self.model)
         self.progs: FedPrograms = build_programs(
             self.model, self.mesh,
             optimizer=cfg.optimizer, learning_rate=cfg.learning_rate,
@@ -1304,7 +1322,8 @@ class FedEngine:
                     for r in recs:
                         telemetry.emit("round", round=r.round,
                                        wall_s=r.wall_s, fused=True,
-                                       degraded=r.degraded)
+                                       degraded=r.degraded,
+                                       **(r.counters or {}))
                 if on_round is not None:
                     with clock.span("on_round"):
                         for r in recs:
@@ -1444,13 +1463,19 @@ class FedEngine:
                 self._maybe_checkpoint(rnd, trainable, stacked)
                 telemetry.emit("round", round=rnd, wall_s=rec.wall_s,
                                degraded=rec.degraded, healed=rec.healed,
-                               partitioned=rec.partition is not None)
+                               partitioned=rec.partition is not None,
+                               **(rec.counters or {}))
             if on_round is not None:
                 with clock.span("on_round"):
                     on_round(rec)
             rnd += 1
 
-        params = _merge(trainable, self.frozen)
+        # the model's parameters as they are applied: the trained tree, the
+        # adapters merged into the base, or (adapters applied on the
+        # activations) the base, with the adapters beside it in ``trainable``
+        params = (self.frozen if self._lora_policy.on_activations
+                  and self.frozen is not None
+                  else _merge(trainable, self.frozen))
         metrics.model_size_gb = model_size_gb(params)
         metrics.resources = monitor.snapshot()
         metrics.phases = clock.summary()
@@ -1780,14 +1805,24 @@ class FedEngine:
     # ----------------------------------------------------------- round bodies
 
     def _stats_to_rec(self, rnd: int, stats) -> RoundRecord:
-        s = np.asarray(stats)  # [C, 3]
+        s = np.asarray(stats)  # [C, 3 + the model's counters]
         n = np.maximum(s[:, 2], 1)
         total = s.sum(0)
         C = self.C
         raw = float(self._raw_bytes_per_client * C)
         wire = float(self._wire_bytes_per_client * C)
+        # what the model counted (client_step.model_counters): over the
+        # clients by the counter's kind, and added to the open ``records``
+        # span's counts (a span that covers several rounds sums them)
+        counters = {
+            name: float(s[:, 3 + i].max() if kind == "max" else total[3 + i])
+            for i, (name, kind) in enumerate(self._counters)} or None
+        if counters and self.clock is not None:
+            for name, value in counters.items():
+                self.clock.count(name, int(value))
         return RoundRecord(
             round=rnd,
+            counters=counters,
             train_loss=float(total[0] / max(total[2], 1)),
             train_acc=float(total[1] / max(total[2], 1)),
             local_acc=(s[:, 1] / n).tolist(),

@@ -203,6 +203,10 @@ class RoundRecord:
     # spread across rank dims; a collapsing one trends toward 1.0). None
     # when lora_rank == 0.
     effective_rank: Optional[float] = None
+    # what the model counted this round (fed.client_step.model_counters; the
+    # expert layer's moe_slots_held / moe_slots_absent / moe_rows_max, each
+    # over the round's steps and clients by its kind); None for most models
+    counters: Optional[Dict[str, float]] = None
     wall_s: float = 0.0
     # True when this round ran inside a fused multi-round dispatch: wall_s
     # is then the chunk total split EVENLY across its rounds (an

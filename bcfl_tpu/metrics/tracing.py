@@ -88,12 +88,12 @@ class StepClock:
         path = name if parent is None else f"{parent}/{name}"
         return self._open(path, None, program, counts)
 
-    def count(self, key: str) -> None:
-        """Add one to count ``key`` of the innermost open span (no span
+    def count(self, key: str, n: int = 1) -> None:
+        """Add ``n`` to count ``key`` of the innermost open span (no span
         open: nothing is counted)."""
         if self._stack:
             counts = self._stack[-1][1]
-            counts[key] = counts.get(key, 0) + 1
+            counts[key] = counts.get(key, 0) + n
 
     @contextlib.contextmanager
     def _open(self, name, phase, program, counts):

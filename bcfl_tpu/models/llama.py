@@ -83,10 +83,14 @@ class RMSNorm(nn.Module):
         return (y * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embedding over [B, H, S, D] with positions [B, S] or [S]."""
+def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+         freqs: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Rotary embedding over [B, H, S, D] with positions [B, S] or [S],
+    rotating the pairs (2i, 2i + 1). ``freqs`` [D / 2] replaces the plain
+    ``theta^(-2i/D)`` (YaRN: models.latent_moe.yarn_inv_freq)."""
     D = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    if freqs is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
     if positions.ndim == 1:
         positions = positions[None, :]
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]

@@ -36,10 +36,13 @@ def _is_target(path: Tuple[str, ...], targets: Sequence[str]) -> bool:
 
 def init_lora(key: jax.Array, params, rank: int,
               targets: Sequence[str] = DEFAULT_TARGETS,
-              head_modules: Sequence[str] = HEAD_MODULES):
+              head_modules: Sequence[str] = HEAD_MODULES,
+              dtype=None):
     """Create the adapter tree: for each targeted kernel W (viewed 2D as
     [fan_in, fan_out]) an ``a`` [fan_in, rank] (gaussian/sqrt(rank)) and
-    ``b`` [rank, fan_out] (zeros — adapters start as identity). Leaves of
+    ``b`` [rank, fan_out] (zeros — adapters start as identity), in ``dtype``
+    (None: the kernel's own; a family's policy, ``models.lora_policy``, may
+    keep float32 adapters over a bfloat16 base). Leaves of
     ``head_modules`` are copied into the tree whole and substituted (not
     low-rank-added) at merge time, so task heads fine-tune in full."""
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
@@ -65,12 +68,27 @@ def init_lora(key: jax.Array, params, rank: int,
         else:
             continue
         key, k1 = jax.random.split(key)
+        dt = leaf.dtype if dtype is None else jnp.dtype(dtype)
         adapters["/".join(names[:-1])] = {
-            "a": (jax.random.normal(k1, (fan_in, rank), leaf.dtype)
-                  / jnp.sqrt(jnp.asarray(rank, leaf.dtype))),
-            "b": jnp.zeros((rank, fan_out), leaf.dtype),
+            "a": (jax.random.normal(k1, (fan_in, rank), dt)
+                  / jnp.sqrt(jnp.asarray(rank, dt))),
+            "b": jnp.zeros((rank, fan_out), dt),
         }
     return adapters
+
+
+def as_collection(adapters):
+    """The adapter tree (keys ``"module/path"``) as the nested ``lora``
+    variable collection of a model whose dense layers apply adapters on the
+    activations, ``x W + (x a) b`` (``models.lora_policy(...).on_activations``):
+    ``{"module": {"path": {"a": ..., "b": ...}}}``."""
+    out = {}
+    for path, entry in adapters.items():
+        node = out
+        for name in path.split("/"):
+            node = node.setdefault(name, {})
+        node.update(entry)
+    return out
 
 
 def apply_lora(params, adapters, scale: float = 1.0):

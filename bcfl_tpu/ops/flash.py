@@ -140,6 +140,10 @@ FLASH_ATTENTION = registry.register_op(registry.KernelOp(
         {"label": "bert-base-B4-S512", "B": 4, "H": 12, "S": 512, "D": 64},
         {"label": "llama-decode-B1-S2048", "B": 1, "H": 8, "S": 2048,
          "D": 64},
+        # models/latent_moe.py at its published sizes: query-key and value
+        # heads both 128 wide (pinned in tests/test_pallas_kernels.py)
+        {"label": "latent-moe-B2-H32-S2048-D128", "B": 2, "H": 32, "S": 2048,
+         "D": 128},
     ),
     supports=pallas_supported,
 ))

@@ -72,6 +72,18 @@ def _zero_oob_rows(x, start: int, limit: int):
     return jnp.where(idx < limit, x, jnp.zeros_like(x))
 
 
+def _when_block_is_live(causal: bool, qi, ki, bq: int, bk: int,
+                        sq: int, sk: int):
+    """Decorator that runs a kernel's per-block work unless the causal
+    triangle masks the whole (query block, key block) pair: its first key
+    lies past its last query's position (``sk - sq + i`` for query ``i``).
+    Such a block adds nothing to any accumulator, so skipping it changes no
+    result; at equal lengths it is nearly half of the grid."""
+    if not causal:
+        return lambda body: body()
+    return pl.when(ki * bk <= (sk - sq) + qi * bq + (bq - 1))
+
+
 # --------------------------------------------------------------------- forward
 
 
@@ -79,6 +91,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, scale: float, causal: bool, bq: int, bk: int,
                 sq: int, sk: int):
+    # read outside the blocks below: a program id is no value inside one
+    qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -88,41 +102,44 @@ def _fwd_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0]  # [bq, D]
-    k = _zero_oob_rows(k_ref[0, 0], ki * bk, sk)  # [bk, D]
-    v = _zero_oob_rows(v_ref[0, 0], ki * bk, sk)  # [bk, D]
-    b = bias_ref[0, 0]  # [bk]
+    @_when_block_is_live(causal, qi, ki, bq, bk, sq, sk)
+    def _block():
+        q = q_ref[0, 0]  # [bq, D]
+        k = _zero_oob_rows(k_ref[0, 0], ki * bk, sk)  # [bk, D]
+        v = _zero_oob_rows(v_ref[0, 0], ki * bk, sk)  # [bk, D]
+        b = bias_ref[0, 0]  # [bk]
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [bq, bk]
-    s = s + b[None, :].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [bq, bk]
+        s = s + b[None, :].astype(jnp.float32)
 
-    # block-index masking: padded tail keys + (optionally) the causal triangle
-    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    dead = kpos >= sk
-    if causal:
-        # suffix alignment for Sq != Sk (decode pattern): query i sits at
-        # global position (sk - sq) + i — matches flash_attention_xla
-        qpos = (sk - sq) + pl.program_id(2) * bq + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 0)
-        dead = jnp.logical_or(dead, kpos > qpos)
-    s = jnp.where(dead, NEG_INF, s)
+        # block-index masking: padded tail keys + (optionally) the causal
+        # triangle
+        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        dead = kpos >= sk
+        if causal:
+            # suffix alignment for Sq != Sk (decode pattern): query i sits at
+            # global position (sk - sq) + i — matches flash_attention_xla
+            qpos = (sk - sq) + qi * bq + (
+                jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0))
+            dead = jnp.logical_or(dead, kpos > qpos)
+        s = jnp.where(dead, NEG_INF, s)
 
-    m_prev = m_ref[:, :1]  # [bq, 1]
-    l_prev = l_ref[:, :1]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    p = jnp.where(dead, 0.0, p)  # exp(NEG-NEG)=1 on all-masked rows otherwise
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_prev = m_ref[:, :1]  # [bq, 1]
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        p = jnp.where(dead, 0.0, p)  # exp(NEG-NEG)=1 on all-masked rows otherwise
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_prev * alpha + p.sum(axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -203,48 +220,50 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, do_ref, lse_ref,
         dv_acc[:] = jnp.zeros_like(dv_acc)
         db_acc[:] = jnp.zeros_like(db_acc)
 
-    q = _zero_oob_rows(q_ref[0, 0], qi * bq, sq)    # [bq, D]
-    k = k_ref[0, 0]    # [bk, D]
-    v = v_ref[0, 0]    # [bk, D]
-    o = _zero_oob_rows(o_ref[0, 0], qi * bq, sq)    # [bq, D]
-    do = _zero_oob_rows(do_ref[0, 0], qi * bq, sq)  # [bq, D]
-    b = bias_ref[0, 0]  # [bk]
-    lse = lse_ref[0, 0][:, :1]  # [bq, 1]
+    @_when_block_is_live(causal, qi, ki, bq, bk, sq, sk)
+    def _block():
+        q = _zero_oob_rows(q_ref[0, 0], qi * bq, sq)    # [bq, D]
+        k = k_ref[0, 0]    # [bk, D]
+        v = v_ref[0, 0]    # [bk, D]
+        o = _zero_oob_rows(o_ref[0, 0], qi * bq, sq)    # [bq, D]
+        do = _zero_oob_rows(do_ref[0, 0], qi * bq, sq)  # [bq, D]
+        b = bias_ref[0, 0]  # [bk]
+        lse = lse_ref[0, 0][:, :1]  # [bq, 1]
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + b[None, :].astype(jnp.float32)
-    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    qrow = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    # padded tail QUERY rows must be masked here: unlike the forward (where
-    # garbage rows land in the discarded output slice) they would otherwise
-    # contribute to the dk/dv/db accumulators
-    dead = jnp.logical_or(kpos >= sk, qrow >= sq)
-    if causal:
-        dead = jnp.logical_or(dead, kpos > (sk - sq) + qrow)
-    p = jnp.where(dead, 0.0, jnp.exp(s - lse))  # [bq, bk]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale + b[None, :].astype(jnp.float32)
+        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        qrow = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        # padded tail QUERY rows must be masked here: unlike the forward (where
+        # garbage rows land in the discarded output slice) they would otherwise
+        # contribute to the dk/dv/db accumulators
+        dead = jnp.logical_or(kpos >= sk, qrow >= sq)
+        if causal:
+            dead = jnp.logical_or(dead, kpos > (sk - sq) + qrow)
+        p = jnp.where(dead, 0.0, jnp.exp(s - lse))  # [bq, bk]
 
-    dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [bk, D]
+        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [bk, D]
 
-    dsum = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
-        axis=-1, keepdims=True)  # [bq, 1] = rowsum(dO * O)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [bq, bk]
-    # explicit re-mask: dp/dsum can carry NaN/Inf from padded tail reads and
-    # 0 * NaN = NaN would survive p's zeros
-    ds = jnp.where(dead, 0.0, p * (dp - dsum))  # [bq, bk] f32
+        dsum = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+            axis=-1, keepdims=True)  # [bq, 1] = rowsum(dO * O)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [bq, bk]
+        # explicit re-mask: dp/dsum can carry NaN/Inf from padded tail reads and
+        # 0 * NaN = NaN would survive p's zeros
+        ds = jnp.where(dead, 0.0, p * (dp - dsum))  # [bq, bk] f32
 
-    dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [bk, D]
-    db_acc[0:1, :] = db_acc[0:1, :] + ds.sum(axis=0)[None, :]
+        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [bk, D]
+        db_acc[0:1, :] = db_acc[0:1, :] + ds.sum(axis=0)[None, :]
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -265,38 +284,40 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, do_ref, lse_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    q = q_ref[0, 0]
-    k = _zero_oob_rows(k_ref[0, 0], ki * bk, sk)
-    v = _zero_oob_rows(v_ref[0, 0], ki * bk, sk)
-    o = o_ref[0, 0]
-    do = do_ref[0, 0]
-    b = bias_ref[0, 0]  # [bk]
-    lse = lse_ref[0, 0][:, :1]
+    @_when_block_is_live(causal, qi, ki, bq, bk, sq, sk)
+    def _block():
+        q = q_ref[0, 0]
+        k = _zero_oob_rows(k_ref[0, 0], ki * bk, sk)
+        v = _zero_oob_rows(v_ref[0, 0], ki * bk, sk)
+        o = o_ref[0, 0]
+        do = do_ref[0, 0]
+        b = bias_ref[0, 0]  # [bk]
+        lse = lse_ref[0, 0][:, :1]
 
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale + b[None, :].astype(jnp.float32)
-    kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    dead = kpos >= sk
-    if causal:
-        qpos = (sk - sq) + qi * bq + jax.lax.broadcasted_iota(
-            jnp.int32, (bq, bk), 0)
-        dead = jnp.logical_or(dead, kpos > qpos)
-    p = jnp.where(dead, 0.0, jnp.exp(s - lse))
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale + b[None, :].astype(jnp.float32)
+        kpos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        dead = kpos >= sk
+        if causal:
+            qpos = (sk - sq) + qi * bq + jax.lax.broadcasted_iota(
+                jnp.int32, (bq, bk), 0)
+            dead = jnp.logical_or(dead, kpos > qpos)
+        p = jnp.where(dead, 0.0, jnp.exp(s - lse))
 
-    dsum = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
-        axis=-1, keepdims=True)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = jnp.where(dead, 0.0, p * (dp - dsum))
+        dsum = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+            axis=-1, keepdims=True)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = jnp.where(dead, 0.0, p * (dp - dsum))
 
-    dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [bq, D]
+        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [bq, D]
 
     @pl.when(ki == nk - 1)
     def _finalize():

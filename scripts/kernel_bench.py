@@ -37,6 +37,7 @@ import numpy as np  # noqa: E402
 
 # importing these registers the ops
 import bcfl_tpu.ops.flash  # noqa: E402,F401
+import bcfl_tpu.ops.grouped_matmul  # noqa: E402,F401
 import bcfl_tpu.ops.pallas_codec  # noqa: E402,F401
 from bcfl_tpu.core.fence import fence  # noqa: E402
 from bcfl_tpu.core.hostenv import compile_cache  # noqa: E402
@@ -63,6 +64,17 @@ def _build(op_name: str, row: dict):
         kk = jax.random.normal(jax.random.fold_in(key, 1), q.shape)
         v = jax.random.normal(jax.random.fold_in(key, 2), q.shape)
         return (q, kk, v), {}
+    if op_name == "moe_grouped_matmul":
+        M, K, N, G = row["M"], row["K"], row["N"], row["G"]
+        # outputs of about 0.25: under 2 in magnitude, where one bfloat16
+        # rounding apart (float32 sums in another order) is inside the
+        # bench's coarse allclose
+        lhs = jax.random.normal(key, (M, K), jnp.bfloat16) * 0.2
+        rhs = jax.random.normal(jax.random.fold_in(key, 1), (G, K, N),
+                                jnp.bfloat16) * 0.02
+        # ``live`` rows spread evenly over the groups; the rest belong to none
+        sizes = jnp.full((G,), row["live"] // G, jnp.int32)
+        return (lhs, rhs, sizes), {}
     raise SystemExit(f"no arg builder for op {op_name!r}; add one here")
 
 

@@ -153,3 +153,29 @@ def test_pallas_odd_block_sizes_clamped():
     out = flash_pl(q, k, v, None, False, 67, 130)  # odd blocks, clamped
     ref = dot_product_attention(q, k, v, None)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+def test_flash_head_128_seq_2048_causal_key_padding_matches_xla():
+    """The latent-attention decoder's shape (models/latent_moe.py: query-key
+    and value heads both 128 wide, rows of 2048, causal, padded keys), where
+    the registry's other bench shapes stop at heads of 64: forward and
+    backward against the XLA blockwise reference."""
+    B, H, S, D = 1, 1, 2048, 128
+    ks = jax.random.split(jax.random.key(128), 4)
+    q, k, v = (jax.random.normal(ks[i], (B, H, S, D), jnp.float32) * 0.5 for i in range(3))
+    t = jax.random.normal(ks[3], (B, H, S, D), jnp.float32)
+    key_bias = jnp.where(jnp.arange(S)[None, :] < 1500, 0.0, -1e30).astype(jnp.float32)
+
+    def pallas(q, k, v):
+        return flash_pl(q, k, v, key_bias, True, 256, 256)
+
+    def xla(q, k, v):
+        return flash_attention_xla(q, k, v, key_bias, block_size=512, causal=True)
+
+    np.testing.assert_allclose(pallas(q, k, v), xla(q, k, v), atol=2e-5, rtol=2e-5)
+    gp = jax.grad(lambda *a: (pallas(*a) * t).sum(), (0, 1, 2))(q, k, v)
+    gx = jax.grad(lambda *a: (xla(*a) * t).sum(), (0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gx):
+        np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-4)
+    # keys past the padding get no gradient
+    assert float(jnp.abs(gp[1][:, :, 1500:]).max()) == 0.0

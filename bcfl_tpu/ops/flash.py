@@ -108,8 +108,17 @@ def flash_attention_xla(
 
 
 def flash_attention_pallas(q, k, v, bias=None, causal: bool = False,
-                           block_q: int = 256, block_k: int = 256):
-    """TPU Pallas flash kernel; implemented in :mod:`bcfl_tpu.ops.pallas_flash`."""
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None):
+    """TPU Pallas flash kernels; implemented in :mod:`bcfl_tpu.ops.pallas_flash`.
+
+    ``block_q, block_k``: named, they go to all three kernels (forward, dKV,
+    dQ). None, which is what :func:`flash_attention` below passes for every
+    model, leaves each kernel the pair of ``pallas_flash.DEFAULT_BLOCKS``:
+    measured on a TPU v5e by ``scripts/kernel_bench.py --ops
+    flash_attention --backward --flash-blocks ...`` at this op's three
+    ``bench_shapes`` (PERF.md section 7 has the table), one request for
+    every row length, clamped to the row by ``registry.legal_block``."""
     from bcfl_tpu.ops.pallas_flash import flash_attention as _pl
 
     # positional: custom_vjp functions don't accept keyword arguments
@@ -139,11 +148,13 @@ FLASH_ATTENTION = registry.register_op(registry.KernelOp(
     bench_shapes=(
         {"label": "bert-base-B4-S512", "B": 4, "H": 12, "S": 512, "D": 64},
         {"label": "llama-decode-B1-S2048", "B": 1, "H": 8, "S": 2048,
-         "D": 64},
-        # models/latent_moe.py at its published sizes: query-key and value
-        # heads both 128 wide (pinned in tests/test_pallas_kernels.py)
-        {"label": "latent-moe-B2-H32-S2048-D128", "B": 2, "H": 32, "S": 2048,
-         "D": 128},
+         "D": 64, "causal": True},
+        # models/latent_moe.py at its published sizes, as the benchmark's
+        # cell runs it: 2 clients x 2 rows folded, query-key and value heads
+        # both 128 wide, causal, bfloat16 (pinned in
+        # tests/test_pallas_kernels.py)
+        {"label": "latent-moe-B4-H32-S2048-D128", "B": 4, "H": 32, "S": 2048,
+         "D": 128, "causal": True, "dtype": "bfloat16"},
     ),
     supports=pallas_supported,
 ))

@@ -33,6 +33,24 @@ On non-TPU backends every kernel runs in Pallas interpret mode, so CI
 exercises the exact kernel bodies on the CPU mesh (SURVEY.md §4's
 distributed-without-hardware strategy applied to kernels).
 
+Block sizes. A caller may name ``block_q, block_k``, which then go to all
+three kernels. Where nobody does (the models' dispatcher,
+:func:`bcfl_tpu.ops.flash.flash_attention`), each kernel takes its pair
+from :data:`DEFAULT_BLOCKS`, one request for every row length:
+:func:`_block_sizes` clamps it to the row (a row of 512 runs one 512 x 512
+block a head in all three kernels; a row of 2560 runs 2048-blocks with a
+masked tail). The pairs were measured on a TPU v5e, not reasoned (PR 29;
+PERF.md section 7 has the table at the registry's three bench shapes, each
+kernel alone by the device's clock): a grid step costs about a third of a
+microsecond whatever it computes, and the 256 x 256 blocks this file was
+written with are less work than that at 128-wide heads. Repeat the sweep
+on the chip with ``python scripts/kernel_bench.py --ops flash_attention
+--backward --iters 20 --flash-blocks "256,256;1024,1024;2048,2048;default"``.
+:func:`_blocks` reckons the scoped VMEM a request needs from the block
+sizes and the head width (:func:`_vmem_bytes`), asks Mosaic for it where
+that is over Mosaic's own default, and refuses named blocks that no chip's
+VMEM holds.
+
 Kernel playbook: ``/opt/skills/guides/pallas_guide.md`` (grid/BlockSpec,
 VMEM scratch, ``@pl.when`` init/finalize pattern, custom-VJP pattern).
 """
@@ -51,6 +69,24 @@ from bcfl_tpu.ops import registry
 
 NEG_INF = -1e30  # large-negative, not -inf: exp underflows to 0 without NaNs
 LANES = 128  # TPU lane width: scratch/lse last dim must be 128
+
+#: The (query, key) block each kernel is asked for when the caller names
+#: none: what ``ops.flash.flash_attention`` runs in every model. The sweep
+#: of PR 29 on a TPU v5e (``scripts/kernel_bench.py``; PERF.md section 7):
+#: at [4, 32, 2048, 128] bfloat16 causal, forward / dKV / dQ alone take
+#: 7.12 / 7.58 / 4.79 ms at 256 x 256, 2.23 / 3.08 / 2.48 at 1024 x 1024
+#: and 1.55 / 2.96 / 2.13 at whole rows of 2048 (the fastest of eleven
+#: pairs for each kernel); the dKV kernel reads 2.99 at 512 x 1024, level
+#: with its whole-row time there, and 0.232 against 0.287 ms at heads of
+#: 64 ([1, 8, 2048, 64]), so it keeps the smaller pair and Mosaic's
+#: default VMEM.
+DEFAULT_BLOCKS = {"fwd": (2048, 2048), "dkv": (512, 1024), "dq": (2048, 2048)}
+
+#: Mosaic's scoped-VMEM limit when a call sets none, and the most a call may
+#: ask for here: three quarters of the 128 MiB a TensorCore of the TPUs this
+#: runs on (v5e, v6e) has, the rest left to the compiler's own buffers.
+VMEM_DEFAULT_BYTES = 16 << 20
+VMEM_MAX_BYTES = 96 << 20
 
 
 def _interpret() -> bool:
@@ -162,12 +198,72 @@ def _block_sizes(block_q: int, block_k: int, S: int, Sk: int):
         ((block_q, S, registry.SUBLANES), (block_k, Sk, LANES)))
 
 
+#: What a kernel holds in VMEM at once, for :func:`_vmem_bytes`: ``[bq, D]``
+#: blocks and ``[bk, D]`` blocks (operands and results) and float32
+#: ``[bq, bk]`` temporaries: the forward's ``s`` and ``p`` with their mask,
+#: the backward's ``s``, ``p``, ``dp`` and ``ds``.
+_VMEM_COUNTS = {"fwd": (2, 2, 3), "dkv": (3, 4, 4), "dq": (4, 2, 4)}
+
+
+def _vmem_bytes(kernel: str, bq: int, bk: int, D: int, dtype) -> int:
+    """The scoped VMEM ``kernel`` needs at blocks ``bq x bk``, reckoned
+    from what it holds at once (:data:`_VMEM_COUNTS`): every operand and
+    result block twice (the pipeline's double buffers; the lane-padded
+    log-sum-exp and the bias row in float32), the float32 accumulators and
+    the score temporaries. An upper estimate, since the compiler shares
+    buffers: compiled for a described v5e at head widths of 64, 128 and 256
+    in bfloat16 and float32 (75 combinations), its own count was at most
+    0.9 of this one (whole rows of 2048 at 128-wide bfloat16 heads: 41.7,
+    50.2 and 41.0 MB for forward, dKV and dQ against 60.9, 80.8 and 79.8
+    here)."""
+    q_blocks, k_blocks, score_tiles = _VMEM_COUNTS[kernel]
+    item = jnp.dtype(dtype).itemsize
+    blocks = 2 * ((q_blocks * bq + k_blocks * bk) * D * item
+                  + (bq * LANES + bk) * 4)
+    scratch = (2 * max(bq, bk) * D + 2 * bq * LANES + 8 * bk) * 4
+    return blocks + scratch + score_tiles * bq * bk * 4
+
+
+def _blocks(kernel: str, block_q: Optional[int], block_k: Optional[int],
+            S: int, Sk: int, D: int, dtype):
+    """``kernel``'s legal (query, key) block and its Mosaic parameters.
+
+    The caller's blocks, else the kernel's own pair of
+    :data:`DEFAULT_BLOCKS`, clamped by :func:`_block_sizes`. A pair nobody
+    named gives way to a wide head: its longer side is halved until
+    :data:`VMEM_MAX_BYTES` hold it (the measured pairs fit heads of 256 in
+    bfloat16 whole). Named blocks that cannot fit raise with their sizes.
+    Over Mosaic's default the reckoned bytes are asked for; under it
+    nothing is."""
+    named = block_q is not None or block_k is not None
+    dq, dk = DEFAULT_BLOCKS[kernel]
+    bq, bk = _block_sizes(dq if block_q is None else block_q,
+                          dk if block_k is None else block_k, S, Sk)
+    need = _vmem_bytes(kernel, bq, bk, D, dtype)
+    while need > VMEM_MAX_BYTES and not named and max(bq, bk) > LANES:
+        bq, bk = _block_sizes(*((bq // 2, bk) if bq >= bk else (bq, bk // 2)),
+                              S, Sk)
+        need = _vmem_bytes(kernel, bq, bk, D, dtype)
+    if need > VMEM_MAX_BYTES:
+        tiles = _VMEM_COUNTS[kernel][2]
+        raise ValueError(
+            f"flash {kernel} kernel: blocks of {bq} x {bk} at a head width "
+            f"of {D} ({jnp.dtype(dtype).name}) need about {need >> 20} MiB "
+            f"of VMEM ({tiles} float32 score tiles of "
+            f"{(bq * bk * 4) >> 20} MiB each); at most "
+            f"{VMEM_MAX_BYTES >> 20} MiB can be asked for: name smaller "
+            "blocks")
+    params = (None if need <= VMEM_DEFAULT_BYTES
+              else pltpu.CompilerParams(vmem_limit_bytes=need))
+    return bq, bk, params
+
+
 def _flash_fwd_pallas(q, k, v, key_bias, causal: bool,
-                      block_q: int, block_k: int):
+                      block_q: Optional[int], block_k: Optional[int]):
     """Returns ``(out [B,H,S,D], lse [B,H,S,LANES] f32)``."""
     B, H, S, D = q.shape
     Sk = k.shape[2]
-    bq, bk = _block_sizes(block_q, block_k, S, Sk)
+    bq, bk, params = _blocks("fwd", block_q, block_k, S, Sk, D, q.dtype)
     grid = (B, H, pl.cdiv(S, bq), pl.cdiv(Sk, bk))
     scale = 1.0 / (D ** 0.5)
 
@@ -199,6 +295,7 @@ def _flash_fwd_pallas(q, k, v, key_bias, causal: bool,
             pltpu.VMEM((bq, LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, LANES), jnp.float32),  # running normalizer
         ],
+        compiler_params=params,
         interpret=_interpret(),
     )(q, k, v, key_bias)
 
@@ -324,22 +421,19 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref, do_ref, lse_ref,
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _flash_bwd_pallas(q, k, v, key_bias, out, do, lse, causal: bool,
-                      block_q: int, block_k: int):
-    """Hand-written backward: returns ``(dq, dk, dv, db[B, Sk])``."""
+def _flash_bwd_dkv_pallas(q, k, v, key_bias, out, do, lse, causal: bool,
+                          block_q: Optional[int], block_k: Optional[int]):
+    """The key side of the backward: ``(dk, dv, db[B, Sk])``."""
     B, H, S, D = q.shape
     Sk = k.shape[2]
-    bq, bk = _block_sizes(block_q, block_k, S, Sk)
-    scale = 1.0 / (D ** 0.5)
+    bq, bk, params = _blocks("dkv", block_q, block_k, S, Sk, D, q.dtype)
     nq = pl.cdiv(S, bq)
     nk = pl.cdiv(Sk, bk)
-
-    kw = dict(scale=scale, causal=causal, bq=bq, bk=bk, sq=S, sk=Sk)
-    interp = _interpret()
     key_bias = key_bias[:, None, :]  # [B, 1, Sk] — see forward BlockSpec note
 
     dk, dv, db_h = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kw),
+        functools.partial(_bwd_dkv_kernel, scale=1.0 / (D ** 0.5),
+                          causal=causal, bq=bq, bk=bk, sq=S, sk=Sk),
         grid=(B, H, nk, nq),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, ki, qi: (b, h, qi, 0)),
@@ -365,11 +459,26 @@ def _flash_bwd_pallas(q, k, v, key_bias, out, do, lse, causal: bool,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((8, bk), jnp.float32),  # db row accumulator (8-sublane)
         ],
-        interpret=interp,
+        compiler_params=params,
+        interpret=_interpret(),
     )(q, k, v, key_bias, out, do, lse)
+    # [B, Sk]: the bias is shared across heads and queries
+    return dk, dv, db_h.sum(axis=(1, 2))
 
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kw),
+
+def _flash_bwd_dq_pallas(q, k, v, key_bias, out, do, lse, causal: bool,
+                         block_q: Optional[int], block_k: Optional[int]):
+    """The query side of the backward: ``dq``."""
+    B, H, S, D = q.shape
+    Sk = k.shape[2]
+    bq, bk, params = _blocks("dq", block_q, block_k, S, Sk, D, q.dtype)
+    nq = pl.cdiv(S, bq)
+    nk = pl.cdiv(Sk, bk)
+    key_bias = key_bias[:, None, :]  # [B, 1, Sk] — see forward BlockSpec note
+
+    return pl.pallas_call(
+        functools.partial(_bwd_dq_kernel, scale=1.0 / (D ** 0.5),
+                          causal=causal, bq=bq, bk=bk, sq=S, sk=Sk),
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
@@ -383,11 +492,9 @@ def _flash_bwd_pallas(q, k, v, key_bias, out, do, lse, causal: bool,
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interp,
+        compiler_params=params,
+        interpret=_interpret(),
     )(q, k, v, key_bias, out, do, lse)
-
-    db = db_h.sum(axis=(1, 2))  # [B, Sk]: bias is shared across heads/queries
-    return dq, dk, dv, db
 
 
 # ------------------------------------------------------------------ public API
@@ -410,8 +517,14 @@ def _normalize_bias(bias, B: int, Sk: int) -> jnp.ndarray:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def flash_attention(q, k, v, bias=None, causal: bool = False,
-                    block_q: int = 256, block_k: int = 256):
-    """[B, H, S, D] x3 (+ key bias [B, Sk]) -> [B, H, S, D]."""
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None):
+    """[B, H, S, D] x3 (+ key bias [B, Sk]) -> [B, H, S, D].
+
+    ``block_q, block_k``: a named block goes to all three kernels (forward,
+    dKV, dQ); None leaves each kernel its own pair of
+    :data:`DEFAULT_BLOCKS`, the measured ones. Either way the request is
+    clamped to the row length and to Mosaic's (8, 128) rule."""
     key_bias = _normalize_bias(bias, q.shape[0], k.shape[2])
     out, _ = _flash_fwd_pallas(q, k, v, key_bias, causal, block_q, block_k)
     return out
@@ -425,8 +538,9 @@ def _vjp_fwd(q, k, v, bias, causal, block_q, block_k):
 
 def _vjp_bwd(causal, block_q, block_k, res, g):
     q, k, v, bias, key_bias, out, lse = res
-    dq, dk, dv, db = _flash_bwd_pallas(
-        q, k, v, key_bias, out, g, lse, causal, block_q, block_k)
+    args = (q, k, v, key_bias, out, g, lse, causal, block_q, block_k)
+    dk, dv, db = _flash_bwd_dkv_pallas(*args)
+    dq = _flash_bwd_dq_pallas(*args)
     if bias is None:
         return dq, dk, dv, None
     return dq, dk, dv, db.astype(bias.dtype).reshape(bias.shape)

@@ -16,17 +16,36 @@ is stamped ``plumbing_only: true`` on a non-TPU backend so a CPU artifact
 can never be mistaken for silicon evidence. On a TPU the same invocation
 needs zero new code.
 
+The ``flash_attention`` rows run what a model hands the kernels: operands
+in the bench shape's ``dtype``, its ``causal`` flag, a padded-key bias.
+``--flash-blocks "256,256;1024,1024;1024,1024/512,1024/1024,512;default"``
+adds one Pallas row a shape for each entry: a ``bq,bk`` pair for all three
+kernels, three pairs ``forward/dKV/dQ``, or ``default``
+(``pallas_flash.DEFAULT_BLOCKS``, what the models' dispatcher runs; the
+one row when the flag is absent). ``--backward`` times ``jax.grad`` of a
+sum through the op (forward and backward together) and, for the Pallas
+rows, each of the three kernels alone (``kernel_ms``). A flash row's
+``device_ms`` and ``kernel_ms`` are device time from a profiler trace (a
+short call is bound by the host's dispatch on the wall clock). Each row records
+the blocks it asked for, what the clamp made of them and the grids' step
+counts. This is the sweep ``DEFAULT_BLOCKS`` was chosen by (PERF.md
+section 7).
+
 Usage: python scripts/kernel_bench.py [--out results/kernel_bench.json]
-       [--ops int8_quantize,topk_select] [--iters N]
+       [--ops int8_quantize,topk_select] [--iters N] [--backward]
+       [--flash-blocks "bq,bk[;bq,bk...]"]
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
+import glob
 import json
 import math
 import os
 import sys
+import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -60,10 +79,13 @@ def _build(op_name: str, row: dict):
         return (x,), {"k": k}
     if op_name == "flash_attention":
         B, H, S, D = row["B"], row["H"], row["S"], row["D"]
-        q = jax.random.normal(key, (B, H, S, D), jnp.float32)
-        kk = jax.random.normal(jax.random.fold_in(key, 1), q.shape)
-        v = jax.random.normal(jax.random.fold_in(key, 2), q.shape)
-        return (q, kk, v), {}
+        q, kk, v = (jax.random.normal(jax.random.fold_in(key, i), (B, H, S, D),
+                                      jnp.dtype(row.get("dtype", "float32")))
+                    for i in range(3))
+        # padded keys: row 0 whole, each further row an eighth shorter
+        lens = S - (jnp.arange(B) % 8) * (S // 8)
+        bias = jnp.where(jnp.arange(S)[None, :] < lens[:, None], 0.0, -1e30)
+        return (q, kk, v, bias), {"causal": row.get("causal", False)}
     if op_name == "moe_grouped_matmul":
         M, K, N, G = row["M"], row["K"], row["N"], row["G"]
         # outputs of about 0.25: under 2 in magnitude, where one bfloat16
@@ -84,9 +106,14 @@ def _parity_ok(op: registry.KernelOp, ref, got) -> bool:
         return all(np.array_equal(np.asarray(a), np.asarray(b))
                    for a, b in zip(ref_l, got_l))
     # pinned-tolerance ops (flash): the tight pin lives in the op's tests;
-    # here a coarse allclose guards against timing a broken kernel
-    return all(np.allclose(np.asarray(a), np.asarray(b), atol=2e-2)
-               for a, b in zip(ref_l, got_l))
+    # here a coarse bound guards against timing a broken kernel, read
+    # against the reference's own magnitude (bfloat16 values of 4 and over
+    # are one rounding, 3e-2, apart)
+    def close(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        return np.abs(a - b).max() <= 2e-2 * max(1.0, np.abs(a).max())
+
+    return all(close(a, b) for a, b in zip(ref_l, got_l))
 
 
 def _time_ms(fn, args, iters: int) -> float:
@@ -96,7 +123,96 @@ def _time_ms(fn, args, iters: int) -> float:
     for _ in range(iters):
         out = fn(*args)
     fence(out)
-    return (time.perf_counter() - t0) / iters * 1000.0
+    return round((time.perf_counter() - t0) / iters * 1000.0, 4)
+
+
+def _device_ms(fn, args, iters: int):
+    """Device time of one call, ms: the busy time of the first TPU's "XLA
+    Ops" line over a traced loop of ``iters`` warm calls (the union of its
+    events: a loop's body nests under the loop). A call of a fraction of a
+    millisecond is bound by the host's dispatch on the wall clock (a
+    quarter of a millisecond an output array on the chip tool's machine,
+    PR 29), so block sizes at short rows can only be told apart here. None
+    off a TPU: a CPU run has no device time."""
+    if jax.default_backend() != "tpu":
+        return None
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(iters):
+                out = fn(*args)
+            fence(out)
+        (path,) = glob.glob(os.path.join(d, "plugins", "profile", "*", "*.xplane.pb"))
+        planes = jax.profiler.ProfileData.from_file(path).planes
+        (ops,) = [line for plane in planes if plane.name == "/device:TPU:0"
+                  for line in plane.lines if line.name == "XLA Ops"]
+        spans = sorted((e.start_ns, e.end_ns) for e in ops.events)
+    busy, end = 0.0, 0.0
+    for s, e in spans:
+        busy += max(0.0, e - max(s, end))
+        end = max(end, e)
+    return round(busy / iters / 1e6, 4)
+
+
+def _flash_variants(spec: str):
+    """``--flash-blocks`` -> ``[(label, {kernel: (bq, bk)} or None)]``;
+    None is ``pallas_flash.DEFAULT_BLOCKS`` as the module has it."""
+    out = []
+    for entry in (e.strip() for e in (spec or "default").split(";")):
+        if entry == "default":
+            out.append((entry, None))
+            continue
+        pairs = [tuple(int(x) for x in p.split(",")) for p in entry.split("/")]
+        if len(pairs) not in (1, 3) or any(len(p) != 2 for p in pairs):
+            raise SystemExit(f"--flash-blocks: {entry!r} is neither 'bq,bk', "
+                             "'bq,bk/bq,bk/bq,bk' (forward/dKV/dQ) nor 'default'")
+        out.append((entry, dict(zip(("fwd", "dkv", "dq"), pairs * 3))))
+    return out
+
+
+@contextlib.contextmanager
+def _flash_blocks(blocks):
+    """Run with ``pallas_flash.DEFAULT_BLOCKS`` set to ``blocks`` (None:
+    as the module has it): a kernel reads it when it is traced."""
+    from bcfl_tpu.ops import pallas_flash as pf
+
+    kept = pf.DEFAULT_BLOCKS
+    pf.DEFAULT_BLOCKS = blocks or kept
+    try:
+        yield
+    finally:
+        pf.DEFAULT_BLOCKS = kept
+
+
+def _flash_row(call_args, causal: bool, backward: bool, iters: int):
+    """What the three kernels make of ``pallas_flash.DEFAULT_BLOCKS`` at
+    this shape and, with ``backward``, each kernel's own time."""
+    from bcfl_tpu.ops import pallas_flash as pf
+
+    q, k, v, bias = call_args
+    B, H, S, D = q.shape
+    Sk = k.shape[2]
+    legal = {name: pf._blocks(name, None, None, S, Sk, D, q.dtype)[:2]
+             for name in pf.DEFAULT_BLOCKS}
+    info = {
+        "blocks_requested": {n: list(p) for n, p in pf.DEFAULT_BLOCKS.items()},
+        "blocks": {n: list(p) for n, p in legal.items()},
+        "grid_steps": {n: B * H * -(-S // bq) * -(-Sk // bk)
+                       for n, (bq, bk) in legal.items()},
+    }
+    if backward:
+        fwd = jax.jit(lambda q, k, v, b: pf._flash_fwd_pallas(
+            q, k, v, b, causal, None, None))
+        out, lse = fwd(q, k, v, bias)
+        bwd_args = (q, k, v, bias, out, jnp.ones_like(out), lse)
+        dkv = jax.jit(lambda *a: pf._flash_bwd_dkv_pallas(*a, causal, None, None))
+        dq = jax.jit(lambda *a: pf._flash_bwd_dq_pallas(*a, causal, None, None))
+        # each kernel alone in a program of its own; on a TPU by the
+        # device's clock
+        info["kernel_ms"] = {
+            name: _device_ms(f, a, iters) or _time_ms(f, a, iters)
+            for name, f, a in (("fwd", fwd, (q, k, v, bias)),
+                               ("dkv", dkv, bwd_args), ("dq", dq, bwd_args))}
+    return info
 
 
 def main() -> int:
@@ -107,6 +223,13 @@ def main() -> int:
     ap.add_argument("--iters", type=int, default=0,
                     help="timed iterations (default: 3 on TPU, 1 off-TPU "
                          "plumbing)")
+    ap.add_argument("--backward", action="store_true",
+                    help="flash_attention: time jax.grad of a sum through "
+                         "the op, and each Pallas kernel alone")
+    ap.add_argument("--flash-blocks", default="",
+                    help="flash_attention: 'bq,bk[;bq,bk...]', an entry "
+                         "'f,f/k,k/q,q' names forward/dKV/dQ apart, "
+                         "'default' is pallas_flash.DEFAULT_BLOCKS")
     args = ap.parse_args()
 
     compile_cache()
@@ -120,10 +243,15 @@ def main() -> int:
     rows = []
     for name in names:
         op = registry.get_op(name)  # loud rejection of a typo'd --ops
+        flash = name == "flash_attention"
         for shape in op.bench_shapes:
             call_args, kw = _build(name, shape)
             ref = None
-            for impl in ("xla", "pallas"):
+            # one Pallas row, or one for each --flash-blocks entry
+            pallas_rows = (_flash_variants(args.flash_blocks) if flash
+                           else [(None, None)])
+            for impl, (label, blocks) in [("xla", (None, None))] + [
+                    ("pallas", v) for v in pallas_rows]:
                 fn, resolved = registry.select(name, impl, *call_args, **kw)
                 row = {
                     "op": name,
@@ -135,31 +263,42 @@ def main() -> int:
                     "backend": backend,
                     "plumbing_only": plumbing,
                 }
+                if label:
+                    row["flash_blocks"] = label
+                rows.append(row)
                 if impl == "pallas" and not op.has_pallas:
                     row["status"] = "no_pallas_impl"
-                    rows.append(row)
                     continue
                 if impl == "pallas" and resolved != "pallas":
                     # the op's static predicate turns this shape away (e.g.
                     # a top-k row wider than the VMEM budget) — recorded,
                     # never hidden: production serves it from the reference
                     row["status"] = "declined"
-                    rows.append(row)
                     continue
-                jfn = jax.jit(lambda *a, _f=fn: _f(*a, **kw))
-                out = jfn(*call_args)
-                fence(out)
-                if impl == "xla":
-                    ref = out
+                if flash and args.backward:
+                    row["timed"] = "grad of a sum (forward + backward)"
+                    jfn = jax.jit(jax.grad(
+                        lambda q, k, v, b, _f=fn: _f(q, k, v, b, **kw).astype(
+                            jnp.float32).sum(), argnums=(0, 1, 2)))
                 else:
-                    row["parity_ok"] = _parity_ok(op, ref, out)
-                    if not row["parity_ok"]:
-                        row["status"] = "parity_violation"
-                        rows.append(row)
-                        continue  # never time a wrong kernel
-                row["wall_ms"] = round(_time_ms(jfn, call_args, iters), 4)
+                    jfn = jax.jit(lambda *a, _f=fn: _f(*a, **kw))
+                with _flash_blocks(blocks):
+                    out = jfn(*call_args)
+                    fence(out)
+                    if impl == "xla":
+                        ref = out
+                    else:
+                        row["parity_ok"] = _parity_ok(op, ref, out)
+                        if not row["parity_ok"]:
+                            row["status"] = "parity_violation"
+                            continue  # never time a wrong kernel
+                    row["wall_ms"] = _time_ms(jfn, call_args, iters)
+                    if flash:
+                        row["device_ms"] = _device_ms(jfn, call_args, iters)
+                    if flash and impl == "pallas":
+                        row.update(_flash_row(call_args, kw["causal"],
+                                              args.backward, iters))
                 row["status"] = "ok"
-                rows.append(row)
     doc = {
         "backend": backend,
         "device_kind": jax.devices()[0].device_kind,

@@ -179,3 +179,175 @@ def test_flash_head_128_seq_2048_causal_key_padding_matches_xla():
         np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-4)
     # keys past the padding get no gradient
     assert float(jnp.abs(gp[1][:, :, 1500:]).max()) == 0.0
+
+
+# ---- the blocks the models' dispatcher runs (pallas_flash.DEFAULT_BLOCKS) ----
+
+_DEFAULT_BLOCK_CASES = {
+    # the benchmark's expert-decoder cell: the request divides the row
+    "cell-2048-d128-causal-padded": dict(shape=(1, 1, 2048, 128), sk=2048, causal=True, keep=1500),
+    # a row shorter than the request: one clamped block a head
+    "row-512-d64-key-bias": dict(shape=(2, 2, 512, 64), sk=512, causal=False, keep=400, noise=True),
+    # a row no power of two divides: whole-row blocks of 1536, or a masked
+    # tail after the 1024 keys of a dKV block
+    "row-1536-d64-causal": dict(shape=(1, 2, 1536, 64), sk=1536, causal=True, keep=None),
+    # a row longer than every request: 2048-blocks with a masked tail of 512
+    "row-2560-d64-causal": dict(shape=(1, 1, 2560, 64), sk=2560, causal=True, keep=None),
+    # fewer queries than keys: suffix alignment across a key-block boundary
+    "sq-256-sk-1280-causal": dict(shape=(1, 2, 256, 64), sk=1280, causal=True, keep=None),
+}
+
+
+@pytest.mark.parametrize("case", list(_DEFAULT_BLOCK_CASES))
+def test_default_blocks_match_xla_and_256_blocks(case):
+    """The kernels at the dispatcher's default blocks, forward and every
+    gradient (dq, dk, dv and the key bias's), against the XLA blockwise
+    reference and against the same kernels at the 256 x 256 blocks they ran
+    before PR 29: block sizes change the order of float32 sums and nothing
+    else."""
+    c = _DEFAULT_BLOCK_CASES[case]
+    B, H, S, D = c["shape"]
+    Sk = c["sk"]
+    ks = jax.random.split(jax.random.key(29), 5)
+    q = jax.random.normal(ks[0], (B, H, S, D), jnp.float32) * 0.5
+    k, v = (jax.random.normal(ks[i], (B, H, Sk, D), jnp.float32) * 0.5 for i in (1, 2))
+    t = jax.random.normal(ks[3], (B, H, S, D), jnp.float32)
+    bias = jnp.zeros((B, Sk), jnp.float32)
+    if c.get("noise"):
+        bias = bias + jax.random.normal(ks[4], (B, Sk), jnp.float32)
+    if c["keep"] is not None:
+        bias = jnp.where(jnp.arange(Sk)[None, :] < c["keep"], bias, -1e30)
+
+    def outs(fn):
+        def loss(*a):
+            out = fn(*a)
+            return (out * t).sum(), out
+        grads, out = jax.grad(loss, argnums=(0, 1, 2, 3), has_aux=True)(q, k, v, bias)
+        return (out,) + grads
+
+    got = outs(lambda q, k, v, b: flash_pl(q, k, v, b, c["causal"]))
+    old = outs(lambda q, k, v, b: flash_pl(q, k, v, b, c["causal"], 256, 256))
+    ref = outs(lambda q, k, v, b: flash_attention_xla(
+        q, k, v, b, block_size=256, causal=c["causal"]))
+    for name, a, b256, x in zip(("out", "dq", "dk", "dv", "dbias"), got, old, ref):
+        np.testing.assert_allclose(a, x, atol=5e-5, rtol=5e-4, err_msg=f"{name} vs xla")
+        np.testing.assert_allclose(a, b256, atol=5e-5, rtol=5e-4, err_msg=f"{name} vs 256")
+    if c["keep"] is not None:  # keys past the padding get no gradient
+        assert float(jnp.abs(got[2][:, :, c["keep"]:]).max()) == 0.0
+
+
+def _pallas_grids(fn, *args):
+    """``{kernel name: grid}`` of the Pallas calls in ``fn``'s jaxpr."""
+    found = {}
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                found[e.params["jaxpr"].debug_info.func_name] = tuple(
+                    e.params["grid_mapping"].grid)
+            for val in e.params.values():
+                for j in (val if isinstance(val, (list, tuple)) else [val]):
+                    inner = getattr(j, "jaxpr", j)
+                    if hasattr(inner, "eqns"):
+                        walk(inner)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def test_dispatcher_hands_each_kernel_its_default_blocks(monkeypatch):
+    """``ops.flash.flash_attention`` under ``impl == "pallas"`` names no
+    block, so each of the three kernels runs its own pair of
+    ``pallas_flash.DEFAULT_BLOCKS`` (read off the jaxpr's grids: three
+    distinct pairs here, then the module's own); blocks named to
+    ``flash_attention_pallas`` reach all three."""
+    from bcfl_tpu.ops import flash as flash_mod, pallas_flash, registry
+
+    monkeypatch.setattr(registry, "pallas_by_default", lambda: True)
+    B, H, S, D = 1, 2, 2048, 16
+    q = jnp.ones((B, H, S, D), jnp.float32)
+    key_bias = jnp.zeros((B, S), jnp.float32)
+
+    def through(attend):
+        return _pallas_grids(
+            jax.grad(lambda q, k, v: attend(q, k, v, key_bias, causal=True).sum(), (0, 1, 2)),
+            q, q, q)
+
+    def grids(blocks):  # dKV's grid runs (key blocks, query blocks)
+        (fq, fk), (kq, kk), (dq, dk) = (
+            pallas_flash._block_sizes(*blocks[name], S, S) for name in ("fwd", "dkv", "dq"))
+        return {"_fwd_kernel": (B, H, -(-S // fq), -(-S // fk)),
+                "_bwd_dkv_kernel": (B, H, -(-S // kk), -(-S // kq)),
+                "_bwd_dq_kernel": (B, H, -(-S // dq), -(-S // dk))}
+
+    assert through(flash_mod.flash_attention) == grids(pallas_flash.DEFAULT_BLOCKS)
+    apart = {"fwd": (512, 256), "dkv": (256, 1024), "dq": (1024, 512)}
+    monkeypatch.setattr(pallas_flash, "DEFAULT_BLOCKS", apart)
+    assert through(flash_mod.flash_attention) == {
+        "_fwd_kernel": (B, H, 4, 8), "_bwd_dkv_kernel": (B, H, 2, 8),
+        "_bwd_dq_kernel": (B, H, 2, 4)} == grids(apart)
+    named = through(lambda *a, **kw: flash_mod.flash_attention_pallas(
+        *a, block_q=128, block_k=512, **kw))
+    assert named == {"_fwd_kernel": (B, H, 16, 4), "_bwd_dkv_kernel": (B, H, 4, 16),
+                     "_bwd_dq_kernel": (B, H, 16, 4)}
+
+
+def test_vmem_request_is_reckoned_from_the_blocks_and_refused_past_the_chip():
+    """Small blocks ask Mosaic for nothing; score tiles past its default ask
+    for what they need; named blocks no chip holds raise with their sizes;
+    the default request gives way to a wide head and is never refused."""
+    from bcfl_tpu.ops import pallas_flash as pf
+
+    for kernel in ("fwd", "dkv", "dq"):
+        assert pf._blocks(kernel, 256, 256, 2048, 2048, 128, jnp.bfloat16) == (256, 256, None)
+        bq, bk, big = pf._blocks(kernel, 2048, 1024, 4096, 4096, 128, jnp.bfloat16)
+        tiles = pf._VMEM_COUNTS[kernel][2] * 2048 * 1024 * 4
+        assert (bq, bk) == (2048, 1024)
+        assert tiles < big.vmem_limit_bytes <= pf.VMEM_MAX_BYTES
+        with pytest.raises(ValueError, match=r"4096 x 4096 at a head width of 128"):
+            pf._blocks(kernel, 4096, 4096, 4096, 4096, 128, jnp.bfloat16)
+        # the measured pairs hold whole at the widths the models have ...
+        for D, dtype in ((64, jnp.float32), (128, jnp.bfloat16), (256, jnp.bfloat16)):
+            assert pf._blocks(kernel, None, None, 8192, 8192, D, dtype)[:2] == pf.DEFAULT_BLOCKS[kernel]
+        # ... and give way, legal still, where a head is too wide for them
+        bq, bk, _ = pf._blocks(kernel, None, None, 8192, 8192, 4096, jnp.float32)
+        dq, dk = pf.DEFAULT_BLOCKS[kernel]
+        assert bq * bk < dq * dk and bq % 8 == 0 and bk % 128 == 0
+        assert pf._vmem_bytes(kernel, bq, bk, 4096, jnp.float32) <= pf.VMEM_MAX_BYTES
+    # the refusal reaches a caller through the public function
+    q = jnp.ones((1, 1, 4096, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="name smaller blocks"):
+        flash_pl(q, q, q, None, True, 4096, 4096)
+
+
+def test_kernel_bench_flash_rows_run_what_the_shape_declares():
+    """scripts/kernel_bench.py: ``--flash-blocks`` entries and the operands
+    of a flash row (the bench shape's dtype and causal flag, a padded-key
+    bias), and the latent-attention bench shape is the cell's folded batch."""
+    import importlib.util
+    import pathlib
+
+    from bcfl_tpu.ops.flash import FLASH_ATTENTION
+
+    path = pathlib.Path(__file__).resolve().parents[1] / "scripts" / "kernel_bench.py"
+    spec = importlib.util.spec_from_file_location("kernel_bench", path)
+    kb = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kb)
+
+    assert kb._flash_variants("") == [("default", None)]
+    assert kb._flash_variants("256,256;2048,2048/512,1024/1024,2048;default") == [
+        ("256,256", {"fwd": (256, 256), "dkv": (256, 256), "dq": (256, 256)}),
+        ("2048,2048/512,1024/1024,2048",
+         {"fwd": (2048, 2048), "dkv": (512, 1024), "dq": (1024, 2048)}),
+        ("default", None)]
+    with pytest.raises(SystemExit):
+        kb._flash_variants("256")
+
+    (cell,) = [r for r in FLASH_ATTENTION.bench_shapes if r["D"] == 128]
+    assert (cell["B"], cell["H"], cell["S"]) == (4, 32, 2048)
+    assert cell["causal"] is True and cell["dtype"] == "bfloat16"
+    tiny = dict(cell, B=3, H=1, S=64, D=8)
+    (q, k, v, bias), kw = kb._build("flash_attention", tiny)
+    assert q.dtype == k.dtype == v.dtype == jnp.bfloat16 and kw == {"causal": True}
+    assert bias.shape == (3, 64) and bias.dtype == jnp.float32
+    assert [int((row == 0).sum()) for row in bias] == [64, 56, 48]  # padded keys

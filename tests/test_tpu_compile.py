@@ -1,0 +1,78 @@
+"""The flash kernels compiled for a DESCRIBED TPU v5e, with no chip attached
+(the TPU's compiler is installed here): what interpret mode cannot show,
+above all whether a block request fits the kernel's scoped VMEM. Nothing
+runs, so nothing here is a time or a result.
+
+The topology is described inside a fixture and these tests stay in this one
+file: only one process may load the TPU's library, and under xdist every
+worker imports every test file."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from bcfl_tpu.ops import pallas_flash, registry
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_the_chip(monkeypatch):
+    """Compiled kernels, not interpreted ones, and no persistent cache: an
+    entry compiled for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(registry, "interpret_mode", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile_grad(one_chip, S, D, dtype, block_q=None, block_k=None):
+    q = jax.ShapeDtypeStruct((1, 2, S, D), dtype, sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((1, S), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v, b):
+        out = pallas_flash.flash_attention(q, k, v, b, True, block_q, block_k)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2, 3))).lower(q, q, q, bias).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3  # forward, dKV, dQ
+    return text
+
+
+@pytest.mark.parametrize("S,D,dtype", [
+    (2048, 128, jnp.bfloat16),  # the expert decoder's cell
+    (2048, 64, jnp.bfloat16),   # models/llama.py's heads
+    (512, 64, jnp.bfloat16),    # a row under every request: one block a head
+    (4096, 256, jnp.float32),   # a head the dQ request gives way to
+], ids=["s2048-d128-bf16", "s2048-d64-bf16", "s512-d64-bf16", "s4096-d256-f32"])
+def test_flash_kernels_compile_at_the_default_blocks(one_chip, for_the_chip, S, D, dtype):
+    _compile_grad(one_chip, S, D, dtype)
+
+
+def test_whole_row_blocks_compile_only_with_the_reckoned_vmem_request(
+        one_chip, for_the_chip, monkeypatch):
+    """2048 x 1024 score tiles need more scoped VMEM than Mosaic's default:
+    with the request ``_blocks`` reckons they compile, without it (the
+    default taken for boundless, so nothing is asked for) the compiler
+    refuses them."""
+    _compile_grad(one_chip, 4096, 128, jnp.bfloat16, 2048, 1024)
+    monkeypatch.setattr(pallas_flash, "VMEM_DEFAULT_BYTES", 1 << 40)
+    with pytest.raises(Exception, match="vmem"):
+        _compile_grad(one_chip, 4096, 128, jnp.bfloat16, 2048, 1024)

@@ -742,8 +742,7 @@ class FedConfig:
     # PER-CLIENT staleness decay inside the merge (a stale arrival votes at
     # full strength); the global step-size rescale (_async_merge_scale)
     # still shrinks the applied delta, so staleness dampens the step, not
-    # the vote. gspmd impl only (the default); impl="shard_map" supports
-    # "mean" only.
+    # the vote.
     aggregator: str = "mean"
     # assumed Byzantine fraction for trimmed_mean/krum, in [0, 0.5)
     aggregator_trim: float = 0.2
@@ -783,7 +782,7 @@ class FedConfig:
     # kind ∈ none/int8/topk/int8+topk — quantized and/or sparsified client
     # deltas with error-feedback residuals, compiled INTO the round
     # programs. 'none' (default) is bit-identical to the uncompressed
-    # programs. gspmd impl only; the faithful host-sequential mode has no
+    # programs. The faithful host-sequential mode has no
     # transport stage to compress (rejected below). kernel_impl ∈
     # auto/xla/pallas selects the codec kernels (PERF.md "Custom
     # kernels"); every impl's payload is byte-identical, so it never

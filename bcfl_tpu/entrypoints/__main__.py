@@ -450,7 +450,7 @@ def main(argv=None):
         if comp_kw["kind"] == "none" and args.compress != "none":
             # a codec sub-flag with no codec selected would silently ship
             # full-precision trees under a compression-tweak label — the
-            # same fail-loudly stance as the shard_map/bench rejections
+            # same fail-loudly stance as the bench's rejections
             raise SystemExit(
                 "--compress-topk/--compress-chunk/--no-compress-ef have no "
                 "effect without a codec: add --compress "

@@ -6,14 +6,15 @@ Reference equivalents (SURVEY.md §3):
 - local step (hot loop): 1-epoch AdamW lr=5e-5 full fine-tune, fresh optimizer
   per round — ``train``, ``src/Servercase/server_IID_IMDB.py:108-118`` and
   ``IMDBClient.train_model``, ``serverless_NonIID_IMDB.py:188-199``. Here it is
-  a ``lax.scan`` over static-shape batches, vmapped over the stacked clients of
-  each device, ``shard_map``-ped over the mesh.
+  a ``lax.scan`` over static-shape batches, vmapped over the stacked clients;
+  the client dim is sharded over the mesh and XLA's SPMD partitioner inserts
+  the collectives (:mod:`bcfl_tpu.parallel.gspmd`).
 - server aggregation: Flower FedAvg (``server_IID_IMDB.py:205-218``) ->
-  :func:`bcfl_tpu.parallel.masked_weighted_mean` (psum).
+  :func:`bcfl_tpu.parallel.gspmd.masked_weighted_mean` (all-reduce).
 - serverless aggregation: all-client unweighted mean
   (``serverless_NonIID_IMDB.py:296``) -> masked ring gossip
-  (:func:`bcfl_tpu.parallel.gossip_mix`, ppermute) or exact mean when
-  ``gossip_steps == 0``.
+  (:func:`bcfl_tpu.parallel.gspmd.gossip_mix`, collective-permute) or exact
+  mean when ``gossip_steps == 0``.
 
 Trainable tree is either the full param tree (reference behaviour) or a LoRA
 adapter tree over a frozen base (``frozen``), chosen by the engine; the round
@@ -29,7 +30,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax, shard_map
+from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bcfl_tpu.compression import CompressionConfig, codecs as cc
@@ -38,7 +39,6 @@ from bcfl_tpu.ledger.fingerprint import client_fingerprint, tree_fingerprint
 from bcfl_tpu.metrics.tracing import scope
 from bcfl_tpu.models import lora as lora_lib
 from bcfl_tpu.parallel import gspmd
-from bcfl_tpu.parallel.collectives import gossip_mix, masked_weighted_mean
 
 Tree = Any
 
@@ -176,7 +176,7 @@ def _unstack_rng(r, impl=None):
 
 def make_eval_one(loss_fn) -> Callable:
     """(trainable, frozen, batches) -> summed [loss*n, correct, n] over the
-    scanned eval batches. Shared by both program implementations."""
+    scanned eval batches."""
 
     def eval_one(trainable, frozen, batches):
         def step(carry, batch):
@@ -205,9 +205,8 @@ def make_broadcast(mesh: ClientMesh) -> Callable:
 
 def _adopt_pull(client_t: Tree, global_t: Tree, pull: jnp.ndarray) -> Tree:
     """Pull-masked clients adopt the replicated ``global_t`` (broadcast
-    fused into the select); everyone else keeps their stacked row. THE
-    definition of the ``adopt`` program body — both impl builders wrap
-    exactly this, so the select semantics cannot drift between them."""
+    fused into the select); everyone else keeps their stacked row: the
+    ``adopt`` program's body."""
     return jax.tree.map(
         lambda x, g: jnp.where(
             pull.reshape((-1,) + (1,) * (x.ndim - 1)) > 0,
@@ -217,8 +216,8 @@ def _adopt_pull(client_t: Tree, global_t: Tree, pull: jnp.ndarray) -> Tree:
 
 def _exact_mean_spread(avg: Tree, new_t: Tree, mask: jnp.ndarray) -> Tree:
     """Serverless exact-mean aggregation: every unmasked client adopts the
-    (mask-weighted) average, masked clients keep their own state. Shared by
-    both implementations' ``gossip_steps == 0`` path."""
+    (mask-weighted) average, masked clients keep their own state (the
+    ``gossip_steps == 0`` path)."""
     return jax.tree.map(
         lambda a, x: jnp.where(
             mask.reshape((-1,) + (1,) * (x.ndim - 1)) > 0,
@@ -287,26 +286,31 @@ class FedPrograms:
     local_updates: Callable  # (client_t, frozen, batches, rngs) -> (stacked_t, metrics)
     mix_only: Callable  # (client_t, mask, start_t) -> client_t (gossip mix / full mean)
     single_update: Callable  # (trainable, frozen, batches, rng) -> (trainable, stats);
-    # un-shard_mapped single client, used by the reference-faithful sequential
+    # one unstacked client, used by the reference-faithful sequential
     # serverless mode (SURVEY.md §3.2)
     # device-side ledger digests (bcfl_tpu.ledger.fingerprint) — [C, K] / [K]
     # content fingerprints so the ledger never pulls the full tree to host:
-    fingerprint: Optional[Callable] = None  # stacked client_t -> [C, K]
-    fingerprint_one: Optional[Callable] = None  # trainable -> [K]
+    fingerprint: Callable  # stacked client_t -> [C, K]
+    fingerprint_one: Callable  # trainable -> [K]
     # transport-aware serverless mix for the split-phase corruption flow
     # (faults.FaultPlan): (self_t, recv_t, mask, start_t) -> client_t —
     # neighbor/aggregate terms from the TRANSPORTED tree, self-terms from
-    # the honest local tree (gspmd impl only)
-    mix_recv: Optional[Callable] = None
+    # the honest local tree
+    mix_recv: Callable
     # (client_t, global_t, pull) -> client_t: pull-masked clients adopt the
     # replicated global (broadcast fused into the select — ONE dispatch, no
     # materialized [C, ...] broadcast buffer). Used by the async engine's
     # post-merge pull and the chaos-partition scatter/heal (component
-    # members adopt their component aggregate / the reconciled global);
-    # both impls compile it.
-    adopt: Optional[Callable] = None
-    # --- communication-compression programs (COMPRESSION.md; gspmd impl
-    # only, present iff the builder's CompressionConfig is enabled). When
+    # members adopt their component aggregate / the reconciled global).
+    adopt: Callable
+    # fused-round twins that ALSO emit each round's per-client update
+    # fingerprints [R, C, K], so the ledger can fuse:
+    server_rounds_fp: Callable
+    server_rounds_static_fp: Callable
+    gossip_rounds_fp: Callable
+    gossip_rounds_static_fp: Callable
+    # --- communication-compression programs (COMPRESSION.md), present iff
+    # the builder's CompressionConfig is enabled. When
     # compression is on, the round/fused programs above change signature:
     # their first argument and first result become the carry tuple
     # ``(params_tree, ef_residual)`` — the error-feedback residual rides the
@@ -334,12 +338,6 @@ class FedPrograms:
     corrupt_payload: Optional[Callable] = None
     # (trainable_like) -> [C, ...] f32 zero error-feedback state
     ef_init: Optional[Callable] = None
-    # fused-round twins that ALSO emit each round's per-client update
-    # fingerprints [R, C, K] (gspmd impl only — the ledger can then fuse):
-    server_rounds_fp: Optional[Callable] = None
-    server_rounds_static_fp: Optional[Callable] = None
-    gossip_rounds_fp: Optional[Callable] = None
-    gossip_rounds_static_fp: Optional[Callable] = None
 
 
 def build_programs(
@@ -353,8 +351,7 @@ def build_programs(
     task: str = "classification",
     # Byzantine-robust aggregation rule (parallel.gspmd.AGGREGATORS,
     # ROBUSTNESS.md). A build-time static: each choice is its own compiled
-    # program, so switching it never retraces inside a run. gspmd impl only;
-    # shard_map supports "mean".
+    # program, so switching it never retraces inside a run.
     aggregator: str = "mean",
     aggregator_trim: float = 0.2,
     # typed-key impl for the stacked per-client rngs: None follows jax's
@@ -366,7 +363,7 @@ def build_programs(
     # its own compiled program set (the config is part of the program-cache
     # key below), so switching codecs never retraces inside a run. None or
     # kind='none' builds EXACTLY today's uncompressed programs — that path
-    # is untouched, bit-for-bit. gspmd impl only.
+    # is untouched, bit-for-bit.
     compression: Optional[CompressionConfig] = None,
     # donate=True deletes the caller's input param/opt buffers after each call
     # (halves peak HBM for the round-chained engine); leave False if you reuse
@@ -376,20 +373,9 @@ def build_programs(
     # aggregation (gspmd.hierarchical_weighted_mean) into every mean
     # aggregation point — cohort mode's within-cohort-then-cross-device
     # reduction (SCALING.md). Only meaningful for aggregator='mean' (the
-    # robust order statistics are global by definition) and only the gspmd
-    # impl compiles it; normalized away otherwise so equal program sets
-    # share one cache entry.
+    # robust order statistics are global by definition); normalized away
+    # otherwise so equal program sets share one cache entry.
     hierarchical: bool = False,
-    # Two numerically-identical implementations of the same programs:
-    #   "gspmd"     (default) — global stacked-client arrays under plain jit
-    #               with sharding annotations; XLA's SPMD partitioner inserts
-    #               the collectives. ~200x faster than shard_map in the one
-    #               bisection on record (PERF.md "Earlier recordings").
-    #   "shard_map" — explicit psum/ppermute manual SPMD
-    #               (bcfl_tpu.parallel.collectives).
-    # Parity between them is pinned by tests/test_gspmd_impl.py. Override the
-    # default with BCFL_FED_IMPL.
-    impl: str = "auto",
     # per-client LoRA rank tuple (FedConfig.client_lora_ranks) for
     # HETEROGENEOUS fleets: every client is materialized zero-padded at
     # max(lora_ranks), the [C, R] padding mask compiles in as a closure
@@ -400,8 +386,6 @@ def build_programs(
     # uniform tuple builds EXACTLY the plain programs.
     lora_ranks: Optional[tuple] = None,
 ) -> FedPrograms:
-    if impl == "auto":
-        impl = os.environ.get("BCFL_FED_IMPL", "gspmd")
     if lora_ranks is not None and len(set(lora_ranks)) <= 1:
         # uniform spec == plain build: the all-ones clip would be a
         # different (wastefully retraced) program computing the identity
@@ -431,7 +415,7 @@ def build_programs(
         # mesh field, including any added later that changes program layout
         key = (model, mesh, optimizer, learning_rate, max_grad_norm,
                gossip_alpha, gossip_steps, task, aggregator, aggregator_trim,
-               prng_impl, donate, impl, compression, hierarchical, lora_ranks)
+               prng_impl, donate, compression, hierarchical, lora_ranks)
         hash(key)
     except TypeError:
         key = None
@@ -439,13 +423,13 @@ def build_programs(
         key = None
     if key is not None and key in _PROGRAM_CACHE:
         return _PROGRAM_CACHE[key]
-    progs = _build_programs_dispatch(
+    progs = _build_programs(
         model, mesh, optimizer=optimizer, learning_rate=learning_rate,
         max_grad_norm=max_grad_norm, gossip_alpha=gossip_alpha,
         gossip_steps=gossip_steps, donate=donate, task=task,
         aggregator=aggregator, aggregator_trim=aggregator_trim,
         prng_impl=prng_impl, compression=compression,
-        hierarchical=hierarchical, impl=impl, lora_ranks=lora_ranks)
+        hierarchical=hierarchical, lora_ranks=lora_ranks)
     if key is not None:
         while len(_PROGRAM_CACHE) >= _PROGRAM_CACHE_MAX:
             # FIFO eviction bounds the compiled-executable footprint over a
@@ -466,369 +450,26 @@ def clear_program_cache() -> None:
     _PROGRAM_CACHE.clear()
 
 
-def _build_programs_dispatch(
+def _build_programs(
     model,
     mesh: ClientMesh,
+    *,
     optimizer: str,
     learning_rate: float,
     max_grad_norm: float,
     gossip_alpha: float,
     gossip_steps: int,
+    donate: bool,
     task: str,
     aggregator: str,
     aggregator_trim: float,
     prng_impl: Optional[str],
     compression: Optional[CompressionConfig],
-    donate: bool,
     hierarchical: bool,
-    impl: str,
-    lora_ranks: Optional[tuple] = None,
+    lora_ranks: Optional[tuple],
 ) -> FedPrograms:
-    if impl == "gspmd":
-        return _build_programs_gspmd(
-            model, mesh, optimizer=optimizer, learning_rate=learning_rate,
-            max_grad_norm=max_grad_norm, gossip_alpha=gossip_alpha,
-            gossip_steps=gossip_steps, donate=donate, task=task,
-            aggregator=aggregator, aggregator_trim=aggregator_trim,
-            prng_impl=prng_impl, compression=compression,
-            hierarchical=hierarchical, lora_ranks=lora_ranks)
-    if impl != "shard_map":
-        raise ValueError(f"unknown fed impl {impl!r}")
-    if lora_ranks is not None:
-        # the rank-aware RBLA aggregation is global-array math over the full
-        # stacked client dim (per-rank-dim normalization needs every
-        # client's mask row at once); the manual-SPMD twin has no form of it
-        raise ValueError(
-            "heterogeneous lora_ranks require impl='gspmd' (unset "
-            "BCFL_FED_IMPL or set it to 'gspmd'); the shard_map twin has no "
-            "rank-aware aggregation and would dilute low-rank clients")
-    if hierarchical:
-        # the explicit two-level reduction is global-array math over the
-        # full stacked client dim — the manual-SPMD twin would need its own
-        # psum-within-psum form; only the GSPMD programs compile it
-        raise ValueError(
-            "hierarchical aggregation (cohort mode) requires impl='gspmd' "
-            "(unset BCFL_FED_IMPL or set it to 'gspmd')")
-    if compression is not None and compression.enabled:
-        # same gap class as the robust aggregators below (both documented in
-        # ROBUSTNESS.md §5): the codecs are global-array math over the full
-        # stacked client dim, and the shard_map twin would need its own
-        # manual-SPMD encode/decode + an error-feedback carry threaded
-        # through every program signature — only the GSPMD programs compile
-        # them today. Failing loudly beats silently shipping full-precision
-        # trees under a compress=... label.
-        raise ValueError(
-            f"compress={compression.kind!r} requires impl='gspmd' (unset "
-            "BCFL_FED_IMPL or set it to 'gspmd'); the shard_map twin has no "
-            "codec path and would silently exchange uncompressed updates")
-    if aggregator != "mean":
-        # the robust rules are order statistics over the GLOBAL client dim;
-        # inside a shard_map body each device sees only its local stack, so
-        # a faithful manual-SPMD form needs an all-gather the twin deliberately
-        # avoids — only the GSPMD programs compile them today
-        raise ValueError(
-            f"aggregator={aggregator!r} requires impl='gspmd' (unset "
-            "BCFL_FED_IMPL or set it to 'gspmd'); the shard_map twin "
-            "implements 'mean' only")
-    if getattr(mesh, "tp", 1) > 1:
-        # the manual-SPMD twin would replicate each client's compute over the
-        # tp axis instead of sharding it; only GSPMD composes clients x tp
-        raise ValueError(
-            "clients x tp meshes require impl='gspmd' (unset BCFL_FED_IMPL "
-            "or set it to 'gspmd' when tp > 1)")
-    if getattr(mesh, "sp", 1) > 1:
-        # same story for the (clients, seq) mesh: these specs only name the
-        # clients axis, and the model's ring-attention override constrains on
-        # the full mesh — inside a shard_map body that either errors or
-        # silently replicates the sequence dimension
-        raise ValueError(
-            "clients x seq meshes require impl='gspmd' (unset BCFL_FED_IMPL "
-            "or set it to 'gspmd' when sp > 1)")
-    tx = make_optimizer(optimizer, learning_rate, max_grad_norm)
-    loss_fn = make_loss_fn(model, task)
-    unstack = lambda r: _unstack_rng(r, prng_impl)  # noqa: E731
-    axis = mesh.axis
-    jmesh = mesh.mesh
-    repl = P()
-    shard = P("clients")
-
-    # ---- one client's local round: fresh opt state, scan over batches ----
-    local_train = make_local_train(tx, loss_fn)
-
-    @scope("aggregate")
-    def agg(stacked_t, weights, fallback):
-        return masked_weighted_mean(stacked_t, weights, axis, fallback=fallback)
-
-    # ---- server mode: everyone trains from the SAME global trainable ----
-    # single source of truth for one FedAvg round; the per-round program and
-    # the scanned multi-round fast path below both apply exactly this body
-    def server_shard(global_t, frozen, batches, weights, rngs):
-        def per_client(b, r):
-            return local_train(global_t, frozen, b, unstack(r))
-
-        new_t, stats = jax.vmap(per_client)(batches, rngs)
-        # all-masked round -> keep the round's starting params, don't zero them
-        return agg(new_t, weights, global_t), stats
-
-    server_round = jax.jit(
-        shard_map(
-            server_shard, mesh=jmesh,
-            in_specs=(repl, repl, shard, shard, shard),
-            out_specs=(repl, shard),
-            check_vma=False,
-        ),
-        donate_argnums=(0,) if donate else (),
-    )
-
-    # ---- serverless mode: per-client params persist, ring gossip after ----
-    @scope("aggregate")
-    def _mix(new_t, mask, fallback):
-        """Post-train serverless aggregation. gossip_steps == 0 -> exact
-        mask-weighted all-client mean, the reference-faithful serverless
-        aggregation (serverless_NonIID_IMDB.py:296): every participating
-        client ends the round with the same average; ``fallback`` (the
-        round's STARTING per-client params) is what an all-masked round keeps.
-        gossip_steps > 0 -> masked ring diffusion."""
-        if gossip_steps == 0:
-            avg = masked_weighted_mean(new_t, mask, axis, fallback=fallback)
-            return _exact_mean_spread(avg, new_t, mask)
-        return gossip_mix(new_t, mask, gossip_alpha, axis, steps=gossip_steps)
-
-    def gossip_shard(client_t, frozen, batches, mask, rngs):
-        def per_client(t, b, r):
-            return local_train(t, frozen, b, unstack(r))
-
-        new_t, stats = jax.vmap(per_client)(client_t, batches, rngs)
-        return _mix(new_t, mask, fallback=client_t), stats
-
-    gossip_round = jax.jit(
-        shard_map(
-            gossip_shard, mesh=jmesh,
-            in_specs=(shard, repl, shard, shard, shard),
-            out_specs=(shard, shard),
-            check_vma=False,
-        ),
-        donate_argnums=(0,) if donate else (),
-    )
-
-    # ---- multi-round fast path: R whole federated rounds in ONE program ----
-    # For sync FedAvg with static participation/data the per-round host
-    # round-trip is pure overhead. Scanning the rounds on-device keeps
-    # params in HBM for the whole block. The engine
-    # keeps the per-round program (masks/ledger need the host between
-    # rounds); this is the bench/static-config path.
-    def server_rounds_shard(global_t, frozen, batches, weights, rngs):
-        def one_round(t, xs):
-            b, w, r = xs
-            return server_shard(t, frozen, b, w, r)
-
-        # batches/weights/rngs leaves are [R, Cl, ...] (round-leading, client
-        # dim sharded); scan consumes the leading round axis
-        return lax.scan(one_round, global_t, (batches, weights, rngs))
-
-    rshard = P(None, "clients")
-    server_rounds = jax.jit(
-        shard_map(
-            server_rounds_shard, mesh=jmesh,
-            in_specs=(repl, repl, rshard, rshard, rshard),
-            out_specs=(repl, rshard),
-            check_vma=False,
-        ),
-        donate_argnums=(0,) if donate else (),
-    )
-
-    # static-partition variant: every round reuses ONE batch tree [Cl, ...]
-    # (round-static partitions would otherwise stack R identical copies of
-    # the batches on device — an R-fold HBM blowup for no information)
-    def server_rounds_static_shard(global_t, frozen, batches, weights, rngs):
-        def one_round(t, xs):
-            w, r = xs
-            return server_shard(t, frozen, batches, w, r)
-
-        return lax.scan(one_round, global_t, (weights, rngs))
-
-    server_rounds_static = jax.jit(
-        shard_map(
-            server_rounds_static_shard, mesh=jmesh,
-            in_specs=(repl, repl, shard, rshard, rshard),
-            out_specs=(repl, rshard),
-            check_vma=False,
-        ),
-        donate_argnums=(0,) if donate else (),
-    )
-
-    # serverless twin of the multi-round fast path: R gossip rounds scanned
-    # on-device, per-client params carried in HBM across the whole block
-    def gossip_rounds_shard(client_t, frozen, batches, masks, rngs):
-        def one_round(t, xs):
-            b, m, r = xs
-            return gossip_shard(t, frozen, b, m, r)
-
-        return lax.scan(one_round, client_t, (batches, masks, rngs))
-
-    gossip_rounds = jax.jit(
-        shard_map(
-            gossip_rounds_shard, mesh=jmesh,
-            in_specs=(shard, repl, rshard, rshard, rshard),
-            out_specs=(shard, rshard),
-            check_vma=False,
-        ),
-        donate_argnums=(0,) if donate else (),
-    )
-
-    def gossip_rounds_static_shard(client_t, frozen, batches, masks, rngs):
-        def one_round(t, xs):
-            m, r = xs
-            return gossip_shard(t, frozen, batches, m, r)
-
-        return lax.scan(one_round, client_t, (masks, rngs))
-
-    gossip_rounds_static = jax.jit(
-        shard_map(
-            gossip_rounds_static_shard, mesh=jmesh,
-            in_specs=(shard, repl, shard, rshard, rshard),
-            out_specs=(shard, rshard),
-            check_vma=False,
-        ),
-        donate_argnums=(0,) if donate else (),
-    )
-
-    # ---- split-phase programs (ledger commit/verify flow, async engine) ----
-    def client_updates_shard(global_t, frozen, batches, rngs):
-        new_t, stats = jax.vmap(
-            lambda b, r: local_train(global_t, frozen, b, unstack(r))
-        )(batches, rngs)
-        return new_t, stats
-
-    client_updates = jax.jit(
-        shard_map(
-            client_updates_shard, mesh=jmesh,
-            in_specs=(repl, repl, shard, shard),
-            out_specs=(shard, shard),
-            check_vma=False,
-        ),
-    )
-
-    def local_updates_shard(client_t, frozen, batches, rngs):
-        return jax.vmap(
-            lambda t, b, r: local_train(t, frozen, b, unstack(r))
-        )(client_t, batches, rngs)
-
-    local_updates = jax.jit(
-        shard_map(
-            local_updates_shard, mesh=jmesh,
-            in_specs=(shard, repl, shard, shard),
-            out_specs=(shard, shard),
-            check_vma=False,
-        ),
-    )
-
-    # split-phase serverless aggregation: ``fallback`` must be the round's
-    # STARTING stacked params (the engine keeps them across the
-    # local_updates -> ledger-verify -> mix_only sequence)
-    mix_only = jax.jit(
-        shard_map(
-            lambda client_t, mask, fallback: _mix(client_t, mask, fallback),
-            mesh=jmesh,
-            in_specs=(shard, shard, shard), out_specs=shard, check_vma=False,
-        ),
-    )
-
-    single_update = jax.jit(local_train)
-
-    # ---- evaluation ----
-    eval_one = make_eval_one(loss_fn)
-
-    def eval_clients_shard(client_t, frozen, batches):
-        return jax.vmap(lambda t, b: eval_one(t, frozen, b))(client_t, batches)
-
-    eval_clients = jax.jit(
-        shard_map(
-            eval_clients_shard, mesh=jmesh,
-            in_specs=(shard, repl, shard),
-            out_specs=shard,
-            check_vma=False,
-        ),
-    )
-
-    # Flower-style client evaluate: the ONE (global) model scored on each
-    # client's local test set (server_IID_IMDB.py:176-179)
-    eval_clients_global = jax.jit(
-        shard_map(
-            lambda g, f, b: jax.vmap(lambda bb: eval_one(g, f, bb))(b),
-            mesh=jmesh,
-            in_specs=(repl, repl, shard),
-            out_specs=shard,
-            check_vma=False,
-        ),
-    )
-
-    eval_global = jax.jit(eval_one)
-
-    # ---- layout helpers ----
-    broadcast = make_broadcast(mesh)
-
-    # ``fallback`` (replicated) is returned when every weight is zero — e.g. a
-    # round where all clients fail ledger authentication must NOT aggregate
-    # the rejected updates.
-    collapse = jax.jit(
-        shard_map(
-            agg, mesh=jmesh,
-            in_specs=(shard, shard, repl), out_specs=repl, check_vma=False,
-        )
-    )
-
-    adopt = jax.jit(
-        shard_map(
-            _adopt_pull, mesh=jmesh,
-            in_specs=(shard, repl, shard), out_specs=shard, check_vma=False,
-        )
-    )
-
-    return FedPrograms(
-        mesh=mesh,
-        server_round=server_round,
-        server_rounds=server_rounds,
-        server_rounds_static=server_rounds_static,
-        gossip_round=gossip_round,
-        gossip_rounds=gossip_rounds,
-        gossip_rounds_static=gossip_rounds_static,
-        eval_clients=eval_clients,
-        eval_clients_global=eval_clients_global,
-        eval_global=eval_global,
-        broadcast=broadcast,
-        collapse=collapse,
-        client_updates=client_updates,
-        local_updates=local_updates,
-        mix_only=mix_only,
-        single_update=single_update,
-        adopt=adopt,
-        # impl-agnostic (plain global-array math); the fused *_fp twins are
-        # gspmd-only, so a ledger run under shard_map falls back per-round
-        fingerprint=jax.jit(lambda t: client_fingerprint(t)),
-        fingerprint_one=jax.jit(lambda t: tree_fingerprint(t)),
-    )
-
-
-def _build_programs_gspmd(
-    model,
-    mesh: ClientMesh,
-    optimizer: str = "adamw",
-    learning_rate: float = 5e-5,
-    max_grad_norm: float = 0.0,
-    gossip_alpha: float = 0.5,
-    gossip_steps: int = 1,
-    donate: bool = False,
-    task: str = "classification",
-    aggregator: str = "mean",
-    aggregator_trim: float = 0.2,
-    prng_impl: Optional[str] = None,
-    compression: Optional[CompressionConfig] = None,
-    hierarchical: bool = False,
-    lora_ranks: Optional[tuple] = None,
-) -> FedPrograms:
-    """GSPMD twin of the shard_map builder: identical program signatures and
-    semantics (global stacked-client arrays in, global arrays out), but the
+    """What :func:`build_programs` memoizes, on its normalized arguments
+    (``compression`` None unless enabled). Every program takes and returns global stacked-client arrays; the
     bodies are plain global-array math under ``jit`` with sharding
     annotations — reductions/rolls over the sharded client dim become XLA
     all-reduce / collective-permute (:mod:`bcfl_tpu.parallel.gspmd`).
@@ -852,8 +493,7 @@ def _build_programs_gspmd(
     simulated transport stage, so ledger auth covers exactly the bytes on
     the wire. ``None``/'none' leaves every body below byte-identical to the
     uncompressed build."""
-    comp = (compression
-            if compression is not None and compression.enabled else None)
+    comp = compression
     # hierarchical (cohort mode): every 'mean' aggregation point — server
     # FedAvg, collapse, the serverless exact-mean — becomes the explicit
     # within-device-stack then cross-device reduction; groups = the mesh's
@@ -901,29 +541,6 @@ def _build_programs_gspmd(
                     unstack(r))
             )(rmask, batches, rngs)
         return _c(new_t, cl), _c(stats, cl)
-
-    def server_body(global_t, frozen, batches, weights, rngs):
-        new_t, stats = train_clients(global_t, frozen, batches, rngs)
-        avg = agg(new_t, weights, global_t)
-        return _c(avg, repl), stats
-
-    def server_body_comp(carry, frozen, batches, weights, rngs):
-        # compressed FedAvg: the server aggregates each client's
-        # RECONSTRUCTION from the compressed delta — what actually arrived —
-        # never the honest full-precision update
-        global_t, resid = carry
-        new_t, stats = train_clients(global_t, frozen, batches, rngs)
-        payload, dec, resid = _compress_stage(new_t, global_t, resid, rngs)
-        del payload  # clean path: ledger/corruption rounds run split-phase
-        avg = agg(_recon(global_t, dec, new_t), weights, global_t)
-        return (_c(avg, repl), resid), stats
-
-    if comp is None:
-        server_round = jax.jit(server_body, donate_argnums=_don(0),
-                               out_shardings=(repl, cl))
-    else:
-        server_round = jax.jit(server_body_comp, donate_argnums=_don(0),
-                               out_shardings=((repl, cl), cl))
 
     @scope("transport")
     def _transport(new_t, c_row):
@@ -996,82 +613,21 @@ def _build_programs_gspmd(
         auth = jnp.all(fp_recv == fp_commit, axis=-1).astype(jnp.float32)
         return sent, fp_commit, fp_recv, _c(auth, cl)
 
-    def _make_server_rounds(static: bool, with_fp: bool):
-        """Fused R-round server program; ``with_fp=True`` additionally takes
-        a per-round per-client transport-corruption input [R, C] and emits
-        ``(stats, fp_commit, fp_recv, auth)`` with fingerprints [R, C, K]:
-        ``fp_commit`` digests the pre-transport update (what each client
-        commits to the ledger), ``fp_recv`` the post-transport buffer that
-        is actually aggregated, and the round's mean is gated by the
-        in-graph comparison — a corrupted update is EXCLUDED from the
-        aggregate, not just flagged. This keeps the fused fast path a real
-        verification (VERDICT r04 weak #2), not an accounting identity."""
-
-        def body(global_t, frozen, batches, weights, rngs, corrupts=None):
-            def one_round(t, xs):
-                if static:
-                    b = batches
-                    (w, r), rest = xs[:2], xs[2:]
-                else:
-                    (b, w, r), rest = xs[:3], xs[3:]
-                if comp is not None:
-                    # compressed carry: (global params, EF residual). The
-                    # residual is per-client sender state riding the scan —
-                    # compression error re-enters the next round's encode
-                    # instead of accumulating (COMPRESSION.md).
-                    g, resid = t
-                    new_t, stats = train_clients(g, frozen, b, r)
-                    payload, dec, resid = _compress_stage(new_t, g, resid, r)
-                    if with_fp:
-                        sent, fpc, fpr, auth = _fp_auth_payload(
-                            payload, rest[0])
-                        # decode the TRANSPORTED payload: a corrupted wire
-                        # yields a corrupted reconstruction, which auth
-                        # already excluded from the aggregate
-                        dec = cc.decode_tree(comp, sent, new_t)
-                        avg = _c(agg(_recon(g, dec, new_t), w * auth, g),
-                                 repl)
-                        return (avg, resid), (stats, fpc, fpr, auth)
-                    avg = _c(agg(_recon(g, dec, new_t), w, g), repl)
-                    return (avg, resid), stats
-                new_t, stats = train_clients(t, frozen, b, r)
-                if with_fp:
-                    sent_t, fpc, fpr, auth = _fp_auth(new_t, rest[0])
-                    avg = _c(agg(sent_t, w * auth, t), repl)
-                    return avg, (stats, fpc, fpr, auth)
-                avg = _c(agg(new_t, w, t), repl)
-                return avg, stats
-
-            xs = (weights, rngs) if static else (batches, weights, rngs)
-            if with_fp:
-                xs = xs + (corrupts,)
-            return lax.scan(one_round, global_t, xs)
-
-        carry_sh = repl if comp is None else (repl, cl)
-        out_sh = ((carry_sh, (rcl, rcl, rcl, rcl)) if with_fp
-                  else (carry_sh, rcl))
-        return jax.jit(body, donate_argnums=_don(0), out_shardings=out_sh)
-
-    server_rounds = _make_server_rounds(static=False, with_fp=False)
-    server_rounds_static = _make_server_rounds(static=True, with_fp=False)
-    server_rounds_fp = _make_server_rounds(static=False, with_fp=True)
-    server_rounds_static_fp = _make_server_rounds(static=True, with_fp=True)
-
     @scope("aggregate")
-    def _mix_g(new_t, mask, fallback):
-        # same semantics as the shard_map _mix (see its docstring); the
-        # exact-mean path rides the configured aggregator
-        if gossip_steps == 0:
-            avg = agg(new_t, mask, fallback)
-            return _exact_mean_spread(avg, new_t, mask)
-        return gspmd.gossip_mix(new_t, mask, gossip_alpha, steps=gossip_steps)
+    def _mix(self_t, recv_t, mask, fallback):
+        """Post-train serverless aggregation. gossip_steps == 0 -> exact
+        mask-weighted all-client aggregate (the configured rule), the
+        reference-faithful serverless aggregation
+        (serverless_NonIID_IMDB.py:296): every participating client ends the
+        round with the same average; ``fallback`` (the round's STARTING
+        per-client params) is what the average of an all-masked round
+        reads. gossip_steps > 0 -> masked ring diffusion.
 
-    @scope("aggregate")
-    def _mix_g_recv(self_t, recv_t, mask, fallback):
-        # transport-aware twin of _mix_g: neighbor/aggregate terms come from
-        # the TRANSPORTED tree, the self-term (and a masked client's kept
-        # state) from the client's own honest post-train tree — in-flight
-        # corruption must not rewrite the sender's local copy
+        Transport-aware: neighbor/aggregate terms come from ``recv_t`` (the
+        TRANSPORTED or reconstructed tree), the self-term (and a masked
+        client's kept state) from the client's own honest post-train tree
+        ``self_t`` — in-flight corruption must not rewrite the sender's
+        local copy. A clean exchange passes the same tree twice."""
         if gossip_steps == 0:
             avg = agg(recv_t, mask, fallback)
             return _exact_mean_spread(avg, self_t, mask)
@@ -1093,97 +649,111 @@ def _build_programs_gspmd(
             )(rmask, client_t, batches, rngs)
         return _c(new_t, cl), _c(stats, cl)
 
-    def gossip_body(client_t, frozen, batches, mask, rngs):
-        new_t, stats = local_updates_body(client_t, frozen, batches, rngs)
-        return _c(_mix_g(new_t, mask, client_t), cl), stats
+    def _round_step(mode: str, with_fp: bool):
+        """THE definition of one federated round; every round program below
+        is this step, jitted alone (``server_round``, ``gossip_round``) or
+        scanned over R rounds (:func:`_make_rounds`). Four stages, each an
+        identity when its feature is off:
 
-    def gossip_body_comp(carry, frozen, batches, mask, rngs):
-        # compressed gossip: the DELTA each peer ships is vs its own
-        # round-start params (which its neighbours hold from the previous
-        # exchange — the standard delta-compression gossip assumption);
-        # neighbour/aggregate terms come from the lossy reconstruction, each
-        # sender's self-term stays its honest post-train tree (mix_recv's
-        # transport split, reused as the codec split)
-        client_t, resid = carry
-        new_t, stats = local_updates_body(client_t, frozen, batches, rngs)
-        payload, dec, resid = _compress_stage(new_t, client_t, resid, rngs)
-        del payload
-        recon = _recon(client_t, dec, new_t)
-        mixed = _c(_mix_g_recv(new_t, recon, mask, client_t), cl)
-        return (mixed, resid), stats
+        - train: ``server`` trains every client from the replicated global,
+          ``gossip`` each client from its own stacked params;
+        - encode (compression on): the carry is ``(params, EF residual)``;
+          each client's delta vs the round's reference (the global, or its
+          own round-start params, which its neighbours hold from the
+          previous exchange) is error-feedback-compensated and encoded, and
+          only the lossy RECONSTRUCTION reaches the combine stage — never
+          the honest full-precision update. The residual is per-client
+          sender state riding the carry: compression error re-enters the
+          next round's encode instead of accumulating (COMPRESSION.md);
+        - verify (``with_fp``): takes the round's per-client transport
+          corruption row [C] and emits ``(stats, fp_commit, fp_recv, auth)``:
+          ``fp_commit`` digests the pre-transport update or payload (what
+          each client commits to the ledger), ``fp_recv`` the
+          post-transport buffer that is actually combined, and the combine
+          weights are gated by the in-graph comparison — a corrupted update
+          is EXCLUDED from the aggregate, not just flagged, which keeps the
+          fused fast path a real verification, not an accounting identity.
+          Off, ledger/corruption rounds run split-phase instead;
+        - combine: ``server`` aggregates to the replicated global (an
+          all-masked round keeps the round's starting params);
+          ``gossip`` mixes — neighbour/aggregate terms from what crossed the
+          wire (the transported or reconstructed tree), each sender's
+          self-term from its honest post-train tree (``_mix``).
+        """
+        server = mode == "server"
+        train = train_clients if server else local_updates_body
 
-    if comp is None:
-        gossip_round = jax.jit(gossip_body, donate_argnums=_don(0),
-                               out_shardings=(cl, cl))
-    else:
-        gossip_round = jax.jit(gossip_body_comp, donate_argnums=_don(0),
-                               out_shardings=((cl, cl), cl))
+        def step(carry, frozen, batches, weights, rngs, c_row=None):
+            ref_t, resid = carry if comp is not None else (carry, None)
+            new_t, stats = train(ref_t, frozen, batches, rngs)
+            recv_t, out = new_t, stats
+            if comp is not None:
+                payload, dec, resid = _compress_stage(
+                    new_t, ref_t, resid, rngs)
+                if with_fp:
+                    sent, fpc, fpr, auth = _fp_auth_payload(payload, c_row)
+                    # decode the TRANSPORTED payload: a corrupted wire
+                    # yields a corrupted reconstruction, which auth
+                    # excludes from the combine
+                    dec = cc.decode_tree(comp, sent, new_t)
+                recv_t = _recon(ref_t, dec, new_t)
+            elif with_fp:
+                recv_t, fpc, fpr, auth = _fp_auth(new_t, c_row)
+            if with_fp:
+                weights, out = weights * auth, (stats, fpc, fpr, auth)
+            if server:
+                nxt = _c(agg(recv_t, weights, ref_t), repl)
+            else:
+                nxt = _c(_mix(new_t, recv_t, weights, ref_t), cl)
+            return (nxt if comp is None else (nxt, resid)), out
 
-    def _make_gossip_rounds(static: bool, with_fp: bool):
-        """Fused R-round gossip program; ``with_fp`` adds the same
-        simulated-transport verification as ``_make_server_rounds``: commit
-        fingerprints on the post-train pre-transport update (the tree the
-        split-phase ledger flow commits via ``local_updates``), verification
-        fingerprints + in-graph auth on the transported buffer, and the
-        gossip mix consumes the transported buffer gated by auth."""
+        return step
 
-        def body(client_t, frozen, batches, masks, rngs, corrupts=None):
+    def _carry_sharding(mode: str):
+        params = repl if mode == "server" else cl
+        return params if comp is None else (params, cl)
+
+    def _make_round(mode: str):
+        """The per-round program: one step, no scan."""
+        return jax.jit(_round_step(mode, with_fp=False),
+                       donate_argnums=_don(0),
+                       out_shardings=(_carry_sharding(mode), cl))
+
+    def _make_rounds(mode: str, static: bool, with_fp: bool):
+        """Fused R-round program: the step scanned over round-leading
+        weights/rngs [R, C, ...] (and corruption rows [R, C] with
+        ``with_fp``, whose outputs then carry fingerprints [R, C, K]).
+        ``static`` reuses ONE batch tree [C, ...] every round instead of
+        consuming batches [R, C, ...]."""
+        step = _round_step(mode, with_fp)
+
+        def body(carry, frozen, batches, weights, rngs, corrupts=None):
             def one_round(t, xs):
                 if static:
-                    b = batches
-                    (m, r), rest = xs[:2], xs[2:]
-                else:
-                    (b, m, r), rest = xs[:3], xs[3:]
-                if comp is not None:
-                    # compressed carry (client params, EF residual); see
-                    # gossip_body_comp for the delta-reference semantics
-                    ct, resid = t
-                    new_t, stats = local_updates_body(ct, frozen, b, r)
-                    payload, dec, resid = _compress_stage(new_t, ct, resid, r)
-                    if with_fp:
-                        sent, fpc, fpr, auth = _fp_auth_payload(
-                            payload, rest[0])
-                        dec = cc.decode_tree(comp, sent, new_t)
-                        mixed = _c(_mix_g_recv(
-                            new_t, _recon(ct, dec, new_t), m * auth, ct), cl)
-                        return (mixed, resid), (stats, fpc, fpr, auth)
-                    mixed = _c(_mix_g_recv(
-                        new_t, _recon(ct, dec, new_t), m, ct), cl)
-                    return (mixed, resid), stats
-                new_t, stats = local_updates_body(t, frozen, b, r)
-                if with_fp:
-                    sent_t, fpc, fpr, auth = _fp_auth(new_t, rest[0])
-                    mixed = _c(_mix_g_recv(new_t, sent_t, m * auth, t), cl)
-                    return mixed, (stats, fpc, fpr, auth)
-                mixed = _c(_mix_g(new_t, m, t), cl)
-                return mixed, stats
+                    return step(t, frozen, batches, *xs)
+                return step(t, frozen, *xs)
 
-            xs = (masks, rngs) if static else (batches, masks, rngs)
+            xs = (weights, rngs) if static else (batches, weights, rngs)
             if with_fp:
                 xs = xs + (corrupts,)
-            return lax.scan(one_round, client_t, xs)
+            return lax.scan(one_round, carry, xs)
 
-        carry_sh = cl if comp is None else (cl, cl)
-        out_sh = ((carry_sh, (rcl, rcl, rcl, rcl)) if with_fp
-                  else (carry_sh, rcl))
-        return jax.jit(body, donate_argnums=_don(0), out_shardings=out_sh)
-
-    gossip_rounds = _make_gossip_rounds(static=False, with_fp=False)
-    gossip_rounds_static = _make_gossip_rounds(static=True, with_fp=False)
-    gossip_rounds_fp = _make_gossip_rounds(static=False, with_fp=True)
-    gossip_rounds_static_fp = _make_gossip_rounds(static=True, with_fp=True)
+        out_sh = (rcl, rcl, rcl, rcl) if with_fp else rcl
+        return jax.jit(body, donate_argnums=_don(0),
+                       out_shardings=(_carry_sharding(mode), out_sh))
 
     client_updates = jax.jit(train_clients, out_shardings=(cl, cl))
 
     local_updates = jax.jit(local_updates_body, out_shardings=(cl, cl))
 
     mix_only = jax.jit(
-        lambda client_t, mask, fallback: _c(_mix_g(client_t, mask, fallback), cl),
+        lambda client_t, mask, fallback: _c(
+            _mix(client_t, client_t, mask, fallback), cl),
         out_shardings=cl)
 
     mix_recv = jax.jit(
         lambda self_t, recv_t, mask, fallback: _c(
-            _mix_g_recv(self_t, recv_t, mask, fallback), cl),
+            _mix(self_t, recv_t, mask, fallback), cl),
         out_shardings=cl)
 
     single_update = jax.jit(local_train)
@@ -1249,14 +819,19 @@ def _build_programs_gspmd(
             lambda t: cc.zero_residual(t, mesh.num_clients),
             out_shardings=cl)
 
+    # the eight fused programs under their field names: {mode}_rounds,
+    # then _static, then _fp (the engine composes the same names)
+    fused = {
+        f"{mode}_rounds" + "_static" * static + "_fp" * with_fp:
+            _make_rounds(mode, static, with_fp)
+        for mode in ("server", "gossip")
+        for static in (False, True) for with_fp in (False, True)}
+
     return FedPrograms(
         mesh=mesh,
-        server_round=server_round,
-        server_rounds=server_rounds,
-        server_rounds_static=server_rounds_static,
-        gossip_round=gossip_round,
-        gossip_rounds=gossip_rounds,
-        gossip_rounds_static=gossip_rounds_static,
+        server_round=_make_round("server"),
+        gossip_round=_make_round("gossip"),
+        **fused,
         eval_clients=eval_clients,
         eval_clients_global=eval_clients_global,
         eval_global=eval_global,
@@ -1270,10 +845,6 @@ def _build_programs_gspmd(
         fingerprint=jax.jit(lambda t: _c(client_fingerprint(t), cl),
                             out_shardings=cl),
         fingerprint_one=jax.jit(lambda t: tree_fingerprint(t)),
-        server_rounds_fp=server_rounds_fp,
-        server_rounds_static_fp=server_rounds_static_fp,
-        gossip_rounds_fp=gossip_rounds_fp,
-        gossip_rounds_static_fp=gossip_rounds_static_fp,
         mix_recv=mix_recv,
         encode_deltas=encode_deltas,
         encode_deltas_local=encode_deltas_local,

@@ -453,20 +453,6 @@ class FedEngine:
                 "trees; with compression enabled the wire carries encoded "
                 "payloads — schedule corruption via FedConfig.faults "
                 "(it corrupts the compressed representation)")
-        if (self.faults.plan.corrupts and cfg.mode == "serverless"
-                and cfg.sync != "async" and self.progs.mix_recv is None):
-            # async is exempt: _async_round never mixes — `sent` feeds only
-            # the delta merge, and each sender's carried state stays honest
-            # without the transport-aware mix the corrupted copy would
-            # REPLACE the sender's own carried state — the next round it
-            # would honestly commit (and pass authentication for) garbage
-            # params, diverging through a path the fault model says cannot
-            # exist. Only the gspmd programs compile mix_recv today.
-            raise ValueError(
-                "serverless FaultPlan corruption requires the gspmd fed "
-                "impl (mix_recv): the shard_map twin has no transport-aware "
-                "mix, so in-flight corruption would poison the sender's own "
-                "carried state (unset BCFL_FED_IMPL or set it to 'gspmd')")
         if cfg.donate and (cfg.sync == "async" or cfg.faithful):
             warnings.warn(
                 "donate=True has no effect on the async/faithful paths — "
@@ -1609,17 +1595,14 @@ class FedEngine:
         comparison, and the host chain authenticates the post-transport
         fingerprints — so fused-mode auth genuinely fails for a corrupted
         update (``fused_tamper``) instead of being an identity. A host
-        tamper hook (or the shard_map impl, which has no fp programs) falls
-        back to per-round. Chunks never cross an eval or checkpoint
-        boundary, so the observable cadence is identical to the per-round
-        path."""
+        tamper hook falls back to per-round. Chunks never cross an eval or
+        checkpoint boundary, so the observable cadence is identical to the
+        per-round path."""
         cfg = self.cfg
         k = cfg.rounds_per_dispatch
-        ledger_blocks = (self.ledger is not None
-                         and self.progs.server_rounds_fp is None)
         if (k <= 1 or cfg.sync != "sync"
                 or (cfg.mode != "server" and cfg.faithful)
-                or ledger_blocks or self.faults.host_tamper is not None
+                or self.faults.host_tamper is not None
                 or self.faults.blocks_fusion()
                 or self.reputation is not None
                 or self.sampling

@@ -1,9 +1,3 @@
-from bcfl_tpu.parallel.collectives import (  # noqa: F401
-    masked_weighted_mean,
-    ring_shift,
-    gossip_mix,
-    mix_with_matrix,
-)
 from bcfl_tpu.parallel import gspmd  # noqa: F401
 from bcfl_tpu.parallel.ring_attention import (  # noqa: F401
     ring_attention,

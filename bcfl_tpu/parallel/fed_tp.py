@@ -66,8 +66,7 @@ def build_fed_tp_programs(model, mesh: Mesh, num_clients: Optional[int] = None,
     XLA keeps the tp sharding inside each client's update)."""
     from bcfl_tpu.fed.client_step import build_programs
 
-    return build_programs(model, as_client_mesh(mesh, num_clients),
-                          impl="gspmd", **kw)
+    return build_programs(model, as_client_mesh(mesh, num_clients), **kw)
 
 
 def build_fed_tp_round(

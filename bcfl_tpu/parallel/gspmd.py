@@ -1,24 +1,25 @@
-"""Global-array (GSPMD) forms of the client-axis collectives.
+"""Aggregation and gossip collectives over the ``clients`` mesh axis.
 
-:mod:`bcfl_tpu.parallel.collectives` expresses aggregation/gossip as explicit
-``psum``/``ppermute`` inside ``shard_map`` — the manual-SPMD style. This module
-is the same math written over the GLOBAL stacked-client arrays, compiled with
-plain ``jit`` + sharding annotations so the XLA SPMD partitioner inserts the
+This is the framework's distributed communication backend — the TPU-native
+replacement for the reference's Flower-over-Ray parameter shipping (server
+mode, ``src/Servercase/server_IID_IMDB.py:211-218``) and its Python-list
+"weight transfer" (serverless mode, ``serverless_NonIID_IMDB.py:293-296``) —
+SURVEY.md §2.5:
+
+- FedAvg            -> masked weighted mean (all-reduce over ICI/DCN)
+- P2P ring gossip   -> neighbour exchange (collective-permute) + local mixing
+- arbitrary topology-> mixing-matrix einsum
+
+The math is written over the GLOBAL stacked-client arrays and compiled with
+plain ``jit`` + sharding annotations, so the XLA SPMD partitioner inserts the
 collectives itself (the scaling-book recipe: pick a mesh, annotate shardings,
 let XLA lower reductions/rolls over a sharded axis to all-reduce /
-collective-permute over ICI/DCN).
-
-Why both exist: in the one bisection on record (an earlier recording on one
-chip, PERF.md "Earlier recordings"), the ``shard_map``-wrapped round program
-executed ~200x slower than the identical math under plain ``jit`` (7.2 s vs
-36 ms per BERT-base step); the GSPMD forms recover full speed and are what
-:func:`bcfl_tpu.fed.client_step.build_programs` compiles by default. Numeric
-parity between the two is pinned by ``tests/test_gspmd_impl.py``.
-
-Every function takes leaves with a leading GLOBAL client dim ``C`` (the
-device-major stacked order of :class:`bcfl_tpu.core.mesh.ClientMesh`) and a
-``[C]`` mask/weight vector; reference semantics citations live with the
-shard_map twins.
+collective-permute). Every function takes leaves with a leading GLOBAL client
+dim ``C`` (the device-major stacked order of
+:class:`bcfl_tpu.core.mesh.ClientMesh`) and a ``[C]`` mask/weight vector.
+Anomaly-gated aggregation keeps the mesh shape fixed: excluded clients keep
+computing but carry weight 0 (SURVEY.md §7 "anomaly gating without reshaping
+the mesh").
 """
 
 from __future__ import annotations
@@ -34,9 +35,18 @@ EPS = 1e-12
 
 def masked_weighted_mean(tree: Tree, weights: jnp.ndarray,
                          fallback: Optional[Tree] = None) -> Tree:
-    """Weighted mean over the global client dim; all-masked rounds return
-    ``fallback`` (unweighted mean when no fallback is given). Twin of
-    ``collectives.masked_weighted_mean``."""
+    """Weighted mean over the global client dim; ``weights`` [C] already
+    folds participation mask x (optionally) example counts.
+
+    weights = mask                  -> reference serverless unweighted mean
+              (``serverless_NonIID_IMDB.py:296``)
+    weights = mask * num_examples   -> Flower FedAvg example weighting
+              (``server_IID_IMDB.py:199-204``)
+
+    If EVERY client is masked out (an anomaly filter can do that on a bad
+    round) the mean is undefined; rather than silently zeroing the model we
+    return ``fallback`` (e.g. the round's starting params). With no fallback,
+    an unweighted mean of the tree is returned."""
     den = weights.sum()
     empty = den <= EPS
 
@@ -324,12 +334,29 @@ def ring_shift(tree: Tree, direction: int = +1) -> Tree:
     return jax.tree.map(lambda x: jnp.roll(x, -direction, axis=0), tree)
 
 
+def gossip_step_mix(x, xl, xr, ml, mr, me, alpha: float):
+    """One client's masked ring-gossip update (masks already reshaped to
+    broadcast against ``x``): THE definition of the mixing rule."""
+    mixed = x + (alpha / 2) * ml * (xl - x) + (alpha / 2) * mr * (xr - x)
+    return me * mixed + (1 - me) * x
+
+
 def gossip_mix(tree: Tree, mask: jnp.ndarray, alpha: float,
                steps: int = 1) -> Tree:
-    """Symmetric masked ring gossip over the global client order — same
-    update rule (and anomaly-freeze semantics) as
-    ``collectives.gossip_mix``. The self==received special case of
-    :func:`gossip_mix_recv` (one mixing-rule definition, not two)."""
+    """Symmetric masked ring gossip over the global client order: each
+    client averages toward its two ring neighbors. With mixing weight
+    ``alpha`` and participation ``mask`` [C]:
+
+        x_i <- x_i + (alpha/2) * m_{i-1} (x_{i-1} - x_i)
+                   + (alpha/2) * m_{i+1} (x_{i+1} - x_i)
+
+    Anomalous neighbors (mask 0) contribute nothing, and a client that is
+    itself masked out is frozen entirely, so its (possibly poisoned) state
+    neither spreads nor drifts. Repeated ``steps`` diffuse toward the global
+    average — the intended semantics of the reference's all-client averaging
+    (``serverless_NonIID_IMDB.py:296``) without any all-to-all. The
+    self==received special case of :func:`gossip_mix_recv` (one mixing-rule
+    definition, not two)."""
     return gossip_mix_recv(tree, tree, mask, alpha, steps=steps)
 
 
@@ -347,8 +374,6 @@ def gossip_mix_recv(self_tree: Tree, recv_tree: Tree, mask: jnp.ndarray,
     this is bit-identical to ``gossip_mix``. Only the FIRST step models
     transport (later steps exchange post-mix state, whose transport is not
     simulated)."""
-    from bcfl_tpu.parallel.collectives import gossip_step_mix
-
     m_left = jnp.roll(mask, 1, axis=0)   # value of client i-1, at slot i
     m_right = jnp.roll(mask, -1, axis=0)
     for _ in range(steps):

@@ -200,7 +200,7 @@ def random_graph(n: int, low: float = 88.0, high: float = 496.0,
 def metropolis_mixing_matrix(mask: np.ndarray) -> np.ndarray:
     """Doubly-stochastic Metropolis-Hastings weights over the participating
     complete subgraph — the mixing matrix for
-    :func:`bcfl_tpu.parallel.mix_with_matrix`. Masked nodes get identity rows
+    :func:`bcfl_tpu.parallel.gspmd.mix_with_matrix`. Masked nodes get identity rows
     (they neither send nor receive)."""
     n = mask.shape[0]
     m = mask.astype(bool)

@@ -204,14 +204,14 @@ def main(argv=None):
     timeit("D_stripped_fused", jax.jit(stripped), params, ROUNDS * STEPS)
 
     # ---- E: the real GSPMD server_rounds, donate OFF ----
-    progs_nd = build_programs(model, mesh, donate=False, impl="gspmd")
+    progs_nd = build_programs(model, mesh, donate=False)
     timeit("E_gspmd_rounds",
            lambda t: progs_nd.server_rounds(t, None, rbatches, rweights,
                                             rrngs)[0],
            params, ROUNDS * STEPS)
 
     # ---- F: the bench config — GSPMD server_rounds, donate ON ----
-    progs_d = build_programs(model, mesh, donate=True, impl="gspmd")
+    progs_d = build_programs(model, mesh, donate=True)
     timeit("F_gspmd_donate",
            lambda t: progs_d.server_rounds(t, None, rbatches, rweights,
                                            rrngs)[0],

@@ -2,19 +2,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import shard_map
-from jax.sharding import PartitionSpec as P
 
 from bcfl_tpu.core import client_mesh
-from bcfl_tpu.parallel import gossip_mix, masked_weighted_mean, mix_with_matrix, ring_shift
+from bcfl_tpu.parallel.gspmd import gossip_mix, masked_weighted_mean, mix_with_matrix, ring_shift
 
 
-def _run_sharded(mesh, fn, *args, out_specs=P("clients")):
-    f = jax.jit(
-        shard_map(fn, mesh=mesh.mesh, in_specs=(P("clients"),) * len(args),
-                  out_specs=out_specs, check_vma=False)
-    )
-    return f(*args)
+def _run_sharded(mesh, fn, *args, replicated_out=False):
+    """``fn`` under jit on client-sharded inputs: the SPMD partitioner
+    inserts the collectives over the (8, 5 or 8-device) clients axis."""
+    args = [mesh.shard_clients(jax.tree.map(jnp.asarray, a)) for a in args]
+    out = mesh.replicated() if replicated_out else mesh.client_sharding()
+    return jax.jit(fn, out_shardings=out)(*args)
 
 
 @pytest.mark.parametrize("num_clients", [8, 10, 16])
@@ -26,9 +24,7 @@ def test_masked_weighted_mean_matches_numpy(num_clients):
     tree = {"p": x}
 
     out = _run_sharded(
-        mesh, lambda t, ww: masked_weighted_mean(t, ww, "clients"), tree, w,
-        out_specs=P(),
-    )
+        mesh, masked_weighted_mean, tree, w, replicated_out=True)
     want = (x * w[:, None, None]).sum(0) / w.sum()
     np.testing.assert_allclose(np.asarray(out["p"]), want, rtol=1e-5)
 
@@ -39,7 +35,7 @@ def test_ring_shift_global_order(num_clients, direction):
     mesh = client_mesh(num_clients)
     x = np.arange(num_clients, dtype=np.float32).reshape(num_clients, 1)
     out = _run_sharded(
-        mesh, lambda t: ring_shift(t, "clients", direction), {"x": x}
+        mesh, lambda t: ring_shift(t, direction), {"x": x}
     )
     got = np.asarray(out["x"]).ravel()
     want = np.roll(np.arange(num_clients), -direction)
@@ -53,7 +49,7 @@ def test_gossip_mix_converges_to_mean():
     mask = np.ones((num_clients,), np.float32)
     out = _run_sharded(
         mesh,
-        lambda t, m: gossip_mix(t, m, alpha=0.6, axis_name="clients", steps=60),
+        lambda t, m: gossip_mix(t, m, alpha=0.6, steps=60),
         {"x": x}, mask,
     )
     got = np.asarray(out["x"])
@@ -72,7 +68,7 @@ def test_gossip_mix_isolates_masked_client():
     mask[3] = 0.0
     out = _run_sharded(
         mesh,
-        lambda t, m: gossip_mix(t, m, alpha=0.5, axis_name="clients", steps=20),
+        lambda t, m: gossip_mix(t, m, alpha=0.5, steps=20),
         {"x": x}, mask,
     )
     got = np.asarray(out["x"])
@@ -90,7 +86,7 @@ def test_mix_with_matrix_matches_dense_einsum():
     W = W / W.sum(1, keepdims=True)
     out = _run_sharded(
         mesh,
-        lambda t: mix_with_matrix(t, jnp.asarray(W), "clients", mesh.per_device),
+        lambda t: mix_with_matrix(t, jnp.asarray(W)),
         {"x": x},
     )
     np.testing.assert_allclose(np.asarray(out["x"]), W @ x, rtol=1e-4, atol=1e-6)

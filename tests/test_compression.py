@@ -5,7 +5,7 @@ feedback residual algebra, bytes accounting, payload corruption semantics.
 Engine-level: ``compress=none`` bit-identical to the uncompressed programs,
 error-feedback convergence parity on the tiny model, codec params keying the
 program cache (no silent cross-codec reuse), zero per-round retraces with
-compression on, the shard_map impl rejecting compression loudly, and the
+compression on, and the
 chaos-matrix rows at ``int8+topk`` — ledger auth passes on clean compressed
 rounds and fails on transport-corrupted compressed payloads, on both the
 per-round and fused paths, plus bit-identical compressed crash/resume
@@ -284,16 +284,6 @@ def test_program_cache_keys_on_codec_params():
     d = build_programs(model, mesh, compression=CompressionConfig(
         kind="int8+topk", topk_frac=0.1, stochastic=False))
     assert d is not a
-
-
-def test_shard_map_impl_rejects_compression():
-    from bcfl_tpu.core.mesh import client_mesh
-    from bcfl_tpu.models import build
-
-    with pytest.raises(ValueError, match="gspmd"):
-        build_programs(build("tiny-bert", num_labels=2, vocab_size=512),
-                       client_mesh(4), compression=INT8_TOPK,
-                       impl="shard_map")
 
 
 # ------------------------------------------------------------------- engine

@@ -1,5 +1,5 @@
 """The compiled federated round: training happens, FedAvg/gossip aggregate,
-masks gate contributions — all inside shard_map on the 8-device CPU mesh."""
+masks gate contributions — all inside one jitted program on the 8-device CPU mesh."""
 
 import jax
 import jax.numpy as jnp

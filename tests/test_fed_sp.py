@@ -74,18 +74,3 @@ def test_sp_full_finetune_also_works():
     eng = FedEngine(_cfg(lora_rank=0, num_rounds=1))
     res = eng.run()
     assert np.isfinite(res.metrics.rounds[0].train_loss)
-
-
-def test_sp_requires_gspmd_impl():
-    """ADVICE r04: the shard_map builder's specs only name the clients axis,
-    so a (clients, seq) mesh must be rejected just like (clients, tp) — not
-    silently replicate the sequence dimension."""
-    from bcfl_tpu.core.mesh import client_mesh
-    from bcfl_tpu.fed.client_step import build_programs
-    from bcfl_tpu.models import build
-
-    mesh = client_mesh(2, sp=4)
-    assert mesh.sp == 4
-    with pytest.raises(ValueError, match="gspmd"):
-        build_programs(build("tiny-bert", num_labels=2), mesh,
-                       impl="shard_map")

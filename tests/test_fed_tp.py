@@ -10,7 +10,6 @@ import pytest
 
 from bcfl_tpu.config import FedConfig, LedgerConfig, PartitionConfig
 from bcfl_tpu.core.mesh import (
-    client_mesh,
     distributed_init,
     fed_tp_mesh,
     pod_client_mesh,
@@ -175,16 +174,9 @@ def test_engine_fed_tp_serverless_fused_and_ledger():
     assert res.metrics.ledger["chain_ok"] == 1.0
 
 
-def test_tp_requires_lora_and_gspmd():
+def test_tp_requires_lora():
     with pytest.raises(ValueError, match="lora_rank"):
         _tp_cfg(lora_rank=0)
-    from bcfl_tpu.fed.client_step import build_programs
-
-    mesh = client_mesh(4, tp=2)
-    assert mesh.tp == 2
-    with pytest.raises(ValueError, match="gspmd"):
-        build_programs(build("tiny-llama", num_labels=2), mesh,
-                       impl="shard_map")
 
 
 def test_distributed_init_requires_process_id(monkeypatch):

@@ -2,7 +2,7 @@
 
 Config-level: ``lora_ranks`` spec parsing + canonicalization (``lora_rank``
 becomes the cohort max), the heterogeneous-rank composition rejections
-(robust aggregators / gossip / faithful / registry / dist / shard_map), and
+(robust aggregators / gossip / faithful / registry / dist), and
 the capability-table rows for adapter exchange.
 Math-level: the static rank mask, per-client adapter clipping, the
 rank-aware RBLA weighted mean (padded coordinates excluded per rank dim,
@@ -113,17 +113,14 @@ def test_hetero_rejected_on_dist_via_caps_table():
     assert rows["LoRA adapter exchange"] is True
 
 
-def test_shard_map_impl_rejects_hetero():
+def test_uniform_lora_ranks_build_the_plain_programs():
     from bcfl_tpu.core.mesh import client_mesh
     from bcfl_tpu.fed.client_step import build_programs
     from bcfl_tpu.models import build
 
     model = build("tiny-bert", num_labels=2, vocab_size=512)
-    with pytest.raises(ValueError, match="gspmd"):
-        build_programs(model, client_mesh(4), impl="shard_map",
-                       lora_ranks=(2, 4, 2, 4))
-    # a uniform tuple normalizes onto the PLAIN program set — identical
-    # object, so shard_map (and every cache hit) keeps working
+    # a uniform tuple normalizes onto the PLAIN program set: identical
+    # object, so every cache hit keeps working
     a = build_programs(model, client_mesh(4))
     b = build_programs(model, client_mesh(4), lora_ranks=(4, 4, 4, 4))
     assert b is a

@@ -642,9 +642,17 @@ class FedConfig:
     # encoders: dense). True forces the O(S)-memory blockwise/Pallas
     # attention path — the long-context switch, reachable from the CLI
     use_flash: Optional[bool] = None
-    # per-layer activation rematerialization: recompute activations in the
-    # backward instead of storing them — O(num_layers) less activation HBM
-    # for ~1/3 more FLOPs, so more full-fine-tune clients stack per chip
+    # per-layer activation rematerialization. Encoders and llama keep each
+    # layer's input and recompute the layer in the backward pass:
+    # O(num_layers) less activation HBM for ~1/3 more FLOPs, so more
+    # full-fine-tune clients stack per chip. The latent_moe family keeps a
+    # named set of each layer's values as well (models/latent_moe.py::
+    # REMAT_SAVED: no product and no kernel of the forward pass runs again):
+    # 53 KB a position a layer at the published widths in bfloat16, times
+    # batch_size x seq_len x the clients a device stacks x layers (3.5 GB
+    # for 8,192 positions and 8 layers; ``FedEngine.remat_saved`` has the
+    # figure for a job). A job must fit that beside its base; there is no
+    # switch back to keeping the input alone.
     remat: bool = False
     # donate each round's input param/opt buffers to the round program:
     # XLA aliases them into the outputs, halving per-round peak HBM (the

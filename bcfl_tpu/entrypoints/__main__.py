@@ -177,8 +177,11 @@ def main(argv=None):
                          "(one run per engine)")
     ap.add_argument("--remat", action="store_true",
                     help="per-layer activation rematerialization: less HBM "
-                         "per client (more clients stack per chip) for "
-                         "~1/3 more FLOPs")
+                         "per client (more clients stack per chip). Encoders "
+                         "and llama keep a layer's input and run its forward "
+                         "again (~1/3 more FLOPs); latent_moe keeps a named "
+                         "set of values too (53 KB a position a layer at the "
+                         "published widths) and runs no product twice")
     ap.add_argument("--prng-impl", default=None,
                     choices=["threefry", "rbg", "unsafe_rbg"],
                     help="typed-key PRNG: rbg = TPU hardware generator "

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +82,41 @@ def model_counters(model) -> tuple:
     """``((name, "sum" | "max"), ...)``: what the model counts in a step
     (a flax ``counters`` collection), sums first; ``()`` for most models."""
     return tuple(getattr(model, "COUNTERS", ()))
+
+
+def _named_values(jaxpr):
+    """Every ``checkpoint_name`` in ``jaxpr`` and the jaxprs nested in it:
+    ``(name, aval)``."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            yield eqn.params["name"], eqn.outvars[0].aval
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _named_values(sub)
+
+
+def remat_saved(model, task: str, trainable, frozen, batch) -> Tuple[int, int]:
+    """``(values, bytes)``: what a model built with ``remat`` and a named save
+    set (the family's ``REMAT_SAVED``, the names its ``nn.remat`` policy
+    keeps) holds of one client's local step for the backward pass: the
+    values that carry one of those names in the step's forward pass as a
+    VJP traces it (a ``custom_vjp``'s forward rule is in it), and their
+    bytes at the shapes of ``batch``. Nothing runs, so ``batch`` may be
+    ``jax.ShapeDtypeStruct``s. ``(0, 0)`` for a model that keeps everything
+    or rematerialises with no save set."""
+    names = set(getattr(model, "REMAT_SAVED", ()))
+    if not names or not model.cfg.remat:
+        return 0, 0
+    loss_fn = make_loss_fn(model, task)
+
+    def forward(t, f, b):
+        return jax.vjp(lambda t_: loss_fn(t_, f, b, None)[0], t)[0]
+
+    kept = [aval for name, aval in _named_values(
+        jax.make_jaxpr(forward)(trainable, frozen, batch).jaxpr) if name in names]
+    return len(kept), sum(a.size * a.dtype.itemsize for a in kept)
 
 
 def _counter_values(state, counters):

@@ -84,7 +84,7 @@ from bcfl_tpu.data import (
 from bcfl_tpu.data.pipeline import central_eval_batches
 from bcfl_tpu.faults import FaultInjector, SimulatedCrash
 from bcfl_tpu.fed.client_step import (
-    FedPrograms, build_programs, model_counters, _merge)
+    FedPrograms, build_programs, model_counters, remat_saved, _merge)
 from bcfl_tpu.fed.cohort import ClientSampler, EFRegistry, cohort_view
 from bcfl_tpu.ledger import Ledger
 from bcfl_tpu.ledger import fingerprint as fp_lib
@@ -431,6 +431,7 @@ class FedEngine:
             # (or None) to the plain programs
             lora_ranks=cfg.client_lora_ranks,
         )
+        self.remat_saved = self._remat_saved()
         # per-round rank-collapse guard (arXiv 2602.13486): mean effective
         # rank of the global adapter tree, one tiny separate jit (compiles
         # once — the round programs stay untouched); None when LoRA is off
@@ -520,6 +521,22 @@ class FedEngine:
         self.clock: Optional[StepClock] = None
 
     # ------------------------------------------------------------------ spans
+
+    def _remat_saved(self) -> Dict[str, float]:
+        """What the model's rematerialised layers keep for the backward pass
+        (``client_step.remat_saved``; OBSERVABILITY.md §7): the named values
+        a layer, and their megabytes over the layers and the clients one
+        device stacks in a local step. Zeros for a model with no save set,
+        or with ``remat`` off. On the ``run.start`` event."""
+        cfg = self.cfg
+        rows = jax.ShapeDtypeStruct((cfg.batch_size, cfg.seq_len), jnp.int32)
+        per_row = jax.ShapeDtypeStruct((cfg.batch_size,), jnp.float32)
+        values, nbytes = remat_saved(
+            self.model, cfg.task, self.trainable0, self.frozen,
+            {"ids": rows, "mask": rows, "example_mask": per_row})
+        return {"remat_saved_values": values // self.model.cfg.num_layers,
+                "remat_saved_mb_per_step":
+                    round(nbytes * self.mesh.per_device / 1e6, 3)}
 
     def _span(self, name: str, **kw):
         """A child span of whatever phase is open (``round_program/inputs``,
@@ -1097,7 +1114,8 @@ class FedEngine:
                 os.path.join(cfg.telemetry_dir, "events_engine.jsonl"),
                 peer=None, run=cfg.name, sample=cfg.telemetry_sample))
             telemetry.emit("run.start", role="engine", resume=resume,
-                           clients=self.C, rounds=cfg.num_rounds)
+                           clients=self.C, rounds=cfg.num_rounds,
+                           **self.remat_saved)
         status = "crashed"
         try:
             with trace(cfg.profile_dir):

@@ -31,6 +31,9 @@ head, a final RMSNorm.
   clients with the frozen base NOT batched, the expert block's own batching
   rule (:func:`expert_block`) runs one grouped product over all clients'
   rows; the base is never broadcast.
+- **Rematerialisation** (``remat=True``): ``nn.remat`` a layer with a
+  policy that keeps the values :data:`REMAT_SAVED` names, so the backward
+  pass recomputes norms, rotary, gate and copies, and no product or kernel.
 - **Counters** (collection ``counters``, :data:`COUNTERS`): the real
   positions' assignments that fell on held and on absent experts, and the
   fullest held expert's rows. Padded positions are routed to no expert.
@@ -51,11 +54,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.custom_batching import custom_vmap
 
 from bcfl_tpu.metrics.tracing import scope
 from bcfl_tpu.models.llama import RMSNorm, causal_bias, rope
 from bcfl_tpu.ops.attention import dot_product_attention
+from bcfl_tpu.ops.flash import RESIDUAL_NAMES, flash_attention
 from bcfl_tpu.ops.grouped_matmul import grouped_matmul
 
 # the kernels that carry an adapter; the router and the routed experts stay
@@ -68,6 +73,31 @@ LORA_TARGETS = ("q_a_proj", "q_b_proj", "kv_a_proj", "kv_b_proj", "o_proj",
 # are one client's assignments, the fullest expert's rows one client's rows.
 COUNTERS = (("moe_slots_held", "sum"), ("moe_slots_absent", "sum"),
             ("moe_rows_max", "max"))
+
+# What a rematerialised layer KEEPS of its forward pass (``remat=True``: the
+# backward pass of a layer recomputes everything else from these and the
+# layer's input). The base is frozen, so a frozen product's backward needs
+# its weights and nothing of the forward; what the backward does read are the
+# INPUTS of the norms, the gate and the softmax, the adapters' thin products
+# and the flash kernels' operands. Kept are the values from which all of
+# that follows by elementwise work, a norm or a copy, so no product and no
+# kernel of the forward pass runs a second time. A name sits on the value
+# the consumers read: an operation whose derivative reads its own input or
+# output (a norm, silu, softmax) is recomputed from the nearest kept value
+# above it, so the kept value is the product's output, not the norm's.
+# Bytes a position a layer at the published widths, bfloat16 unless said
+# (53,008 in all; ``FedEngine.remat_saved`` reads a job's own figure off its
+# step):
+REMAT_SAVED = (
+    "lora_xa",  # every adapter's x a, float32 [.., r]: 8 x 64 B
+    "mla_q_a",  # q_a_proj's output, before q_a_norm: 2 KB
+    "mla_kv_a",  # kv_a_proj's output (latent before kv_a_norm, rotary key): 640 B
+    "mla_q", "mla_k", "mla_v",  # as the attention op receives them: 3 x 8 KB
+    *RESIDUAL_NAMES,  # the flash kernel's output and log-sum-exp: 8.1 KB
+    "mla_residual",  # the residual stream after attention: 8 KB
+    "router_logits", "router_idx",  # float32 [.., E] and int32 [.., k]: 528 B
+    "shared_gate", "shared_up",  # the shared expert's gate and up products: 8 KB
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,6 +135,9 @@ class LatentMoEConfig:
     flash_min_seq: int = 512
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
+    # True: a layer keeps its input and the values REMAT_SAVED names for the
+    # backward pass and recomputes the rest (norms, rotary, gate, softmax,
+    # copies); False: everything is kept
     remat: bool = False
 
     @property
@@ -181,7 +214,8 @@ class LoRADense(nn.Module):
             with scope("lora"):
                 a = self.get_variable("lora", "a").astype(self.dtype)
                 b = self.get_variable("lora", "b").astype(self.dtype)
-                xa = jnp.dot(x, a, preferred_element_type=jnp.float32)
+                xa = checkpoint_name(
+                    jnp.dot(x, a, preferred_element_type=jnp.float32), "lora_xa")
                 y = y + jnp.dot(xa.astype(self.dtype), b,
                                 preferred_element_type=out)
         return y
@@ -199,9 +233,9 @@ class SwiGLU(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.cfg
-        return _dense(c, c.hidden_size, "down_proj")(
-            nn.silu(_dense(c, self.width, "gate_proj")(x))
-            * _dense(c, self.width, "up_proj")(x))
+        gate = checkpoint_name(_dense(c, self.width, "gate_proj")(x), "shared_gate")
+        up = checkpoint_name(_dense(c, self.width, "up_proj")(x), "shared_up")
+        return _dense(c, c.hidden_size, "down_proj")(nn.silu(gate) * up)
 
 
 # --------------------------------------------------------------- attention
@@ -217,10 +251,11 @@ class LatentAttention(nn.Module):
         H, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
                          c.v_head_dim)
         cq = RMSNorm(c.rms_eps, c.param_dtype, name="q_a_norm")(
-            _dense(c, c.q_lora_rank, "q_a_proj")(x))
+            checkpoint_name(_dense(c, c.q_lora_rank, "q_a_proj")(x), "mla_q_a"))
         q = _dense(c, H * (dn + dr), "q_b_proj")(cq)
         q = q.reshape(B, S, H, dn + dr).transpose(0, 2, 1, 3)
-        ckv = _dense(c, c.kv_lora_rank + dr, "kv_a_proj")(x)
+        ckv = checkpoint_name(
+            _dense(c, c.kv_lora_rank + dr, "kv_a_proj")(x), "mla_kv_a")
         k_rope = ckv[..., c.kv_lora_rank:][:, None]  # one head [B, 1, S, dr]
         kv = _dense(c, H * (dn + dv), "kv_b_proj")(
             RMSNorm(c.rms_eps, c.param_dtype, name="kv_a_norm")(
@@ -246,9 +281,10 @@ class LatentAttention(nn.Module):
                 f"query-key heads of {dn + dr} and value heads of {dv}: the "
                 "attention ops take one head size (ROADMAP: attention with "
                 "unequal query-key and value heads)")
+        q = checkpoint_name(q, "mla_q")
+        k = checkpoint_name(k, "mla_k")
+        v = checkpoint_name(v, "mla_v")
         if bias is None:
-            from bcfl_tpu.ops.flash import flash_attention
-
             out = flash_attention(q, k, v, key_bias, causal=True)
         else:
             out = dot_product_attention(q, k, v, bias)
@@ -445,10 +481,11 @@ class ExpertLayer(nn.Module):
         rows = x.reshape(B * S, Hd)
         with scope("moe.route"):
             # exact products of the stored values, float32 sums
-            logits = jnp.dot(rows.astype(jnp.float32), w_r.astype(jnp.float32),
-                             precision=lax.Precision.HIGHEST)
+            logits = checkpoint_name(
+                jnp.dot(rows.astype(jnp.float32), w_r.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST), "router_logits")
             probs = jax.nn.softmax(logits, axis=-1)
-            _, idx = lax.top_k(probs, k)
+            idx = checkpoint_name(lax.top_k(probs, k)[1], "router_idx")
             # the chosen probabilities by a mask, the counts by comparison:
             # a scatter here (top_k's gradient, bincount) is one the TPU
             # compiler has failed on inside the round program's loops
@@ -488,8 +525,8 @@ class LatentMoELayer(nn.Module):
         c = self.cfg
         h = RMSNorm(c.rms_eps, c.param_dtype, name="input_norm")(x)
         with scope("mla"):
-            x = x + LatentAttention(c, name="attention")(
-                h, bias, key_bias, positions)
+            x = checkpoint_name(x + LatentAttention(c, name="attention")(
+                h, bias, key_bias, positions), "mla_residual")
         h = RMSNorm(c.rms_eps, c.param_dtype, name="post_attention_norm")(x)
         return x + ExpertLayer(c, name="moe")(h, key_bias > -1.0)
 
@@ -499,8 +536,10 @@ class LatentMoELM(nn.Module):
     float32 logits over the vocabulary rows held."""
 
     cfg: LatentMoEConfig
-    # read by fed.client_step.model_counters (not a dataclass field)
+    # read by fed.client_step.model_counters and .remat_saved (not
+    # dataclass fields)
     COUNTERS = COUNTERS
+    REMAT_SAVED = REMAT_SAVED
 
     @nn.compact
     def __call__(self, ids, mask, deterministic: bool = True):
@@ -512,7 +551,10 @@ class LatentMoELM(nn.Module):
         bias = None if use_flash else causal_bias(mask)
         key_bias = jnp.where(mask > 0, 0.0, -1e30).astype(jnp.float32)
         positions = jnp.arange(ids.shape[1])
-        layer_cls = nn.remat(LatentMoELayer) if c.remat else LatentMoELayer
+        layer_cls = LatentMoELayer
+        if c.remat:
+            layer_cls = nn.remat(LatentMoELayer, policy=(
+                jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED)))
         for i in range(c.num_layers):
             x = layer_cls(c, name=f"layer_{i}")(x, bias, key_bias, positions)
         x = RMSNorm(c.rms_eps, c.param_dtype, name="final_norm")(x)

@@ -33,6 +33,13 @@ from bcfl_tpu.ops import registry
 
 DEFAULT_BLOCK = 512
 
+#: The names (``jax.ad_checkpoint.checkpoint_name``) the Pallas kernel's
+#: forward rule gives the two residuals no caller can reach, its output and
+#: its log-sum-exp (``pallas_flash._vjp_fwd``): a ``jax.checkpoint`` policy
+#: that saves them (``save_only_these_names``), with q, k and v, does not
+#: run the forward kernel again in the backward pass. Identities elsewhere.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
 
 def flash_attention_xla(
     q: jnp.ndarray,  # [B, H, S, D]

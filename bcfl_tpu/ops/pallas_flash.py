@@ -62,10 +62,12 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from bcfl_tpu.ops import registry
+from bcfl_tpu.ops.flash import RESIDUAL_NAMES
 
 NEG_INF = -1e30  # large-negative, not -inf: exp underflows to 0 without NaNs
 LANES = 128  # TPU lane width: scratch/lse last dim must be 128
@@ -533,11 +535,18 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
 def _vjp_fwd(q, k, v, bias, causal, block_q, block_k):
     key_bias = _normalize_bias(bias, q.shape[0], k.shape[2])
     out, lse = _flash_fwd_pallas(q, k, v, key_bias, causal, block_q, block_k)
+    # The kernel writes a row's log-sum-exp into all LANES lanes: one is
+    # kept, [B, H, S], and spread again where the backward kernels read it.
+    # Both residuals are named for a rematerialising caller's policy
+    # (ops.flash.RESIDUAL_NAMES); a name is the identity elsewhere.
+    out = checkpoint_name(out, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], RESIDUAL_NAMES[1])
     return out, (q, k, v, bias, key_bias, out, lse)
 
 
 def _vjp_bwd(causal, block_q, block_k, res, g):
     q, k, v, bias, key_bias, out, lse = res
+    lse = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))
     args = (q, k, v, key_bias, out, g, lse, causal, block_q, block_k)
     dk, dv, db = _flash_bwd_dkv_pallas(*args)
     dq = _flash_bwd_dq_pallas(*args)

@@ -5,6 +5,7 @@ weights from the seed; the pieces against hand-written loops and hand values;
 the shares of the experts against the uncut layer; the clients' fold; the
 lowered step's operations; the counters; the config-time refusals."""
 
+import collections
 import json
 import math
 import os
@@ -134,8 +135,158 @@ def test_a_federated_round_through_the_engine_against_the_reference(tmp_path):
     assert 0 < c["moe_rows_max"] <= 2 * 16
     kids = res.metrics.phases["round_program"]["children"]["records"]
     assert kids["moe_slots_held"] == sum(int(r.counters["moe_slots_held"]) for r in recs)
+    # what the rematerialised layers keep is on the run's first event
+    with open(os.path.join(str(tmp_path), "telemetry", "events_engine.jsonl")) as f:
+        start = next(e for e in map(json.loads, f) if e.get("ev") == "run.start")
+    assert {k: start[k] for k in engine.remat_saved} == engine.remat_saved
     # the run's parameters are the base; the adapters are beside it
     assert res.params is engine.frozen
+
+
+# ------------------------------------- what a rematerialised layer keeps
+
+
+def test_remat_changes_no_result(sizes, seeded):
+    """``remat=True`` (the named save set) against ``remat=False``
+    (everything kept): the logits bit for bit, every adapter's gradient within
+    the room tests/test_remat.py gives tiny-llama (float32)."""
+    model, adapters, frozen, _ = seeded
+    assert model.cfg.remat
+    kept = build(fam.program(sizes)["model"], head="lm", vocab_size=sizes["vocab_rows"],
+                 dtype=jnp.float32, param_dtype=jnp.float32, remat=False)
+    b = _batch(sizes)
+    out = [m.apply(model_variables(m, adapters, frozen), b["ids"], b["mask"],
+                   mutable=["counters"])[0] for m in (model, kept)]
+    np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(out[1]))
+    grads = [jax.grad(lambda t, m=m: make_loss_fn(m, "causal_lm")(t, frozen, b, None)[0])(adapters)
+             for m in (model, kept)]
+    assert len(jax.tree.leaves(grads[0])) == 2 * (8 * sizes["layers"] + 1)
+    for path, g in jax.tree_util.tree_flatten_with_path(grads[0])[0]:
+        want = grads[1]
+        for k in path:
+            want = want[k.key]
+        np.testing.assert_allclose(np.asarray(g), np.asarray(want), rtol=0, atol=1e-6, err_msg=str(path))
+
+
+def _one_layer(seeded, S, remat, flash=True):
+    """One layer of the seeded model as a function of its input and its
+    adapters: ``(fn, x, adapters)``; ``remat``: "none" (keeps everything),
+    "plain" (``jax.checkpoint`` with no policy: keeps the input), "named"
+    (the model's own policy)."""
+    model, adapters, frozen, _ = seeded
+    cfg = model.cfg
+    layer = lm.LatentMoELayer(cfg)
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(2, S, cfg.hidden_size)), cfg.dtype)
+    mask = jnp.asarray(np.arange(S)[None] < np.array([S, S - 5])[:, None])
+    key_bias = jnp.where(mask, 0.0, -1e30).astype(jnp.float32)
+    bias = None if flash else lm.causal_bias(mask.astype(jnp.int32))
+    la = lora.as_collection(adapters)["layer_0"]
+
+    def fn(x, la):
+        y, _ = layer.apply({"params": frozen["layer_0"], "lora": la}, x, bias, key_bias,
+                           jnp.arange(S), mutable=["counters"])
+        return y.astype(jnp.float32).sum()
+
+    if remat == "plain":
+        fn = jax.checkpoint(fn)
+    elif remat == "named":
+        fn = jax.checkpoint(fn, policy=jax.checkpoint_policies.save_only_these_names(*lm.REMAT_SAVED))
+    return fn, x, la
+
+
+def _kept_shapes(cfg, B, S, r, kernel):
+    """``{(shape, dtype): how many}`` of the values ``REMAT_SAVED`` names in one layer."""
+    H, D, dt = cfg.num_heads, cfg.qk_head_dim, jnp.dtype(cfg.dtype)
+    kept = ([((B, S, r), jnp.dtype("float32"))] * 8  # every adapter's x a
+            + [((B, S, cfg.q_lora_rank), dt), ((B, S, cfg.kv_lora_rank + cfg.qk_rope_head_dim), dt)]
+            + [((B, H, S, D), dt)] * 3  # q, k, v
+            + [((B, S, cfg.hidden_size), dt)]  # the residual stream after attention
+            + [((B * S, cfg.n_routed_experts), jnp.dtype("float32")),
+               ((B * S, cfg.num_experts_per_tok), jnp.dtype("int32"))]
+            + [((B, S, cfg.moe_intermediate_size), dt)] * 2)  # the shared expert's gate and up
+    if kernel:  # the flash kernel's output and one lane of its log-sum-exp
+        kept += [((B, H, S, D), dt), ((B, H, S), jnp.dtype("float32"))]
+    return collections.Counter(kept)
+
+
+@pytest.mark.parametrize("path", ["dense", "kernel"])
+def test_a_rematerialised_layer_keeps_the_named_set_and_no_more(path, seeded, monkeypatch):
+    """``saved_residuals`` of one layer under the model's policy: beyond the
+    layer's arguments and constants (weights, here closed over), exactly the
+    values ``REMAT_SAVED`` names. A jitted function (``silu``, ``one_hot``)
+    hands a kept value on under its own name: the same value twice in the
+    list, once in memory."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from bcfl_tpu.ops import registry
+
+    kernel = path == "kernel"
+    if kernel:  # the Pallas kernels, interpreted on the CPU
+        monkeypatch.setattr(registry, "pallas_by_default", lambda: True)
+    S = 128 if kernel else 16
+    fn, x, la = _one_layer(seeded, S, "named", flash=kernel)
+    cfg = seeded[0].cfg
+    want = _kept_shapes(cfg, 2, S, 4, kernel)
+    got, handed_on = collections.Counter(), []
+    for aval, why in saved_residuals(fn, x, la):
+        if "from the argument" in why or "from a constant" in why:
+            continue
+        key = (tuple(aval.shape), jnp.dtype(aval.dtype))
+        if "jitted function" in why:
+            handed_on.append(key)
+        else:
+            got[key] += 1
+    for key in handed_on:  # a kept value under a second name, or in place of its first
+        if got[key] < want[key]:
+            got[key] += 1
+        else:
+            assert key in want, (key, "kept beyond the named set")
+    assert got == want
+    assert sum(want.values()) == len(lm.REMAT_SAVED) + 7 - (0 if kernel else 2)
+
+
+def test_the_backward_pass_runs_no_product_and_no_kernel_of_the_forward_again(seeded, monkeypatch):
+    """The jaxpr of one layer's gradient on the kernel's path: under the
+    model's policy it has the matrix products and the Pallas calls (forward,
+    dKV, dQ) of the layer that keeps everything, and no more; a
+    ``jax.checkpoint`` with no policy has the forward's again (the test can
+    see a retake)."""
+    from bcfl_tpu.ops import registry
+
+    monkeypatch.setattr(registry, "pallas_by_default", lambda: True)
+    counts = {}
+    for remat in ("none", "plain", "named"):
+        fn, x, la = _one_layer(seeded, 128, remat)
+        jaxpr = jax.make_jaxpr(jax.grad(fn, (0, 1)))(x, la).jaxpr
+        counts[remat] = (str(jaxpr).count("pallas_call"), len(_products_of(jaxpr, [])))
+    assert counts["none"][0] == 3 and counts["named"] == counts["none"], counts
+    assert counts["plain"][0] == 4 and counts["plain"][1] > counts["none"][1], counts
+
+
+def test_the_engine_records_what_the_layers_keep(tmp_path):
+    """``FedEngine.remat_saved`` (on the ``run.start`` event too): the named
+    values a layer keeps and their megabytes over the layers and a device's
+    clients at a local step's shapes; zeros for a model with no save set."""
+    from bcfl_tpu.fed.engine import FedEngine
+
+    cell, sz = harness.load_cell(CELL, plumbing=True)
+    run = harness.Run(cell, sz, SEED, 0.0, False, True, str(tmp_path), 0.0)
+    engine = harness.setup_engine(run)
+    cfg = engine.cfg
+    assert cfg.remat and engine.model.cfg.remat
+    got = engine.remat_saved
+    # the CPU runs the XLA blockwise attention, which has no residuals of its own to name
+    want = _kept_shapes(engine.model.cfg, cfg.batch_size, cfg.seq_len, cfg.lora_rank, kernel=False)
+    assert got["remat_saved_values"] == sum(want.values()) == 18
+    nbytes = sum(math.prod(shape) * dt.itemsize * n for (shape, dt), n in want.items())
+    assert got["remat_saved_mb_per_step"] == pytest.approx(
+        nbytes * sz["layers"] * engine.mesh.per_device / 1e6, abs=1e-3)
+    assert got["remat_saved_mb_per_step"] > 0
+    plain = FedEngine(FedConfig(name="plain", model="tiny-bert", dataset="synthetic", num_clients=2,
+                                num_rounds=1, seq_len=16, batch_size=4, max_local_batches=1, remat=True))
+    assert plain.model.cfg.remat and plain.remat_saved == {
+        "remat_saved_values": 0, "remat_saved_mb_per_step": 0.0}
 
 
 # --------------------------------------------------------------- the pieces

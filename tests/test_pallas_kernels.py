@@ -82,6 +82,46 @@ def test_pallas_backward_causal_and_padded():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
 
 
+@pytest.mark.parametrize("enclosing", ["nothing", "checkpoint", "checkpoint_that_keeps_the_names"])
+def test_pallas_residual_names_change_no_value_and_no_gradient(enclosing):
+    """The forward rule names the kernel's output and log-sum-exp
+    (``ops.flash.RESIDUAL_NAMES``) and keeps one lane of the second: the
+    value and the gradients are the dense oracle's, and bit for bit the same
+    whether nothing encloses the call, a ``jax.checkpoint`` with no policy
+    (the forward kernel runs again in the backward pass) or one whose policy
+    keeps the two names (it does not)."""
+    from bcfl_tpu.ops.flash import RESIDUAL_NAMES
+
+    B, H, S, D = 2, 2, 96, 8  # uneven blocks + padding + causal together
+    q, k, v = _qkv((B, H, S, D), seed=4)
+    mask = np.ones((B, S), np.int32)
+    mask[1, 70:] = 0
+    key_bias = jnp.asarray((1 - mask) * -1e30, jnp.float32)
+    real = jnp.asarray(mask)[:, None, :, None]
+
+    def plain(q, k, v):
+        return (flash_pl(q, k, v, key_bias, True, 32, 32) * real).sum()
+
+    fn = {"nothing": plain, "checkpoint": jax.checkpoint(plain),
+          "checkpoint_that_keeps_the_names": jax.checkpoint(
+              plain, policy=jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES))}[enclosing]
+    value, grads = jax.value_and_grad(fn, (0, 1, 2))(q, k, v)
+    want_value, want = jax.value_and_grad(plain, (0, 1, 2))(q, k, v)
+    assert float(value) == float(want_value)
+    for a, b in zip(grads, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    oracle = jax.grad(lambda q, k, v: (flash_attention_xla(
+        q, k, v, key_bias[:, None, None, :], block_size=32, causal=True) * real).sum(), (0, 1, 2))(q, k, v)
+    for a, b in zip(grads, oracle):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-5)
+    calls = str(jax.make_jaxpr(jax.grad(fn, (0, 1, 2)))(q, k, v)).count("pallas_call")
+    assert calls == (4 if enclosing == "checkpoint" else 3)
+    # one lane of the log-sum-exp is what the backward pass is handed
+    _, vjp = jax.vjp(plain, q, k, v)
+    assert (B, H, S) in {tuple(x.shape) for x in jax.tree.leaves(vjp)}
+    assert (B, H, S, 128) not in {tuple(x.shape) for x in jax.tree.leaves(vjp)}
+
+
 def test_pallas_bias_gradient():
     """The hand-written backward produces the key-bias gradient too (the XLA
     oracle differentiates through its dense-bias path)."""

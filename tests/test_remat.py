@@ -15,6 +15,9 @@ from bcfl_tpu.models import build
     ("tiny-bert", {}, 0.0),
     ("tiny-albert", {}, 0.0),  # share_layers path wraps the shared layer once
     ("tiny-llama", {}, 1e-6),
+    # keeps a named set of each layer's values (models/latent_moe.py::
+    # REMAT_SAVED) and recomputes the rest: norms, silu, softmax
+    ("tiny-latent-moe", {"head": "lm"}, 1e-6),
 ])
 def test_remat_is_numerically_identical(name, kw, grad_tol):
     """Forward logits must be BIT-identical for every family (remat replays

@@ -1,7 +1,8 @@
-"""The flash kernels compiled for a DESCRIBED TPU v5e, with no chip attached
-(the TPU's compiler is installed here): what interpret mode cannot show,
-above all whether a block request fits the kernel's scoped VMEM. Nothing
-runs, so nothing here is a time or a result.
+"""The flash kernels, and the expert decoder's round program at one layer,
+compiled for a DESCRIBED TPU v5e, with no chip attached (the TPU's compiler
+is installed here): what interpret mode cannot show, above all whether a
+block request fits the kernel's scoped VMEM and which kernels the compiled
+backward pass calls. Nothing runs, so nothing here is a time or a result.
 
 The topology is described inside a fixture and these tests stay in this one
 file: only one process may load the TPU's library, and under xdist every
@@ -76,3 +77,48 @@ def test_whole_row_blocks_compile_only_with_the_reckoned_vmem_request(
     monkeypatch.setattr(pallas_flash, "VMEM_DEFAULT_BYTES", 1 << 40)
     with pytest.raises(Exception, match="vmem"):
         _compile_grad(one_chip, 4096, 128, jnp.bfloat16, 2048, 1024)
+
+
+def test_the_expert_decoders_round_program_runs_no_forward_kernel_twice(
+        one_chip, for_the_chip, monkeypatch):
+    """The fused round program the benchmark's expert-decoder cell dispatches
+    (published widths, ONE layer, the cell's rows, ``remat``, LoRA r16,
+    ``donate``), compiled for the described chip: a layer's kernels are the
+    three flash kernels and the grouped products' three forward and five
+    backward calls, so the backward pass runs no forward kernel again (12
+    calls with the forward flash kernel retaken), and the compiler
+    rematerialised nothing on its own to fit."""
+    from bcfl_tpu.core.mesh import client_mesh
+    from bcfl_tpu.fed.client_step import build_programs
+    from bcfl_tpu.models import build, lora, lora_policy
+
+    monkeypatch.setattr(registry, "pallas_by_default", lambda: True)
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    C, T, B, S, K = 2, 4, 2, 2048, 2
+    model = build("mistral-small-4@layers=1,experts_held=16", head="lm", vocab_size=16384,
+                  dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, remat=True, use_flash=True,
+                  flash_min_seq=0)
+    ids = jnp.ones((2, S), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.key(0), ids, ids)["params"]
+    pol = lora_policy(model)
+    adapters = jax.eval_shape(lambda p: lora.init_lora(
+        jax.random.key(1), p, 16, targets=pol.targets, head_modules=pol.head_modules,
+        dtype=pol.adapter_dtype), params)
+    progs = build_programs(model, client_mesh(C, devices=list(one_chip.device_set)), optimizer="adamw",
+                           learning_rate=1e-4, task="causal_lm", donate=True)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    batches = {"ids": sds((C, T, B, S), jnp.int32), "mask": sds((C, T, B, S), jnp.int32),
+               "example_mask": sds((C, T, B), jnp.float32)}
+    per_round = sds((K, C), jnp.float32)
+    compiled = progs.server_rounds_static_fp.lower(
+        on_chip(adapters), on_chip(params), batches, per_round, sds((K, C, 2), jnp.uint32),
+        per_round).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 + 3 + 5
+    assert ".remat" not in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9  # 1.19 + 2.40 GB at one layer

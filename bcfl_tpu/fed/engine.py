@@ -365,7 +365,7 @@ class FedEngine:
             policy = self._lora_policy
             how = dict(targets=policy.targets,
                        head_modules=policy.head_modules,
-                       dtype=policy.adapter_dtype)
+                       dtype=policy.adapter_dtype, tied=policy.tied)
             self.frozen = params
             ranks = cfg.client_lora_ranks
             if ranks is not None and len(set(ranks)) > 1:

@@ -7,10 +7,12 @@ The reference's model space is three HF checkpoints (SURVEY.md §2.1):
 the scale-down smoke models (the reference's de-facto test method is a
 NUM_CLIENTS=2/NUM_ROUNDS=2 scale-down of the same script — SURVEY.md §4).
 
-Three families: ``encoder`` (:mod:`bcfl_tpu.models.bert`), ``llama``
-(:mod:`bcfl_tpu.models.llama`) and ``latent_moe``
+Four families: ``encoder`` (:mod:`bcfl_tpu.models.bert`), ``llama``
+(:mod:`bcfl_tpu.models.llama`), ``latent_moe``
 (:mod:`bcfl_tpu.models.latent_moe`: latent attention, an expert layer that
-holds a share of the experts). :func:`family_of` names a registry name's or a
+holds a share of the experts) and ``ssm_moe``
+(:mod:`bcfl_tpu.models.ssm_moe`: state-space and attention layers in a
+pattern, the same expert layer, a tied head). :func:`family_of` names a registry name's or a
 built model's family; :func:`build`, :func:`lora_policy` and
 :func:`tp_param_specs` dispatch on it and refuse what a family does not
 support with the names that exist.
@@ -77,9 +79,10 @@ def _llama_configs():
 
 _LATENT_MOE_CONFIGS: Dict[str, "LatentMoEConfig"] = {}
 
-# a latent_moe name may carry the cut this chip runs after an "@":
-# "mistral-small-4@layers=8,experts_held=16" (n experts held: 0 .. n-1)
-_LATENT_MOE_CUTS = {"layers": "num_layers", "experts_held": "experts_held"}
+# a name of a family with an expert layer may carry the cut this chip runs
+# after an "@": "mistral-small-4@layers=8,experts_held=16" (n experts held:
+# 0 .. n-1; of a pattern of layer kinds, the first ``layers``)
+_CUTS = {"layers": "num_layers", "experts_held": "experts_held"}
 
 
 def _latent_moe_configs():
@@ -103,20 +106,51 @@ def _latent_moe_configs():
     return _LATENT_MOE_CONFIGS
 
 
+_SSM_MOE_CONFIGS: Dict[str, "SSMMoEConfig"] = {}
+
+
+def _ssm_moe_configs():
+    global _SSM_MOE_CONFIGS
+    if not _SSM_MOE_CONFIGS:
+        from bcfl_tpu.models.ssm_moe import SSMMoEConfig
+
+        _SSM_MOE_CONFIGS = {
+            # test scale-down: 3 mamba layers and 1 attention layer; 4 heads
+            # of 8 with a state of 16 in chunks of 8; 4 query heads over 2
+            # key-value heads; 8 experts of width 16 with 3 a token, a
+            # shared MLP of 32
+            "tiny-ssm-moe": SSMMoEConfig(
+                vocab_size=512, hidden_size=32, num_layers=4,
+                layer_types=("mamba", "mamba", "attention", "mamba"),
+                num_heads=4, num_kv_heads=2, attention_multiplier=0.25,
+                mamba_n_heads=8, mamba_d_head=8, mamba_d_state=16,
+                mamba_chunk_size=8, num_local_experts=8,
+                num_experts_per_tok=3, intermediate_size=16,
+                shared_intermediate_size=32),
+            # ibm-granite/granite-4.0-h-small as published (the dataclass's
+            # defaults): 40 layers, every expert
+            "granite-4.0-h-small": SSMMoEConfig(),
+        }
+    return _SSM_MOE_CONFIGS
+
+
 def _split_cut(name: str):
     """``"base@k=v,..."`` -> ``(base, {config field: value})``."""
     base, _, cut = name.partition("@")
     out = {}
     for item in filter(None, cut.split(",")):
         k, _, v = item.partition("=")
-        if k not in _LATENT_MOE_CUTS:
+        if k not in _CUTS:
             raise KeyError(f"unknown cut {k!r} in model name {name!r}; a "
-                           f"name may carry {sorted(_LATENT_MOE_CUTS)}")
-        out[_LATENT_MOE_CUTS[k]] = int(v)
+                           f"name may carry {sorted(_CUTS)}")
+        out[_CUTS[k]] = int(v)
     return base, out
 
 
-FAMILIES = ("encoder", "llama", "latent_moe")
+FAMILIES = ("encoder", "llama", "latent_moe", "ssm_moe")
+# the families with an LM head only, adapters on the activations and an
+# expert layer that holds a share (one set of refusals, one LoRA policy)
+_EXPERT_FAMILIES = ("latent_moe", "ssm_moe")
 
 
 def family_of(model) -> str:
@@ -129,6 +163,8 @@ def family_of(model) -> str:
             return "llama"
         if base in _latent_moe_configs():
             return "latent_moe"
+        if base in _ssm_moe_configs():
+            return "ssm_moe"
         raise KeyError(f"unknown model {model!r}; have {list_models()}")
     name = type(model).__name__
     if isinstance(model, TextClassifier):
@@ -137,6 +173,8 @@ def family_of(model) -> str:
         return "llama"
     if name == "LatentMoELM":
         return "latent_moe"
+    if name == "SSMMoELM":
+        return "ssm_moe"
     raise TypeError(f"{name} is a model of no family of the registry {FAMILIES}")
 
 
@@ -149,12 +187,13 @@ def get_config(name: str, **overrides):
     if family == "llama":
         return dataclasses.replace(_llama_configs()[name], **overrides)
     base, cut = _split_cut(name)
-    return dataclasses.replace(_latent_moe_configs()[base], **{**cut, **overrides})
+    configs = _latent_moe_configs() if family == "latent_moe" else _ssm_moe_configs()
+    return dataclasses.replace(configs[base], **{**cut, **overrides})
 
 
 def list_models():
     return (sorted(_CONFIGS) + sorted(_llama_configs())
-            + sorted(_latent_moe_configs()))
+            + sorted(_latent_moe_configs()) + sorted(_ssm_moe_configs()))
 
 
 def build(name: str, head: str = "classifier", **overrides):
@@ -162,7 +201,8 @@ def build(name: str, head: str = "classifier", **overrides):
     ``apply(vars, ids, mask, deterministic=...) -> logits``. ``head="lm"``
     builds the causal-LM variant ([B, S, vocab] logits): the decoders only
     (encoders are bidirectional, so next-token training would leak the
-    target), and the only head of the ``latent_moe`` family."""
+    target), and the only head of the ``latent_moe`` and ``ssm_moe``
+    families."""
     cfg = get_config(name, **overrides)
     family = family_of(name)
     if head not in ("classifier", "lm"):
@@ -171,7 +211,7 @@ def build(name: str, head: str = "classifier", **overrides):
         if head == "lm":
             raise ValueError(
                 f"model {name!r} is an encoder: causal-LM training needs a "
-                "decoder (the llama and latent_moe families)")
+                "decoder (the llama, latent_moe and ssm_moe families)")
         return TextClassifier(cfg)
     if family == "llama":
         from bcfl_tpu.models.llama import LlamaClassifier, LlamaLM
@@ -179,12 +219,16 @@ def build(name: str, head: str = "classifier", **overrides):
         return LlamaLM(cfg) if head == "lm" else LlamaClassifier(cfg)
     if head != "lm":
         raise ValueError(
-            f"model {name!r} (family latent_moe) has an LM head only: "
+            f"model {name!r} (family {family}) has an LM head only: "
             "task='causal_lm'; classification heads exist for the encoder "
             "and llama families")
-    from bcfl_tpu.models.latent_moe import LatentMoELM
+    if family == "latent_moe":
+        from bcfl_tpu.models.latent_moe import LatentMoELM
 
-    return LatentMoELM(cfg)
+        return LatentMoELM(cfg)
+    from bcfl_tpu.models.ssm_moe import SSMMoELM
+
+    return SSMMoELM(cfg)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,12 +241,17 @@ class LoRAPolicy:
     ``on_activations``: the adapters enter the forward pass as ``x W + (x a)
     b`` through the model's ``lora`` collection (no merged kernel, no
     weight-gradient product of a frozen kernel); False = merged into the
-    base, ``W + a b``, before the model runs (``fed.lora_merge``)."""
+    base, ``W + a b``, before the model runs (``fed.lora_merge``).
+    ``tied``: ``((module, leaf path), ...)``, adapters of modules that have
+    no kernel of their own and multiply by the TRANSPOSE of another module's
+    leaf (a tied head, ``("lm_head", "embed/embedding")``): ``a`` [leaf's
+    columns, r], ``b`` [r, leaf's rows]."""
 
     targets: tuple
     head_modules: tuple = lora.HEAD_MODULES
     adapter_dtype: Optional[str] = None
     on_activations: bool = False
+    tied: tuple = ()
 
 
 def lora_policy(model) -> LoRAPolicy:
@@ -214,12 +263,18 @@ def lora_policy(model) -> LoRAPolicy:
         from bcfl_tpu.models.llama import LORA_TARGETS
 
         return LoRAPolicy(targets=LORA_TARGETS)
-    from bcfl_tpu.models.latent_moe import LORA_TARGETS
-
     # float32 adapters over a bfloat16 base: AdamW's step at a fine-tuning
     # learning rate is under half a bfloat16 rounding (PERF.md section 7)
+    if family == "latent_moe":
+        from bcfl_tpu.models.latent_moe import LORA_TARGETS
+
+        return LoRAPolicy(targets=LORA_TARGETS, head_modules=(),
+                          adapter_dtype="float32", on_activations=True)
+    from bcfl_tpu.models.ssm_moe import LORA_TARGETS
+
     return LoRAPolicy(targets=LORA_TARGETS, head_modules=(),
-                      adapter_dtype="float32", on_activations=True)
+                      adapter_dtype="float32", on_activations=True,
+                      tied=(("lm_head", "embed/embedding"),))
 
 
 def lora_targets(name: str):
@@ -236,7 +291,7 @@ def refusals(model: str, *, task: str, lora_rank: int, tp: int, sp: int,
         family = family_of(model)
     except KeyError:
         return None  # an unknown name is the registry's error, at build time
-    if family != "latent_moe":
+    if family not in _EXPERT_FAMILIES:
         return None
     why = []
     if task != "causal_lm":
@@ -247,19 +302,20 @@ def refusals(model: str, *, task: str, lora_rank: int, tp: int, sp: int,
                    "frozen and the grouped product has no weight-gradient "
                    "pass; train adapters (lora_rank > 0)")
     if tp > 1:
-        why.append(f"tp={tp}: no tensor-parallel layout for latent attention "
-                   "or for the expert layer (an expert axis in core/mesh.py "
-                   "is not there yet)")
+        why.append(f"tp={tp}: no tensor-parallel layout for latent attention, "
+                   "for a state-space mixer or for the expert layer (an "
+                   "expert axis in core/mesh.py is not there yet)")
     if sp > 1:
         why.append(f"sp={sp}: ring attention is not wired into latent "
-                   "attention")
+                   "attention, and no state-space scan carries its state "
+                   "from chip to chip")
     if client_lora_ranks is not None and len(set(client_lora_ranks)) > 1:
         why.append("heterogeneous client_lora_ranks: rank clipping acts on "
                    "merged adapters, and this family's adapters enter on the "
                    "activations")
     if not why:
         return None
-    return f"model {model!r} (family latent_moe) does not run with " + "; ".join(why)
+    return f"model {model!r} (family {family}) does not run with " + "; ".join(why)
 
 
 def tp_param_specs(model, params, axis: str = "tp"):

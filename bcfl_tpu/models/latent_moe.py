@@ -13,30 +13,17 @@ head, a final RMSNorm.
   and value heads are equally wide here, so the repo's dense and flash
   attention take them as they are; they scale by ``D^-0.5`` alone, so the
   rest of the scale multiplies ``q``.
-- **Expert layer**: a float32 softmax router over ALL ``n_routed_experts``,
-  the top ``num_experts_per_tok`` renormalised, plus shared experts. The
-  layer is told which experts it HOLDS (``experts_held``) and computes their
-  part of the result; what the absent experts would add is left out, and
-  that partial result goes on (no code stands in for the absent chips or
-  their exchange). No token is dropped and no capacity is set: the
-  assignments are sorted by expert, the held ones go through one grouped
-  product a projection (:mod:`bcfl_tpu.ops.grouped_matmul`), the absent ones
-  sort to the tail where nothing is computed. No balance term: the router is
-  frozen.
-- **LoRA on the activations**: every dense product is a :class:`LoRADense`,
-  ``x W + (x a) b`` when the ``lora`` collection carries ``a`` and ``b`` for
-  it (``models.policy``): no ``[in, out]`` product ``a b``, no merged kernel
-  a client, no weight-gradient product of a frozen kernel.
-- **Clients fold into rows**: under the round program's ``vmap`` over
-  clients with the frozen base NOT batched, the expert block's own batching
-  rule (:func:`expert_block`) runs one grouped product over all clients'
-  rows; the base is never broadcast.
+- **Expert layer** (:mod:`bcfl_tpu.models.experts`, shared with the other
+  family that has one): a float32 softmax router over ALL
+  ``n_routed_experts``, the top ``num_experts_per_tok`` renormalised, plus
+  shared experts; the layer is told which experts it HOLDS
+  (``experts_held``) and computes their part of the result.
+- **LoRA on the activations**: every dense product is an
+  ``experts.LoRADense``, ``x W + (x a) b``.
 - **Rematerialisation** (``remat=True``): ``nn.remat`` a layer with a
   policy that keeps the values :data:`REMAT_SAVED` names, so the backward
   pass recomputes norms, rotary, gate and copies, and no product or kernel.
-- **Counters** (collection ``counters``, :data:`COUNTERS`): the real
-  positions' assignments that fell on held and on absent experts, and the
-  fullest held expert's rows. Padded positions are routed to no expert.
+- **Counters**: the expert layer's (``experts.COUNTERS``).
 
 Named scopes (``metrics.tracing.scope``, inside ``fed.forward``): ``fed.mla``,
 ``fed.moe.route``, ``fed.moe.experts``, ``fed.moe.shared``, ``fed.lm_head``,
@@ -52,27 +39,18 @@ from typing import Optional, Tuple, Union
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax import lax
 from jax.ad_checkpoint import checkpoint_name
-from jax.custom_batching import custom_vmap
 
 from bcfl_tpu.metrics.tracing import scope
+from bcfl_tpu.models.experts import COUNTERS, ExpertLayer, dense, held_experts
 from bcfl_tpu.models.llama import RMSNorm, causal_bias, rope
 from bcfl_tpu.ops.attention import dot_product_attention
 from bcfl_tpu.ops.flash import RESIDUAL_NAMES, flash_attention
-from bcfl_tpu.ops.grouped_matmul import grouped_matmul
 
 # the kernels that carry an adapter; the router and the routed experts stay
 # frozen and untargeted
 LORA_TARGETS = ("q_a_proj", "q_b_proj", "kv_a_proj", "kv_b_proj", "o_proj",
                 "gate_proj", "up_proj", "down_proj", "lm_head")
-
-# what the model counts in a step, and how a count folds over steps, clients
-# and rounds: ``(name, "sum" | "max")``, sums first. Per client: the slots
-# are one client's assignments, the fullest expert's rows one client's rows.
-COUNTERS = (("moe_slots_held", "sum"), ("moe_slots_absent", "sum"),
-            ("moe_rows_max", "max"))
 
 # What a rematerialised layer KEEPS of its forward pass (``remat=True``: the
 # backward pass of a layer recomputes everything else from these and the
@@ -147,16 +125,7 @@ class LatentMoEConfig:
     @property
     def held(self) -> Tuple[int, ...]:
         """The held experts' indices, ascending."""
-        h = self.experts_held
-        if h is None:
-            h = self.n_routed_experts
-        held = tuple(range(h)) if isinstance(h, int) else tuple(sorted(h))
-        if (not held or len(set(held)) != len(held) or held[0] < 0
-                or held[-1] >= self.n_routed_experts):
-            raise ValueError(
-                f"experts_held {self.experts_held!r} is not a set of experts "
-                f"out of {self.n_routed_experts}")
-        return held
+        return held_experts(self.experts_held, self.n_routed_experts)
 
 
 # ------------------------------------------------------- rotary frequencies
@@ -189,55 +158,6 @@ def softmax_scale(cfg: LatentMoEConfig) -> float:
     return cfg.qk_head_dim ** -0.5 * m * m
 
 
-# ------------------------------------------------------------------- dense
-
-
-class LoRADense(nn.Module):
-    """``x W`` with a 2-D frozen kernel, plus ``(x a) b`` on the activations
-    when the ``lora`` collection has this module's ``a`` [in, r] and ``b``
-    [r, out]: products in the compute type, adapters stored in their own."""
-
-    features: int
-    dtype: jnp.dtype
-    param_dtype: jnp.dtype
-    init_std: float = 0.02
-    out_dtype: Optional[jnp.dtype] = None  # None = the compute type
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param("kernel", nn.initializers.normal(self.init_std),
-                            (x.shape[-1], self.features), self.param_dtype)
-        out = self.out_dtype or self.dtype
-        x = x.astype(self.dtype)
-        y = jnp.dot(x, kernel.astype(self.dtype), preferred_element_type=out)
-        if self.has_variable("lora", "a"):
-            with scope("lora"):
-                a = self.get_variable("lora", "a").astype(self.dtype)
-                b = self.get_variable("lora", "b").astype(self.dtype)
-                xa = checkpoint_name(
-                    jnp.dot(x, a, preferred_element_type=jnp.float32), "lora_xa")
-                y = y + jnp.dot(xa.astype(self.dtype), b,
-                                preferred_element_type=out)
-        return y
-
-
-def _dense(c: LatentMoEConfig, features: int, name: str, **kw):
-    return LoRADense(features, c.dtype, c.param_dtype, c.initializer_range,
-                     name=name, **kw)
-
-
-class SwiGLU(nn.Module):
-    cfg: LatentMoEConfig
-    width: int
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.cfg
-        gate = checkpoint_name(_dense(c, self.width, "gate_proj")(x), "shared_gate")
-        up = checkpoint_name(_dense(c, self.width, "up_proj")(x), "shared_up")
-        return _dense(c, c.hidden_size, "down_proj")(nn.silu(gate) * up)
-
-
 # --------------------------------------------------------------- attention
 
 
@@ -251,13 +171,13 @@ class LatentAttention(nn.Module):
         H, dn, dr, dv = (c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim,
                          c.v_head_dim)
         cq = RMSNorm(c.rms_eps, c.param_dtype, name="q_a_norm")(
-            checkpoint_name(_dense(c, c.q_lora_rank, "q_a_proj")(x), "mla_q_a"))
-        q = _dense(c, H * (dn + dr), "q_b_proj")(cq)
+            checkpoint_name(dense(c, c.q_lora_rank, "q_a_proj")(x), "mla_q_a"))
+        q = dense(c, H * (dn + dr), "q_b_proj")(cq)
         q = q.reshape(B, S, H, dn + dr).transpose(0, 2, 1, 3)
         ckv = checkpoint_name(
-            _dense(c, c.kv_lora_rank + dr, "kv_a_proj")(x), "mla_kv_a")
+            dense(c, c.kv_lora_rank + dr, "kv_a_proj")(x), "mla_kv_a")
         k_rope = ckv[..., c.kv_lora_rank:][:, None]  # one head [B, 1, S, dr]
-        kv = _dense(c, H * (dn + dv), "kv_b_proj")(
+        kv = dense(c, H * (dn + dv), "kv_b_proj")(
             RMSNorm(c.rms_eps, c.param_dtype, name="kv_a_norm")(
                 ckv[..., :c.kv_lora_rank]))
         kv = kv.reshape(B, S, H, dn + dv).transpose(0, 2, 1, 3)
@@ -289,229 +209,7 @@ class LatentAttention(nn.Module):
         else:
             out = dot_product_attention(q, k, v, bias)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
-        return _dense(c, c.hidden_size, "o_proj")(out)
-
-
-# ------------------------------------------------------------ expert block
-
-# The sorted assignments go through the experts a chunk at a time, and only
-# the chunks that hold an assignment to a HELD expert run: those sort first,
-# and a chunk is this share of all the assignments, so where a chip holds an
-# eighth of the experts one chunk is the rule, and a step whose routing sends
-# more to the held experts takes more chunks and drops nothing.
-CHUNK_SHARE = 4
-
-
-def _chunks(slot, cw, G):
-    """The N*k assignments sorted by slot (held experts first, in order; the
-    absent ones, slot G, at the tail), cut into chunks: ``(rows_of, n, order)``.
-    ``rows_of(c)`` gives chunk c's sorted assignments ``idx`` (padded past the
-    last), their tokens, which of them fall on a held expert, their combine
-    weights (zero elsewhere) and the chunk's group sizes; ``n`` is how many
-    chunks hold a held assignment; ``order`` is the sorting permutation."""
-    N, k = slot.shape
-    M = N * k
-    flat = slot.reshape(-1)
-    chunk = -(-M // CHUNK_SHARE)
-    by_slot = jnp.argsort(flat, stable=True)
-    order = jnp.pad(by_slot, (0, CHUNK_SHARE * chunk - M))
-    # where each held expert's rows end in the sorted order (no scatter: the
-    # TPU compiler merges look-alike scatters into one it cannot emit)
-    ends = jnp.searchsorted(flat[by_slot], jnp.arange(G), side="right")
-    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
-    total = ends[-1]
-    cwf = cw.reshape(-1)
-
-    def rows_of(c):
-        lo = c * chunk
-        idx = lax.dynamic_slice(order, (lo,), (chunk,))
-        live = lo + jnp.arange(chunk) < total
-        sizes = (jnp.clip(ends, lo, lo + chunk)
-                 - jnp.clip(starts, lo, lo + chunk)).astype(jnp.int32)
-        return idx, idx // k, live, jnp.where(live, cwf[idx], 0.0), sizes
-
-    return rows_of, (total + chunk - 1) // chunk, by_slot
-
-
-def _silu_gate(g, u):
-    """``silu(g) * u`` in float32 and what its gradient needs."""
-    gf, uf = g.astype(jnp.float32), u.astype(jnp.float32)
-    sg = jax.nn.sigmoid(gf)
-    return gf, uf, sg
-
-
-def _expert_block_fwd(x, slot, cw, wg, wu, wd):
-    """One row axis: ``x`` [N, H], ``slot`` [N, k] (a held expert's place in
-    ``wg``/``wu``/``wd``, or their count G for an absent expert or a padded
-    position), ``cw`` [N, k] combine weights. Returns ``(y,)``, ``y``
-    [N, H]."""
-    rows_of, n, _ = _chunks(slot, cw, wg.shape[0])
-
-    def chunk(c, y):
-        with scope("moe.route"):
-            _, tok, _, cws, sizes = rows_of(c)
-            xs = x[tok]
-        with scope("moe.experts"):
-            gf, uf, sg = _silu_gate(grouped_matmul(xs, wg, sizes),
-                                    grouped_matmul(xs, wu, sizes))
-            o = grouped_matmul((gf * sg * uf).astype(x.dtype), wd, sizes)
-        with scope("moe.route"):
-            return y.at[tok].add(o.astype(jnp.float32) * cws[:, None])
-
-    y = lax.fori_loop(0, n, chunk, jnp.zeros(x.shape, jnp.float32))
-    return (y.astype(x.dtype),)
-
-
-def _expert_block_bwd(x, slot, cw, dy, wg, wu, wd):
-    """``(dx, dcw)``: the gate and up products once more, then the
-    activation-gradient products of the same frozen weights
-    (``transpose_rhs``); no weight-gradient product. The combine weight's
-    gradient ``<o, dy>`` is read as ``<silu(g) u, dy W_down^T>``, which the
-    pass has anyway."""
-    rows_of, n, order = _chunks(slot, cw, wg.shape[0])
-
-    M = slot.size
-    span = -(-M // CHUNK_SHARE)
-
-    def chunk(c, carry):
-        dx, dcw_sorted = carry
-        with scope("moe.route"):
-            _, tok, live, cws, sizes = rows_of(c)
-            xs, dys = x[tok], dy[tok]
-        with scope("moe.experts"):
-            gf, uf, sg = _silu_gate(grouped_matmul(xs, wg, sizes),
-                                    grouped_matmul(xs, wu, sizes))
-            da = grouped_matmul(dys, wd, sizes,
-                                transpose_rhs=True).astype(jnp.float32)
-            dcws = jnp.where(live, (gf * sg * uf * da).sum(-1), 0.0)
-            da = da * cws[:, None]
-            dg = (da * uf * sg * (1.0 + gf * (1.0 - sg))).astype(x.dtype)
-            du = (da * gf * sg).astype(x.dtype)
-            dxs = (grouped_matmul(dg, wg, sizes, transpose_rhs=True).astype(jnp.float32)
-                   + grouped_matmul(du, wu, sizes, transpose_rhs=True).astype(jnp.float32))
-        with scope("moe.route"):
-            # the combine weights' gradient stays in the sorted order here
-            return (dx.at[tok].add(dxs),
-                    lax.dynamic_update_slice(dcw_sorted, dcws, (c * span,)))
-
-    dx, dcw_sorted = lax.fori_loop(
-        0, n, chunk, (jnp.zeros(x.shape, jnp.float32),
-                      jnp.zeros((CHUNK_SHARE * span,), jnp.float32)))
-    with scope("moe.route"):
-        # where each assignment sits in the sorted order: the inverse permutation
-        dcw = dcw_sorted[jnp.argsort(order)].reshape(cw.shape)
-    return dx.astype(x.dtype), dcw.astype(cw.dtype)
-
-
-def _fold(fn):
-    """``fn`` with the batching rule that folds a ``vmap``'s axis into the
-    row axis: the per-row arguments (those before the three weight stacks)
-    are reshaped ``[C, N, ...] -> [C * N, ...]``, the weights have to be
-    unbatched (a frozen base under a stack of clients), and the results are
-    cut back to ``[C, N, ...]``. The sort inside ``fn`` then groups all
-    clients' rows by expert, so the grouped products see all the clients'
-    rows and each held expert's weights once."""
-    wrapped = custom_vmap(fn)
-
-    @wrapped.def_vmap
-    def rule(axis_size, in_batched, *args):
-        rows, weights = args[:-3], args[-3:]
-        if any(jax.tree.leaves(in_batched[-3:])):
-            raise NotImplementedError(
-                "the expert block under vmap takes expert weights that are "
-                "not batched (one frozen base for the stack of clients); "
-                "batched expert weights would be held once a client")
-        rows = [r if b else jnp.broadcast_to(r[None], (axis_size,) + r.shape)
-                for r, b in zip(rows, in_batched[:-3])]
-        out = wrapped(*(r.reshape((-1,) + r.shape[2:]) for r in rows), *weights)
-        out = tuple(o.reshape((axis_size, -1) + o.shape[1:]) for o in out)
-        return out, tuple(True for _ in out)
-
-    return wrapped
-
-
-_fwd_folded = _fold(_expert_block_fwd)
-_bwd_folded = _fold(_expert_block_bwd)
-
-
-@jax.custom_vjp
-def expert_block(x, slot, cw, wg, wu, wd):
-    """The held routed experts' part of the layer for rows ``x`` [N, H]:
-    ``sum_j cw[n, j] SwiGLU_{slot[n, j]}(x[n])`` over a row's assignments
-    that fall on held experts. Differentiable in ``x`` and ``cw``; the expert
-    weights are frozen (their cotangent is zero: full fine-tuning of this
-    family is refused at config time). Under ``jax.vmap`` with unbatched
-    weights the clients fold into the rows (:func:`_fold`)."""
-    return _fwd_folded(x, slot, cw, wg, wu, wd)[0]
-
-
-def _eb_fwd(x, slot, cw, wg, wu, wd):
-    return expert_block(x, slot, cw, wg, wu, wd), (x, slot, cw, wg, wu, wd)
-
-
-def _eb_bwd(res, dy):
-    x, slot, cw, wg, wu, wd = res
-    dx, dcw = _bwd_folded(x, slot, cw, dy, wg, wu, wd)
-    return dx, None, dcw, None, None, None
-
-
-expert_block.defvjp(_eb_fwd, _eb_bwd)
-
-
-class ExpertLayer(nn.Module):
-    cfg: LatentMoEConfig
-
-    @nn.compact
-    def __call__(self, x, valid):
-        """``valid`` [B, S]: the real positions. A padded position is routed
-        to no expert: it feeds no loss and no real position attends to it,
-        and every padded position of a row has the same hidden state, so they
-        would all fall on the same experts (a fifth of a step's rows on one
-        expert, held or not by the seed's draw)."""
-        c = self.cfg
-        B, S, Hd = x.shape
-        E, k, F = c.n_routed_experts, c.num_experts_per_tok, c.moe_intermediate_size
-        held = c.held
-        G = len(held)
-        init = nn.initializers.normal(c.initializer_range)
-        w_r = self.param("router", init, (Hd, E), c.param_dtype)
-        wg = self.param("experts_gate", init, (G, Hd, F), c.param_dtype)
-        wu = self.param("experts_up", init, (G, Hd, F), c.param_dtype)
-        wd = self.param("experts_down", init, (G, F, Hd), c.param_dtype)
-        rows = x.reshape(B * S, Hd)
-        with scope("moe.route"):
-            # exact products of the stored values, float32 sums
-            logits = checkpoint_name(
-                jnp.dot(rows.astype(jnp.float32), w_r.astype(jnp.float32),
-                        precision=lax.Precision.HIGHEST), "router_logits")
-            probs = jax.nn.softmax(logits, axis=-1)
-            idx = checkpoint_name(lax.top_k(probs, k)[1], "router_idx")
-            # the chosen probabilities by a mask, the counts by comparison:
-            # a scatter here (top_k's gradient, bincount) is one the TPU
-            # compiler has failed on inside the round program's loops
-            p = (probs[:, None, :] * jax.nn.one_hot(idx, E, dtype=probs.dtype)).sum(-1)
-            cw = p / p.sum(-1, keepdims=True)
-            place = np.full((E,), G, np.int32)  # an expert's place, G = absent
-            place[list(held)] = np.arange(G)
-            real = valid.reshape(B * S, 1)
-            slot = jnp.where(real, jnp.asarray(place)[idx], G)
-            per_expert = (slot.reshape(-1, 1) == jnp.arange(G)).sum(0)
-            n_held = per_expert.sum().astype(jnp.float32)
-            self.sow("counters", "moe_slots_held", n_held,
-                     init_fn=lambda: 0.0, reduce_fn=jnp.add)
-            self.sow("counters", "moe_slots_absent",
-                     real.sum().astype(jnp.float32) * k - n_held,
-                     init_fn=lambda: 0.0, reduce_fn=jnp.add)
-            self.sow("counters", "moe_rows_max",
-                     per_expert.max().astype(jnp.float32),
-                     init_fn=lambda: 0.0, reduce_fn=jnp.maximum)
-        # sort, unsort and combine name themselves fed.moe.route inside, the
-        # grouped products fed.moe.experts
-        y = expert_block(rows, slot, cw, wg.astype(c.dtype),
-                         wu.astype(c.dtype), wd.astype(c.dtype))
-        with scope("moe.shared"):
-            shared = SwiGLU(c, c.n_shared_experts * F, name="shared_experts")(x)
-        return shared + y.reshape(B, S, Hd)
+        return dense(c, c.hidden_size, "o_proj")(out)
 
 
 # ------------------------------------------------------------------- model
@@ -559,4 +257,4 @@ class LatentMoELM(nn.Module):
             x = layer_cls(c, name=f"layer_{i}")(x, bias, key_bias, positions)
         x = RMSNorm(c.rms_eps, c.param_dtype, name="final_norm")(x)
         with scope("lm_head"):
-            return _dense(c, c.vocab_size, "lm_head", out_dtype=jnp.float32)(x)
+            return dense(c, c.vocab_size, "lm_head", out_dtype=jnp.float32)(x)

@@ -37,24 +37,31 @@ def _is_target(path: Tuple[str, ...], targets: Sequence[str]) -> bool:
 def init_lora(key: jax.Array, params, rank: int,
               targets: Sequence[str] = DEFAULT_TARGETS,
               head_modules: Sequence[str] = HEAD_MODULES,
-              dtype=None):
+              dtype=None, tied: Sequence[Tuple[str, str]] = ()):
     """Create the adapter tree: for each targeted kernel W (viewed 2D as
     [fan_in, fan_out]) an ``a`` [fan_in, rank] (gaussian/sqrt(rank)) and
     ``b`` [rank, fan_out] (zeros — adapters start as identity), in ``dtype``
     (None: the kernel's own; a family's policy, ``models.lora_policy``, may
     keep float32 adapters over a bfloat16 base). Leaves of
     ``head_modules`` are copied into the tree whole and substituted (not
-    low-rank-added) at merge time, so task heads fine-tune in full."""
+    low-rank-added) at merge time, so task heads fine-tune in full.
+    ``tied`` (``models.LoRAPolicy.tied``): ``(module, leaf path)`` pairs, an
+    adapter under ``module``'s name for the product with the TRANSPOSE of the
+    named leaf (a head whose kernel is the ``[vocab, hidden]`` embedding)."""
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     adapters = {}
+    tied_to = {leaf_path: module for module, leaf_path in tied}
     for path, leaf in flat:
         names = tuple(getattr(p, "key", getattr(p, "name", str(p))) for p in path)
-        if len(names) >= 2 and names[-2] in head_modules:
+        shape = leaf.shape
+        module = tied_to.get("/".join(names))
+        if module is not None:  # this leaf's transpose is ``module``'s kernel
+            names, shape = (module, "kernel"), shape[::-1]
+        elif len(names) >= 2 and names[-2] in head_modules:
             adapters["/".join(names)] = {"full": leaf}
             continue
-        if not _is_target(names, targets):
+        elif not _is_target(names, targets):
             continue
-        shape = leaf.shape
         if len(shape) == 2:
             fan_in, fan_out = shape
         elif len(shape) == 3:

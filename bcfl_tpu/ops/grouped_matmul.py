@@ -5,7 +5,7 @@ by group and ``group_sizes`` [G] says how many rows each group has. Rows past
 ``sum(group_sizes)`` belong to no group and come out zero: an expert layer
 that holds a share of the experts sorts the assignments to absent experts
 there, so nothing is computed for them and nothing is dropped
-(:mod:`bcfl_tpu.models.latent_moe`).
+(:mod:`bcfl_tpu.models.experts`).
 
 Two implementations behind one signature, through the kernel registry:
 
@@ -22,7 +22,7 @@ them. Neither implementation has a batching rule for weights that are NOT
 batched (``jax.vmap`` of ``lax.ragged_dot`` raises "ragged_dot vmap over any
 dim but 0 - NYI"; a Pallas call with scalar prefetch has none): the expert
 block that calls this folds the vmapped clients into the rows itself
-(``latent_moe.expert_block``), so this op only ever sees one row axis.
+(``models.experts.expert_block``), so this op only ever sees one row axis.
 """
 
 from __future__ import annotations
@@ -83,7 +83,7 @@ GROUPED_MATMUL = registry.register_op(registry.KernelOp(
     parity="allclose:2e-2 (float32 accumulation in another order; "
            "pinned in tests/test_latent_moe.py)",
     bench_shapes=(
-        # one chunk of the benchmark cell's folded step (latent_moe.CHUNK_SHARE)
+        # one chunk of the benchmark cell's folded step (models.experts.CHUNK_SHARE)
         {"label": "latent-moe-chunk-8192x4096x2048-16-experts", "M": 8192,
          "K": 4096, "N": 2048, "G": 16, "live": 3200},
     ),

@@ -58,6 +58,7 @@ import numpy as np  # noqa: E402
 import bcfl_tpu.ops.flash  # noqa: E402,F401
 import bcfl_tpu.ops.grouped_matmul  # noqa: E402,F401
 import bcfl_tpu.ops.pallas_codec  # noqa: E402,F401
+import bcfl_tpu.ops.ssm_scan  # noqa: E402,F401
 from bcfl_tpu.core.fence import fence  # noqa: E402
 from bcfl_tpu.core.hostenv import compile_cache  # noqa: E402
 from bcfl_tpu.ops import registry  # noqa: E402
@@ -97,6 +98,17 @@ def _build(op_name: str, row: dict):
         # ``live`` rows spread evenly over the groups; the rest belong to none
         sizes = jnp.full((G,), row["live"] // G, jnp.int32)
         return (lhs, rhs, sizes), {}
+    if op_name == "ssm_scan":
+        B, S, H, P, N = row["B"], row["S"], row["H"], row["P"], row["N"]
+        dtype = jnp.dtype(row.get("dtype", "float32"))
+        ks = [jax.random.fold_in(key, i) for i in range(6)]
+        x, Bm, Cm = (0.5 * jax.random.normal(k, shape, dtype) for k, shape in
+                     zip(ks, ((B, S, H, P), (B, S, N), (B, S, N))))
+        # Mamba-2's draw: dt log-uniform in [0.001, 0.1], A uniform in [-16, -1]
+        dt = jnp.exp(jax.random.uniform(ks[3], (B, S, H), jnp.float32,
+                                        math.log(1e-3), math.log(1e-1)))
+        A = -jax.random.uniform(ks[4], (H,), jnp.float32, 1.0, 16.0)
+        return (x, dt, A, Bm, Cm, jnp.ones((H,), jnp.float32)), {"chunk": row["chunk"]}
     raise SystemExit(f"no arg builder for op {op_name!r}; add one here")
 
 
@@ -224,8 +236,8 @@ def main() -> int:
                     help="timed iterations (default: 3 on TPU, 1 off-TPU "
                          "plumbing)")
     ap.add_argument("--backward", action="store_true",
-                    help="flash_attention: time jax.grad of a sum through "
-                         "the op, and each Pallas kernel alone")
+                    help="flash_attention, ssm_scan: time jax.grad of a sum "
+                         "through the op (flash: and each Pallas kernel alone)")
     ap.add_argument("--flash-blocks", default="",
                     help="flash_attention: 'bq,bk[;bq,bk...]', an entry "
                          "'f,f/k,k/q,q' names forward/dKV/dQ apart, "
@@ -244,6 +256,7 @@ def main() -> int:
     for name in names:
         op = registry.get_op(name)  # loud rejection of a typo'd --ops
         flash = name == "flash_attention"
+        scan = name == "ssm_scan"
         for shape in op.bench_shapes:
             call_args, kw = _build(name, shape)
             ref = None
@@ -280,6 +293,13 @@ def main() -> int:
                     jfn = jax.jit(jax.grad(
                         lambda q, k, v, b, _f=fn: _f(q, k, v, b, **kw).astype(
                             jnp.float32).sum(), argnums=(0, 1, 2)))
+                elif scan and args.backward:
+                    row["timed"] = "grad of a sum (forward + backward)"
+                    jfn = jax.jit(jax.grad(
+                        lambda *a, _f=fn: _f(*a, kw["chunk"]).astype(
+                            jnp.float32).sum(), argnums=(0, 1, 3, 4)))
+                elif scan:  # positional: custom_vjp functions take no keywords
+                    jfn = jax.jit(lambda *a, _f=fn: _f(*a, kw["chunk"]))
                 else:
                     jfn = jax.jit(lambda *a, _f=fn: _f(*a, **kw))
                 with _flash_blocks(blocks):
@@ -293,7 +313,7 @@ def main() -> int:
                             row["status"] = "parity_violation"
                             continue  # never time a wrong kernel
                     row["wall_ms"] = _time_ms(jfn, call_args, iters)
-                    if flash:
+                    if flash or scan:
                         row["device_ms"] = _device_ms(jfn, call_args, iters)
                     if flash and impl == "pallas":
                         row.update(_flash_row(call_args, kw["causal"],

@@ -20,6 +20,7 @@ from bcfl_tpu.fed.client_step import (make_local_train, make_loss_fn,
                                       make_optimizer, model_variables)
 from bcfl_tpu.models import (build, family_of, get_config, lora, lora_policy,
                              tp_param_specs)
+from bcfl_tpu.models import experts
 from bcfl_tpu.models import latent_moe as lm
 from bcfl_tpu.ops.grouped_matmul import grouped_matmul
 
@@ -296,16 +297,16 @@ def test_the_shares_add_up():
     uncut layer: nothing is dropped, nothing stands in for an absent chip."""
     cfg = get_config("tiny-latent-moe", dtype=jnp.float32)
     x = jax.random.normal(jax.random.key(0), (2, 12, cfg.hidden_size)) * 0.5
-    whole = lm.ExpertLayer(cfg)
+    whole = experts.ExpertLayer(cfg)
     valid = jnp.ones(x.shape[:2], bool)
     params = whole.init(jax.random.key(1), x, valid)["params"]
     full = whole.apply({"params": params}, x, valid)
-    shared = lm.SwiGLU(cfg, cfg.moe_intermediate_size).apply(
+    shared = experts.SwiGLU(cfg, cfg.moe_intermediate_size).apply(
         {"params": params["shared_experts"]}, x)
     total = shared
     for s in range(4):
         held = (2 * s, 2 * s + 1)
-        share = lm.ExpertLayer(get_config("tiny-latent-moe", dtype=jnp.float32, experts_held=held))
+        share = experts.ExpertLayer(get_config("tiny-latent-moe", dtype=jnp.float32, experts_held=held))
         p = dict(params, **{k: params[k][jnp.asarray(held)]
                             for k in ("experts_gate", "experts_up", "experts_down")})
         y, state = share.apply({"params": p}, x, valid, mutable=["counters"])
@@ -389,7 +390,7 @@ def test_yarn_frequencies_and_softmax_scale_by_hand():
 
 
 def test_adapters_on_the_activations_equal_the_merged_form():
-    dense = lm.LoRADense(24, jnp.float32, jnp.float32)
+    dense = experts.LoRADense(24, jnp.float32, jnp.float32)
     x = jax.random.normal(jax.random.key(4), (5, 16))
     w = dense.init(jax.random.key(5), x)["params"]
     a = jax.random.normal(jax.random.key(6), (16, 4)) * 0.3
@@ -436,10 +437,10 @@ def test_the_folded_grouped_product_equals_a_per_client_loop(impl, monkeypatch):
     """Under ``vmap`` with the expert weights NOT batched the clients fold
     into the rows (one grouped product over C x N x k rows), forward and
     backward, whichever implementation serves the product."""
-    monkeypatch.setattr(lm, "grouped_matmul",
+    monkeypatch.setattr(experts, "grouped_matmul",
                         lambda *a, **kw: grouped_matmul(*a, impl=impl, **kw))
     x, slot, cw, wg, wu, wd = _block_inputs()
-    folded = jax.vmap(lm.expert_block, in_axes=(0, 0, 0, None, None, None))
+    folded = jax.vmap(experts.expert_block, in_axes=(0, 0, 0, None, None, None))
     got = folded(x, slot, cw, wg, wu, wd)
     want = jnp.stack([_block_plain(x[c], slot[c], cw[c], wg, wu, wd) for c in range(3)])
     np.testing.assert_allclose(got, want, atol=2e-4)
@@ -450,14 +451,14 @@ def test_the_folded_grouped_product_equals_a_per_client_loop(impl, monkeypatch):
     np.testing.assert_allclose(gx, wx, atol=5e-4)
     np.testing.assert_allclose(gcw, wcw, atol=5e-4)
     # one row axis gives the same as the fold
-    np.testing.assert_allclose(lm.expert_block(x[1], slot[1], cw[1], wg, wu, wd), want[1], atol=2e-4)
+    np.testing.assert_allclose(experts.expert_block(x[1], slot[1], cw[1], wg, wu, wd), want[1], atol=2e-4)
 
 
 def test_batched_expert_weights_are_refused_under_vmap():
     x, slot, cw, wg, wu, wd = _block_inputs()
     stack = lambda w: jnp.broadcast_to(w, (3,) + w.shape)  # noqa: E731
     with pytest.raises(NotImplementedError, match="not batched"):
-        jax.vmap(lm.expert_block)(x, slot, cw, stack(wg), stack(wu), stack(wd))
+        jax.vmap(experts.expert_block)(x, slot, cw, stack(wg), stack(wu), stack(wd))
 
 
 @pytest.mark.parametrize("transpose_rhs", [False, True])
@@ -533,7 +534,7 @@ def test_the_lowered_step_has_no_weight_gradient_of_a_frozen_kernel(sizes, seede
     expected = (sum(req for _, req in by.values())
                 + attn_act * (S - (S + 1) / 2.0)
                 + by["routed experts"][1]
-                * (d["E"] / d["G"] / lm.CHUNK_SHARE * (8.0 / 6.0) - 1.0)) * tokens
+                * (d["E"] / d["G"] / experts.CHUNK_SHARE * (8.0 / 6.0) - 1.0)) * tokens
     got = sum(f[2] for f in found)
     assert abs(got - expected) / expected < 0.10, (got, expected)
     merged = sum(f for _, f, trained in flops.products(sizes, S) if not trained) * tokens
@@ -565,7 +566,7 @@ def test_the_required_operations_by_the_rule():
 def test_the_counters_sum_over_steps_layers_and_clients(sizes, seeded):
     model, adapters, frozen, _ = seeded
     loss_fn = make_loss_fn(model, "causal_lm")
-    assert loss_fn.counters == lm.COUNTERS == model.COUNTERS
+    assert loss_fn.counters == experts.COUNTERS == model.COUNTERS
     assert make_loss_fn(build("tiny-llama", head="lm"), "causal_lm").counters == ()
     lt = make_local_train(make_optimizer("adamw", 1e-3), loss_fn)
     C, T, B, S = 2, 3, 2, 16

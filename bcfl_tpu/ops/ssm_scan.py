@@ -27,8 +27,12 @@ products), then the chunks are taken last to first, each differentiated on
 its own with the gradient of the state it hands on. A plain ``jax.grad`` of
 the forward scan would keep every chunk's [H, chunk, chunk] decays instead.
 
-One implementation, ``jax.numpy`` under the kernel registry's name
-``ssm_scan``; a Pallas kernel is a later PR's (ROADMAP).
+Two implementations under the kernel registry's name ``ssm_scan``: this
+one in ``jax.numpy`` (the reference, the CPU path and the fallback) and the
+Pallas kernels of :mod:`bcfl_tpu.ops.pallas_ssm` (the same chunked form and
+rounding points, the state carried in VMEM across a row's chunks), chosen
+where one TPU chip is visible for the shapes they take
+(``pallas_ssm.supports``).
 """
 
 from __future__ import annotations
@@ -39,7 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from bcfl_tpu.ops import registry
+from bcfl_tpu.ops import pallas_ssm, registry
 
 DEFAULT_CHUNK = 256
 
@@ -165,21 +169,24 @@ ssm_scan_xla.defvjp(_fwd, _bwd)
 SSM_SCAN = registry.register_op(registry.KernelOp(
     name="ssm_scan",
     xla=ssm_scan_xla,
-    pallas=None,  # the chunked form in jax.numpy serves every backend
-    parity="no second implementation yet (pinned against a position-by-"
-           "position loop in tests/test_ssm_moe.py)",
+    pallas=pallas_ssm.ssm_scan_pallas,
+    parity="allclose (the same rounding points, float32 sums in another "
+           "order; both pinned against a position-by-position loop in "
+           "tests/test_ssm_moe.py)",
     bench_shapes=(
         # the state-space cell's folded step: 2 clients x 1 row of 4096,
         # 128 heads of 64, state 128, bfloat16 operands
         {"label": "ssm-moe-B2-S4096-H128-P64-N128", "B": 2, "S": 4096, "H": 128,
          "P": 64, "N": 128, "chunk": DEFAULT_CHUNK, "dtype": "bfloat16"},
     ),
+    supports=pallas_ssm.supports,
 ))
 
 
 def ssm_scan(x, dt, A, Bm, Cm, D, chunk: int = DEFAULT_CHUNK, impl: str = "auto"):
-    """Dispatch through the kernel registry (one implementation today: the
-    XLA one serves every request). A row whose length is no multiple of
+    """Dispatch through the kernel registry: the Pallas kernels where
+    ``auto`` selects them (one visible TPU chip) for the shapes they take,
+    the ``jax.numpy`` form elsewhere. A row whose length is no multiple of
     ``chunk`` is padded inside the op."""
-    fn, _ = registry.select("ssm_scan", impl, x, dt, A, Bm, Cm, D)
+    fn, _ = registry.select("ssm_scan", impl, x, dt, A, Bm, Cm, D, chunk=chunk)
     return fn(x, dt, A, Bm, Cm, D, chunk)

@@ -31,9 +31,19 @@ the blocks it asked for, what the clamp made of them and the grids' step
 counts. This is the sweep ``DEFAULT_BLOCKS`` was chosen by (PERF.md
 section 7).
 
+The ``ssm_scan`` rows take ``x`` as the model hands it over, ``[B, S, H P]``,
+made ``[B, S, H, P]`` inside the timed program, so neither impl pays for a
+relayout of a parameter the model never has. ``--scan-heads "16;32;default"``
+adds one Pallas row for each number of heads a grid step takes
+(``pallas_ssm.HEAD_BLOCK``); with ``--backward`` a row's ``device_ms`` is
+``jax.grad`` of a sum (the backward pass, whose states pass stands in for the
+forward: a sum's gradient needs no forward result) and
+``forward_device_ms`` the forward pass alone, both by the device's clock; a
+round of the cell runs both.
+
 Usage: python scripts/kernel_bench.py [--out results/kernel_bench.json]
        [--ops int8_quantize,topk_select] [--iters N] [--backward]
-       [--flash-blocks "bq,bk[;bq,bk...]"]
+       [--flash-blocks "bq,bk[;bq,bk...]"] [--scan-heads "h[;h...]"]
 """
 
 from __future__ import annotations
@@ -102,8 +112,11 @@ def _build(op_name: str, row: dict):
         B, S, H, P, N = row["B"], row["S"], row["H"], row["P"], row["N"]
         dtype = jnp.dtype(row.get("dtype", "float32"))
         ks = [jax.random.fold_in(key, i) for i in range(6)]
+        # x as the model hands it over: [B, S, H P], made [B, S, H, P] inside
+        # the timed program (``_scan_fn``), so neither impl pays a relayout
+        # of a parameter the model never has
         x, Bm, Cm = (0.5 * jax.random.normal(k, shape, dtype) for k, shape in
-                     zip(ks, ((B, S, H, P), (B, S, N), (B, S, N))))
+                     zip(ks, ((B, S, H * P), (B, S, N), (B, S, N))))
         # Mamba-2's draw: dt log-uniform in [0.001, 0.1], A uniform in [-16, -1]
         dt = jnp.exp(jax.random.uniform(ks[3], (B, S, H), jnp.float32,
                                         math.log(1e-3), math.log(1e-1)))
@@ -181,6 +194,41 @@ def _flash_variants(spec: str):
     return out
 
 
+def _scan_variants(spec: str):
+    """``--scan-heads`` -> ``[(label, heads or None)]``; None is
+    ``pallas_ssm.HEAD_BLOCK`` as the module has it."""
+    return [(e, None if e == "default" else int(e))
+            for e in (e.strip() for e in (spec or "default").split(";"))]
+
+
+@contextlib.contextmanager
+def _scan_heads(heads):
+    """Run with ``pallas_ssm.HEAD_BLOCK`` set to ``heads`` (None: as the
+    module has it): the kernels read it when they are traced."""
+    from bcfl_tpu.ops import pallas_ssm as ps
+
+    kept = ps.HEAD_BLOCK
+    ps.HEAD_BLOCK = heads or kept
+    try:
+        yield
+    finally:
+        ps.HEAD_BLOCK = kept
+
+
+def _scan_fn(fn, shape: dict, chunk: int, backward: bool):
+    """The timed scan program: ``x`` [B, S, H P] reshaped to heads inside;
+    with ``backward``, ``jax.grad`` of a sum (forward and backward)."""
+    heads = (shape["B"], shape["S"], shape["H"], shape["P"])
+
+    def scan(x, *rest):  # positional: custom_vjp functions take no keywords
+        return fn(x.reshape(heads), *rest, chunk)
+
+    if backward:
+        return jax.jit(jax.grad(lambda *a: scan(*a).astype(jnp.float32).sum(),
+                                argnums=(0, 1, 3, 4)))
+    return jax.jit(scan)
+
+
 @contextlib.contextmanager
 def _flash_blocks(blocks):
     """Run with ``pallas_flash.DEFAULT_BLOCKS`` set to ``blocks`` (None:
@@ -242,6 +290,9 @@ def main() -> int:
                     help="flash_attention: 'bq,bk[;bq,bk...]', an entry "
                          "'f,f/k,k/q,q' names forward/dKV/dQ apart, "
                          "'default' is pallas_flash.DEFAULT_BLOCKS")
+    ap.add_argument("--scan-heads", default="",
+                    help="ssm_scan: 'h[;h...]' heads a grid step takes, "
+                         "'default' is pallas_ssm.HEAD_BLOCK")
     args = ap.parse_args()
 
     compile_cache()
@@ -260,12 +311,19 @@ def main() -> int:
         for shape in op.bench_shapes:
             call_args, kw = _build(name, shape)
             ref = None
-            # one Pallas row, or one for each --flash-blocks entry
+            # one Pallas row, or one for each --flash-blocks or
+            # --scan-heads entry
             pallas_rows = (_flash_variants(args.flash_blocks) if flash
+                           else _scan_variants(args.scan_heads) if scan
                            else [(None, None)])
             for impl, (label, blocks) in [("xla", (None, None))] + [
                     ("pallas", v) for v in pallas_rows]:
-                fn, resolved = registry.select(name, impl, *call_args, **kw)
+                sel_args = call_args
+                if scan:  # the op's own view: x [B, S, H, P]
+                    sel_args = (jax.ShapeDtypeStruct(
+                        (shape["B"], shape["S"], shape["H"], shape["P"]),
+                        call_args[0].dtype),) + call_args[1:]
+                fn, resolved = registry.select(name, impl, *sel_args, **kw)
                 row = {
                     "op": name,
                     "label": shape["label"],
@@ -277,7 +335,7 @@ def main() -> int:
                     "plumbing_only": plumbing,
                 }
                 if label:
-                    row["flash_blocks"] = label
+                    row["scan_heads" if scan else "flash_blocks"] = label
                 rows.append(row)
                 if impl == "pallas" and not op.has_pallas:
                     row["status"] = "no_pallas_impl"
@@ -293,16 +351,14 @@ def main() -> int:
                     jfn = jax.jit(jax.grad(
                         lambda q, k, v, b, _f=fn: _f(q, k, v, b, **kw).astype(
                             jnp.float32).sum(), argnums=(0, 1, 2)))
-                elif scan and args.backward:
-                    row["timed"] = "grad of a sum (forward + backward)"
-                    jfn = jax.jit(jax.grad(
-                        lambda *a, _f=fn: _f(*a, kw["chunk"]).astype(
-                            jnp.float32).sum(), argnums=(0, 1, 3, 4)))
-                elif scan:  # positional: custom_vjp functions take no keywords
-                    jfn = jax.jit(lambda *a, _f=fn: _f(*a, kw["chunk"]))
+                elif scan:
+                    if args.backward:
+                        row["timed"] = "grad of a sum (forward + backward)"
+                    jfn = _scan_fn(fn, shape, kw["chunk"], args.backward)
                 else:
                     jfn = jax.jit(lambda *a, _f=fn: _f(*a, **kw))
-                with _flash_blocks(blocks):
+                with _flash_blocks(None if scan else blocks), \
+                        _scan_heads(blocks if scan else None):
                     out = jfn(*call_args)
                     fence(out)
                     if impl == "xla":
@@ -315,6 +371,9 @@ def main() -> int:
                     row["wall_ms"] = _time_ms(jfn, call_args, iters)
                     if flash or scan:
                         row["device_ms"] = _device_ms(jfn, call_args, iters)
+                    if scan and args.backward:
+                        fwd = _scan_fn(fn, shape, kw["chunk"], False)
+                        row["forward_device_ms"] = _device_ms(fwd, call_args, iters)
                     if flash and impl == "pallas":
                         row.update(_flash_row(call_args, kw["causal"],
                                               args.backward, iters))

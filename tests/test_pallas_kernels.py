@@ -391,3 +391,41 @@ def test_kernel_bench_flash_rows_run_what_the_shape_declares():
     assert q.dtype == k.dtype == v.dtype == jnp.bfloat16 and kw == {"causal": True}
     assert bias.shape == (3, 64) and bias.dtype == jnp.float32
     assert [int((row == 0).sum()) for row in bias] == [64, 56, 48]  # padded keys
+
+
+def test_kernel_bench_scan_rows_run_what_the_model_hands_over():
+    """scripts/kernel_bench.py: ``--scan-heads`` entries set the kernels'
+    heads a grid step for a row and put the module's back; a scan row's
+    ``x`` is ``[B, S, H P]``, as the model hands it over, and its timed
+    program (forward, or forward and backward) runs the op on it."""
+    import importlib.util
+    import pathlib
+
+    from bcfl_tpu.ops import pallas_ssm, registry
+    from bcfl_tpu.ops.ssm_scan import SSM_SCAN
+
+    path = pathlib.Path(__file__).resolve().parents[1] / "scripts" / "kernel_bench.py"
+    spec = importlib.util.spec_from_file_location("kernel_bench", path)
+    kb = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kb)
+
+    assert kb._scan_variants("") == [("default", None)]
+    assert kb._scan_variants("16; 32;default") == [("16", 16), ("32", 32), ("default", None)]
+    kept = pallas_ssm.HEAD_BLOCK
+    with kb._scan_heads(8):
+        assert pallas_ssm.HEAD_BLOCK == 8
+    with kb._scan_heads(None):
+        assert pallas_ssm.HEAD_BLOCK == kept
+    assert pallas_ssm.HEAD_BLOCK == kept
+
+    (cell,) = SSM_SCAN.bench_shapes
+    tiny = dict(cell, B=2, S=32, H=4, P=8, N=16, chunk=32)
+    args, kw = kb._build("ssm_scan", tiny)
+    assert args[0].shape == (2, 32, 32) and args[0].dtype == jnp.bfloat16 and kw == {"chunk": 32}
+    fn, impl = registry.select("ssm_scan", "pallas", jax.ShapeDtypeStruct((2, 32, 4, 8), jnp.bfloat16),
+                               *args[1:], chunk=32)
+    assert impl == "pallas"
+    y = kb._scan_fn(fn, tiny, 32, False)(*args)
+    assert y.shape == (2, 32, 4, 8)
+    grads = kb._scan_fn(fn, tiny, 32, True)(*args)
+    assert grads[0].shape == args[0].shape and bool(jnp.isfinite(grads[0].astype(jnp.float32)).all())

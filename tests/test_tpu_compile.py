@@ -1,4 +1,4 @@
-"""The flash kernels, the state-space scan, and the expert and state-space
+"""The flash kernels, the state-space scan in both its forms, and the expert and state-space
 decoders' round programs at one layer,
 compiled for a DESCRIBED TPU v5e, with no chip attached (the TPU's compiler
 is installed here): what interpret mode cannot show, above all whether a
@@ -125,26 +125,41 @@ def test_the_expert_decoders_round_program_runs_no_forward_kernel_twice(
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 4e9  # 1.19 + 2.40 GB at one layer
 
 
-def test_the_state_space_scan_compiles_forward_and_backward(one_chip, for_the_chip):
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_the_state_space_scan_compiles_forward_and_backward(one_chip, for_the_chip, impl):
     """``ops/ssm_scan.py`` at the state-space cell's shape (2 clients x 1 row
-    of 4096, 128 heads of 64, state 128, chunks of 256, bfloat16 operands):
-    the chunked form and its ``custom_vjp`` backward pass, for the described
-    chip; what is held beyond the arguments and results stays under 2 GB (a
-    chunk's [2, 128, 256, 256] decays, not a row's)."""
+    of 4096, 128 heads of 64, state 128, chunks of 256, bfloat16 operands),
+    forward and backward, for the described chip; what is held beyond the
+    arguments and results stays under 2 GB (a chunk's [2, 128, 256, 256]
+    decays, not a row's). In ``jax.numpy``: one loop forward, the backward
+    pass's two (the states carried forward again, the chunks last to
+    first). As the Pallas kernels (``ops/pallas_ssm.py``): no loop, the
+    forward kernel once and the backward pass's two (the states that enter
+    the chunks, the chunks last to first), each within the VMEM it asks
+    for, which the compiler checks."""
+    from bcfl_tpu.ops import pallas_ssm
     from bcfl_tpu.ops.ssm_scan import ssm_scan
 
     B, S, H, P, N = 2, 4096, 128, 64, 128
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
     args = (sds((B, S, H, P), jnp.bfloat16), sds((B, S, H), jnp.float32), sds((H,), jnp.float32),
             sds((B, S, N), jnp.bfloat16), sds((B, S, N), jnp.bfloat16), sds((H,), jnp.float32))
-    fwd = jax.jit(lambda *a: ssm_scan(*a, chunk=256)).lower(*args).compile()
+    assert registry.select("ssm_scan", impl, *args, chunk=256)[1] == impl
+    scan = lambda *a: ssm_scan(*a, chunk=256, impl=impl)  # noqa: E731
+    fwd = jax.jit(scan).lower(*args).compile()
     assert fwd.memory_analysis().temp_size_in_bytes < 1e9
-    grad = jax.jit(jax.grad(lambda *a: ssm_scan(*a, chunk=256).astype(jnp.float32).sum(),
+    # a loss that reads y, so that the forward pass runs beside the backward
+    grad = jax.jit(jax.grad(lambda *a: jnp.square(scan(*a).astype(jnp.float32)).sum(),
                             (0, 1, 3, 4))).lower(*args).compile()
     assert grad.memory_analysis().temp_size_in_bytes < 2e9
-    # the backward pass's two loops (the states carried forward again, the
-    # chunks last to first); a sum's gradient needs no forward result
-    assert fwd.as_text().count(" while(") == 1 and grad.as_text().count(" while(") == 2
+    if impl == "xla":
+        assert fwd.as_text().count(" while(") == 1 and grad.as_text().count(" while(") == 3
+        return
+    assert fwd.as_text().count("tpu_custom_call") == 1 and " while(" not in fwd.as_text()
+    assert grad.as_text().count("tpu_custom_call") == 3 and " while(" not in grad.as_text()
+    hb, W = pallas_ssm._geometry(H, P)
+    for kernel in ("fwd", "bwd"):
+        assert pallas_ssm._vmem_bytes(kernel, 256, hb, P, N, H, W, 2) <= pallas_ssm.VMEM_MAX_BYTES
 
 
 def test_one_mamba_layers_round_program_at_published_widths(one_chip, for_the_chip, monkeypatch):
@@ -153,7 +168,9 @@ def test_one_mamba_layers_round_program_at_published_widths(one_chip, for_the_ch
     the cell's rows, ``remat``, LoRA r16 with the tied head's adapter,
     ``donate``): it compiles for the described chip, its kernels are the
     grouped products' three forward and five backward calls (no forward
-    kernel again), and the compiler rematerialised nothing on its own."""
+    kernel again) and the state-space scan's forward kernel and its backward
+    pass's two (none of the forward's again), and the compiler rematerialised
+    nothing on its own."""
     from bcfl_tpu.core.mesh import client_mesh
     from bcfl_tpu.fed.client_step import build_programs
     from bcfl_tpu.models import build, lora, lora_policy
@@ -187,7 +204,7 @@ def test_one_mamba_layers_round_program_at_published_widths(one_chip, for_the_ch
         on_chip(adapters), on_chip(params), batches, per_round, sds((K, C, 2), jnp.uint32),
         per_round).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3 + 5
+    assert text.count("tpu_custom_call") == 3 + 5 + 1 + 2
     assert ".remat" not in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6.5e9

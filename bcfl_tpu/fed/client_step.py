@@ -597,7 +597,13 @@ def _build_programs(
         1.0 iff every fingerprint lane survived transport unchanged."""
         fp_commit = _c(client_fingerprint(new_t), cl)
         sent_t = _transport(new_t, c_row)
-        fp_recv = _c(client_fingerprint(sent_t), cl)
+        # the fingerprint is linear: what arrived is fingerprinted as the
+        # commit plus what transport changed, which is exact where transport
+        # changed nothing, whatever order a backend adds the products in
+        # (XLA:CPU contracts them into FMAs differently in two fusions of one
+        # tree, and two whole fingerprints then differ in their last bits)
+        fp_recv = _c(fp_commit + client_fingerprint(
+            jax.tree.map(jnp.subtract, sent_t, new_t)), cl)
         auth = jnp.all(fp_recv == fp_commit, axis=-1).astype(jnp.float32)
         return sent_t, fp_commit, fp_recv, _c(auth, cl)
 

@@ -7,12 +7,15 @@ The reference's model space is three HF checkpoints (SURVEY.md §2.1):
 the scale-down smoke models (the reference's de-facto test method is a
 NUM_CLIENTS=2/NUM_ROUNDS=2 scale-down of the same script — SURVEY.md §4).
 
-Four families: ``encoder`` (:mod:`bcfl_tpu.models.bert`), ``llama``
+Five families: ``encoder`` (:mod:`bcfl_tpu.models.bert`), ``llama``
 (:mod:`bcfl_tpu.models.llama`), ``latent_moe``
 (:mod:`bcfl_tpu.models.latent_moe`: latent attention, an expert layer that
-holds a share of the experts) and ``ssm_moe``
+holds a share of the experts), ``ssm_moe``
 (:mod:`bcfl_tpu.models.ssm_moe`: state-space and attention layers in a
-pattern, the same expert layer, a tied head). :func:`family_of` names a registry name's or a
+pattern, the same expert layer, a tied head) and ``delta_moe``
+(:mod:`bcfl_tpu.models.delta_moe`: Gated DeltaNet and gated attention
+layers in a pattern, the same expert layer with a gated shared expert, an
+untied head). :func:`family_of` names a registry name's or a
 built model's family; :func:`build`, :func:`lora_policy` and
 :func:`tp_param_specs` dispatch on it and refuse what a family does not
 support with the names that exist.
@@ -134,6 +137,33 @@ def _ssm_moe_configs():
     return _SSM_MOE_CONFIGS
 
 
+_DELTA_MOE_CONFIGS: Dict[str, "DeltaMoEConfig"] = {}
+
+
+def _delta_moe_configs():
+    global _DELTA_MOE_CONFIGS
+    if not _DELTA_MOE_CONFIGS:
+        from bcfl_tpu.models.delta_moe import FULL, LINEAR, DeltaMoEConfig
+
+        _DELTA_MOE_CONFIGS = {
+            # test scale-down: 3 Gated DeltaNet layers and 1 attention layer;
+            # 2 key heads of 8 for 4 value heads of 8 in chunks of 8; 4 query
+            # heads of 16 over 2 key-value heads, rotary on 4 of 16; 8
+            # experts of width 16 with 3 a token, a gated shared expert of 16
+            "tiny-delta-moe": DeltaMoEConfig(
+                vocab_size=512, hidden_size=32, num_layers=4,
+                layer_types=(LINEAR, LINEAR, LINEAR, FULL), num_heads=4, num_kv_heads=2,
+                head_dim=16, linear_num_key_heads=2, linear_num_value_heads=4,
+                linear_key_head_dim=8, linear_value_head_dim=8, gdn_chunk_size=8,
+                num_experts=8, num_experts_per_tok=3, moe_intermediate_size=16,
+                shared_expert_intermediate_size=16),
+            # Qwen/Qwen3-Next-80B-A3B-Instruct as published (the dataclass's
+            # defaults): 48 layers, every expert
+            "qwen3-next-80b-a3b": DeltaMoEConfig(),
+        }
+    return _DELTA_MOE_CONFIGS
+
+
 def _split_cut(name: str):
     """``"base@k=v,..."`` -> ``(base, {config field: value})``."""
     base, _, cut = name.partition("@")
@@ -147,10 +177,13 @@ def _split_cut(name: str):
     return base, out
 
 
-FAMILIES = ("encoder", "llama", "latent_moe", "ssm_moe")
+FAMILIES = ("encoder", "llama", "latent_moe", "ssm_moe", "delta_moe")
 # the families with an LM head only, adapters on the activations and an
-# expert layer that holds a share (one set of refusals, one LoRA policy)
-_EXPERT_FAMILIES = ("latent_moe", "ssm_moe")
+# expert layer that holds a share (one set of refusals)
+_EXPERT_FAMILIES = ("latent_moe", "ssm_moe", "delta_moe")
+# a family whose names may carry a cut: its configurations
+_CUT_CONFIGS = {"latent_moe": _latent_moe_configs, "ssm_moe": _ssm_moe_configs,
+                "delta_moe": _delta_moe_configs}
 
 
 def family_of(model) -> str:
@@ -161,10 +194,9 @@ def family_of(model) -> str:
             return "encoder"
         if model in _llama_configs():
             return "llama"
-        if base in _latent_moe_configs():
-            return "latent_moe"
-        if base in _ssm_moe_configs():
-            return "ssm_moe"
+        for family, configs in _CUT_CONFIGS.items():
+            if base in configs():
+                return family
         raise KeyError(f"unknown model {model!r}; have {list_models()}")
     name = type(model).__name__
     if isinstance(model, TextClassifier):
@@ -175,6 +207,8 @@ def family_of(model) -> str:
         return "latent_moe"
     if name == "SSMMoELM":
         return "ssm_moe"
+    if name == "DeltaMoELM":
+        return "delta_moe"
     raise TypeError(f"{name} is a model of no family of the registry {FAMILIES}")
 
 
@@ -187,13 +221,12 @@ def get_config(name: str, **overrides):
     if family == "llama":
         return dataclasses.replace(_llama_configs()[name], **overrides)
     base, cut = _split_cut(name)
-    configs = _latent_moe_configs() if family == "latent_moe" else _ssm_moe_configs()
-    return dataclasses.replace(configs[base], **{**cut, **overrides})
+    return dataclasses.replace(_CUT_CONFIGS[family]()[base], **{**cut, **overrides})
 
 
 def list_models():
     return (sorted(_CONFIGS) + sorted(_llama_configs())
-            + sorted(_latent_moe_configs()) + sorted(_ssm_moe_configs()))
+            + [n for configs in _CUT_CONFIGS.values() for n in sorted(configs())])
 
 
 def build(name: str, head: str = "classifier", **overrides):
@@ -201,8 +234,8 @@ def build(name: str, head: str = "classifier", **overrides):
     ``apply(vars, ids, mask, deterministic=...) -> logits``. ``head="lm"``
     builds the causal-LM variant ([B, S, vocab] logits): the decoders only
     (encoders are bidirectional, so next-token training would leak the
-    target), and the only head of the ``latent_moe`` and ``ssm_moe``
-    families."""
+    target), and the only head of the ``latent_moe``, ``ssm_moe`` and
+    ``delta_moe`` families."""
     cfg = get_config(name, **overrides)
     family = family_of(name)
     if head not in ("classifier", "lm"):
@@ -211,7 +244,7 @@ def build(name: str, head: str = "classifier", **overrides):
         if head == "lm":
             raise ValueError(
                 f"model {name!r} is an encoder: causal-LM training needs a "
-                "decoder (the llama, latent_moe and ssm_moe families)")
+                "decoder (the llama, latent_moe, ssm_moe and delta_moe families)")
         return TextClassifier(cfg)
     if family == "llama":
         from bcfl_tpu.models.llama import LlamaClassifier, LlamaLM
@@ -226,9 +259,13 @@ def build(name: str, head: str = "classifier", **overrides):
         from bcfl_tpu.models.latent_moe import LatentMoELM
 
         return LatentMoELM(cfg)
-    from bcfl_tpu.models.ssm_moe import SSMMoELM
+    if family == "ssm_moe":
+        from bcfl_tpu.models.ssm_moe import SSMMoELM
 
-    return SSMMoELM(cfg)
+        return SSMMoELM(cfg)
+    from bcfl_tpu.models.delta_moe import DeltaMoELM
+
+    return DeltaMoELM(cfg)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -270,11 +307,18 @@ def lora_policy(model) -> LoRAPolicy:
 
         return LoRAPolicy(targets=LORA_TARGETS, head_modules=(),
                           adapter_dtype="float32", on_activations=True)
-    from bcfl_tpu.models.ssm_moe import LORA_TARGETS
+    if family == "ssm_moe":
+        from bcfl_tpu.models.ssm_moe import LORA_TARGETS
 
-    return LoRAPolicy(targets=LORA_TARGETS, head_modules=(),
-                      adapter_dtype="float32", on_activations=True,
-                      tied=(("lm_head", "embed/embedding"),))
+        return LoRAPolicy(targets=LORA_TARGETS, head_modules=(),
+                          adapter_dtype="float32", on_activations=True,
+                          tied=(("lm_head", "embed/embedding"),))
+    if family == "delta_moe":
+        from bcfl_tpu.models.delta_moe import LORA_TARGETS
+
+        return LoRAPolicy(targets=LORA_TARGETS, head_modules=(),
+                          adapter_dtype="float32", on_activations=True)
+    raise KeyError(f"no LoRA policy for the {family} family; the families are {FAMILIES}")
 
 
 def lora_targets(name: str):
@@ -303,12 +347,12 @@ def refusals(model: str, *, task: str, lora_rank: int, tp: int, sp: int,
                    "pass; train adapters (lora_rank > 0)")
     if tp > 1:
         why.append(f"tp={tp}: no tensor-parallel layout for latent attention, "
-                   "for a state-space mixer or for the expert layer (an "
-                   "expert axis in core/mesh.py is not there yet)")
+                   "for a state-space or Gated DeltaNet mixer or for the expert "
+                   "layer (an expert axis in core/mesh.py is not there yet)")
     if sp > 1:
         why.append(f"sp={sp}: ring attention is not wired into latent "
                    "attention, and no state-space scan carries its state "
-                   "from chip to chip")
+                   "from chip to chip (nor does the gated delta rule)")
     if client_lora_ranks is not None and len(set(client_lora_ranks)) > 1:
         why.append("heterogeneous client_lora_ranks: rank clipping acts on "
                    "merged adapters, and this family's adapters enter on the "

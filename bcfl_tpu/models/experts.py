@@ -1,10 +1,13 @@
 """The expert layer that holds a share of the experts, for every family with
-one (:mod:`bcfl_tpu.models.latent_moe`, :mod:`bcfl_tpu.models.ssm_moe`), and
+one (:mod:`bcfl_tpu.models.latent_moe`, :mod:`bcfl_tpu.models.ssm_moe`,
+:mod:`bcfl_tpu.models.delta_moe`), and
 the dense product that carries a LoRA adapter on the activations.
 
 - **Expert layer** (:class:`ExpertLayer`): a float32 softmax router over ALL
   ``n_routed_experts``, the top ``num_experts_per_tok`` renormalised, plus an
-  MLP every position passes (``shared``). The layer is told which experts it
+  MLP every position passes (``shared``), times ``sigmoid(x w)`` of a frozen
+  ``shared_expert_gate`` [H, 1] where the config's ``shared_expert_gate`` is
+  true. The layer is told which experts it
   HOLDS (the config's ``held``) and computes their part of the result; what
   the absent experts would add is left out, and that partial result goes on
   (no code stands in for the absent chips or their exchange). No token is
@@ -372,4 +375,10 @@ class ExpertLayer(nn.Module):
                          wu.astype(c.dtype), wd.astype(c.dtype))
         with scope("moe.shared"):
             shared = self.shared(c, c.n_shared_experts * F, name="shared_experts")(x)
+            if getattr(c, "shared_expert_gate", False):
+                # times a sigmoid of a product of its own; both factors are
+                # kept for the backward pass (``shared_out``, ``shared_gate_logit``)
+                logit = checkpoint_name(dense(c, 1, "shared_expert_gate")(x), "shared_gate_logit")
+                shared = (checkpoint_name(shared, "shared_out").astype(jnp.float32)
+                          * jax.nn.sigmoid(logit.astype(jnp.float32))).astype(c.dtype)
         return shared + y.reshape(B, S, Hd)

@@ -184,14 +184,14 @@ def _a_log_init(key, shape, dtype):
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0)).astype(dtype)
 
 
-def _dt_bias_init(key, shape, dtype):
+def dt_bias_init(key, shape, dtype):
     """Mamba-2's draw: ``dt`` log-uniform in [0.001, 0.1], stored as the
     value whose softplus it is."""
     dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, math.log(1e-3), math.log(1e-1)))
     return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
 
 
-def _conv_init(d_conv):
+def conv_init(d_conv):
     """torch's Conv1d default, which Mamba-2 leaves: uniform in
     +-1/sqrt(d_conv) for a depthwise kernel [d_conv, channels] and its bias."""
     bound = d_conv ** -0.5
@@ -200,6 +200,19 @@ def _conv_init(d_conv):
         return jax.random.uniform(key, shape, jnp.float32, -bound, bound).astype(dtype)
 
     return init
+
+
+def causal_conv(x, kernel, bias=None):
+    """The causal depthwise convolution of a mixer, float32: ``x`` [B, S, C],
+    ``kernel`` [K, C] (``kernel[k]`` weighs the position ``K - 1 - k``
+    back), ``bias`` [C] or None. Positions before a row's first read 0."""
+    K, S = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    kf = kernel.astype(jnp.float32)
+    taps = (kf[k] * padded[:, k:k + S] for k in range(K))
+    if bias is None:
+        return sum(taps)
+    return bias.astype(jnp.float32) + sum(taps)
 
 
 class FusedSwiGLU(nn.Module):
@@ -228,18 +241,13 @@ class MambaMixer(nn.Module):
         zxbcdt = checkpoint_name(
             dense(c, d_in + conv_dim + H, "in_proj")(u), "ssm_in_proj")
         z, xbc, dt = jnp.split(zxbcdt, (d_in, d_in + conv_dim), axis=-1)
-        # kernel[k] weighs the position K - 1 - k back
-        kernel = self.param("conv_kernel", _conv_init(K), (K, conv_dim), c.param_dtype)
-        bias = self.param("conv_bias", _conv_init(K), (conv_dim,), c.param_dtype)
+        kernel = self.param("conv_kernel", conv_init(K), (K, conv_dim), c.param_dtype)
+        bias = self.param("conv_bias", conv_init(K), (conv_dim,), c.param_dtype)
         a_log = self.param("A_log", _a_log_init, (H,), c.param_dtype)
         d_skip = self.param("D", nn.initializers.ones, (H,), c.param_dtype)
-        dt_bias = self.param("dt_bias", _dt_bias_init, (H,), c.param_dtype)
+        dt_bias = self.param("dt_bias", dt_bias_init, (H,), c.param_dtype)
         with scope("ssm.conv"):
-            padded = jnp.pad(xbc.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
-            kf = kernel.astype(jnp.float32)
-            acc = bias.astype(jnp.float32) + sum(
-                kf[k] * padded[:, k:k + S] for k in range(K))
-            xbc = nn.silu(acc).astype(c.dtype)
+            xbc = nn.silu(causal_conv(xbc, kernel, bias)).astype(c.dtype)
         x = xbc[..., :d_in].reshape(B, S, H, P)
         dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
         with scope("ssm.scan"):

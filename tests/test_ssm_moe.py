@@ -697,7 +697,8 @@ def test_the_published_model_by_eval_shape():
 # ------------------------------------------------------- registry and refusals
 
 def test_four_families_by_name_and_by_model():
-    assert FAMILIES == ("encoder", "llama", "latent_moe", "ssm_moe")
+    # four of the registry's families (a later one follows them)
+    assert FAMILIES[:4] == ("encoder", "llama", "latent_moe", "ssm_moe")
     assert family_of("tiny-bert") == "encoder" and family_of("tiny-llama") == "llama"
     assert family_of("mistral-small-4@layers=8,experts_held=16") == "latent_moe"
     assert family_of("granite-4.0-h-small@layers=10,experts_held=18") == "ssm_moe"

@@ -208,3 +208,50 @@ def test_one_mamba_layers_round_program_at_published_widths(one_chip, for_the_ch
     assert ".remat" not in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 6.5e9
+
+
+def test_a_gated_deltanet_and_a_gated_attention_layers_round_program(one_chip, for_the_chip, monkeypatch):
+    """The fused round program of the Gated DeltaNet cell at TWO layers (one
+    Gated DeltaNet mixer with its chunked rule in ``jax.numpy``, one gated
+    attention layer with heads of 256 through the flash kernels, each with
+    the expert layer at 64 of 512 held; published widths, the cell's rows of
+    8192, ``remat``, LoRA r16, ``donate``): it compiles for the described
+    chip, its kernels are the flash kernels' three and the grouped products'
+    three forward and five backward calls a layer (no forward kernel again),
+    and the compiler rematerialised nothing on its own."""
+    from bcfl_tpu.core.mesh import client_mesh
+    from bcfl_tpu.fed.client_step import build_programs
+    from bcfl_tpu.models import build, lora, lora_policy
+    from bcfl_tpu.models.delta_moe import FULL, LINEAR
+
+    monkeypatch.setattr(registry, "pallas_by_default", lambda: True)
+    monkeypatch.setattr(registry, "on_tpu", lambda: True)
+    C, T, B, S, K = 2, 4, 1, 8192, 2
+    model = build("qwen3-next-80b-a3b@layers=2,experts_held=64", head="lm", vocab_size=18992,
+                  layer_types=(LINEAR, FULL), dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                  remat=True, use_flash=True, flash_min_seq=0)
+    ids = jnp.ones((2, S), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.key(0), ids, ids)["params"]
+    pol = lora_policy(model)
+    adapters = jax.eval_shape(lambda p: lora.init_lora(
+        jax.random.key(1), p, 16, targets=pol.targets, head_modules=pol.head_modules,
+        dtype=pol.adapter_dtype, tied=pol.tied), params)
+    assert len(adapters) == 5 + 7 + 1
+    progs = build_programs(model, client_mesh(C, devices=list(one_chip.device_set)), optimizer="adamw",
+                           learning_rate=1e-4, task="causal_lm", donate=True)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)  # noqa: E731
+    batches = {"ids": sds((C, T, B, S), jnp.int32), "mask": sds((C, T, B, S), jnp.int32),
+               "example_mask": sds((C, T, B), jnp.float32)}
+    per_round = sds((K, C), jnp.float32)
+    compiled = progs.server_rounds_static_fp.lower(
+        on_chip(adapters), on_chip(params), batches, per_round, sds((K, C, 2), jnp.uint32),
+        per_round).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 + 2 * (3 + 5)
+    assert ".remat" not in text
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 9e9

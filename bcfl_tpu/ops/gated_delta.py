@@ -25,6 +25,14 @@ running sum of ``g`` and ``Gam_ij = exp(G_i - G_j)`` for ``j <= i``:
 - ``O = (exp(G) Q) S + ((Q K^T) * Gam)_causal V'``;
 - the state handed on is ``exp(G_last) S + (exp(G_last - G) K)^T V'``.
 
+Only ``V'``, the first term of ``O`` and the state handed on read the state,
+so the op runs in three parts (the chunk-parallel split of
+flash-linear-attention's ``chunk_gated_delta_rule``): everything else, the
+``chunk x chunk`` solve among it, once for every chunk of every row in one
+go; a loop over the chunks that carries the state and computes ``V'``,
+``(exp(G) Q) S`` and the state handed on alone; and the intra-chunk
+product, again for every chunk at once, after the loop.
+
 Decays are ``exp`` of a DIFFERENCE of running sums under the causal mask,
 never a quotient of two exponentials. Decays, ``A``, ``T`` and the state are
 float32; the products take their operands in ``v``'s type (``T`` and the
@@ -32,11 +40,14 @@ state rounded to it as operands) and add in float32.
 
 The backward pass (``custom_vjp``) is the chunked form too, as the
 state-space scan's: what is kept of the forward pass are the arguments
-alone; the states that enter the chunks are carried forward again, then the
-chunks are taken last to first, each differentiated on its own with the
-gradient of the state it hands on. Keeping every chunk's state instead would
-cost ``chunks x H x Dk x Dv`` float32 a row (1.6 GB for the benchmark's
-three mixers at 8192 positions and two clients).
+alone. The state-independent part runs again for every chunk at once (its
+``jax.vjp``), the states that enter the chunks are carried forward again
+through the loop's part alone, the loop's part is taken last to first, each
+chunk differentiated on its own with the gradient of the state it hands
+on, and the cotangents of both batched parts are pulled back for every
+chunk at once, the solve's among them. Keeping every chunk's state instead
+would cost ``chunks x H x Dk x Dv`` float32 a row (1.6 GB for the
+benchmark's three mixers at 8192 positions and two clients).
 
 Registered as ``gated_delta_rule`` under the kernel registry, with this
 ``jax.numpy`` form alone.
@@ -61,19 +72,32 @@ def n_chunks(seq: int, chunk: int = DEFAULT_CHUNK) -> int:
 
 
 def _heads(a, H):
-    """A key head's [b, Q, Hk, D] repeated for the value heads that read it."""
+    """A key head's [..., Q, Hk, D] repeated for the value heads that read it."""
     return jnp.repeat(a, H // a.shape[-2], axis=-2)
 
 
+def _unit_lower_inverse(a):
+    """``(I + a)^-1`` for ``a`` [..., Q, Q] float32 strictly below its
+    diagonal: one unit lower-triangular solve, batched over every leading
+    axis."""
+    eye = jnp.eye(a.shape[-1], dtype=a.dtype)
+    return jax.scipy.linalg.solve_triangular(
+        eye + a, jnp.broadcast_to(eye, a.shape), lower=True, unit_diagonal=True)
+
+
 def _prepare(q, k, v, g, beta):
-    """What a chunk's state-dependent part needs: ``(qh, kh, qg, kd, U, W,
-    gam, decay)`` with heads in front ([b, H, Q, D] and [b, H, Q, Q]) and
-    ``decay`` [b, H] the chunk's whole decay."""
+    """Everything of a chunk that does not read the state, for every chunk
+    at once: ``q``/``k`` [..., Q, Hk, Dk], ``v`` [..., Q, H, Dv], ``g``/``beta``
+    [..., Q, H] under any leading axes; returns ``(U, W, qg, kd, decay, qk)``
+    with heads in front of the positions ([..., H, Q, D] and [..., H, Q, Q]),
+    ``decay`` [..., H] the chunk's whole decay and ``qk`` the masked
+    ``(Q K^T) * Gam``. ``W`` and ``qk`` are rounded to ``v``'s type, as the
+    products take them."""
     pd = v.dtype
     H, Q = v.shape[-2], v.shape[-3]
-    qh, kh = (jnp.moveaxis(_heads(a, H), -2, -3) for a in (q, k))  # [b, H, Q, Dk]
+    qh, kh = (jnp.moveaxis(_heads(a, H), -2, -3) for a in (q, k))  # [..., H, Q, Dk]
     vh = jnp.moveaxis(v, -2, -3)
-    G = jnp.moveaxis(jnp.cumsum(g.astype(jnp.float32), axis=-2), -1, -2)  # [b, H, Q]
+    G = jnp.moveaxis(jnp.cumsum(g.astype(jnp.float32), axis=-2), -1, -2)  # [..., H, Q]
     bt = jnp.moveaxis(beta.astype(jnp.float32), -1, -2)
     causal = jnp.tril(jnp.ones((Q, Q), bool))
     # the mask goes on the exponent: exp(-inf) is 0 with a zero gradient
@@ -81,51 +105,37 @@ def _prepare(q, k, v, g, beta):
     kk = jnp.einsum("...id,...jd->...ij", kh.astype(pd), kh.astype(pd),
                     preferred_element_type=jnp.float32)
     strict = jnp.tril(jnp.ones((Q, Q), bool), -1)
-    eye = jnp.eye(Q, dtype=jnp.float32)
-    a_mat = jnp.where(strict, bt[..., :, None] * kk * gam, 0.0)
-    T = jax.scipy.linalg.solve_triangular(
-        eye + a_mat, jnp.broadcast_to(eye, a_mat.shape), lower=True, unit_diagonal=True)
-    Tp = T.astype(pd)
+    Tp = _unit_lower_inverse(jnp.where(strict, bt[..., :, None] * kk * gam, 0.0)).astype(pd)
     bv = (vh.astype(jnp.float32) * bt[..., None]).astype(pd)
     bk = (kh.astype(jnp.float32) * (bt * jnp.exp(G))[..., None]).astype(pd)
     U = jnp.einsum("...ij,...jd->...id", Tp, bv, preferred_element_type=jnp.float32)
-    W = jnp.einsum("...ij,...jd->...id", Tp, bk, preferred_element_type=jnp.float32)
+    W = jnp.einsum("...ij,...jd->...id", Tp, bk, preferred_element_type=jnp.float32).astype(pd)
     qg = (qh.astype(jnp.float32) * jnp.exp(G)[..., None]).astype(pd)
     kd = (kh.astype(jnp.float32) * jnp.exp(G[..., -1:] - G)[..., None]).astype(pd)
-    return qh, kh, qg, kd, U, W, gam, jnp.exp(G[..., -1])
-
-
-def _written(S, U, W, pd):
-    """``V' = U - W S``: the values the chunk writes, float32."""
-    return U - jnp.einsum("...ik,...kv->...iv", W.astype(pd), S.astype(pd),
-                          preferred_element_type=jnp.float32)
-
-
-def _handed_on(S, q, k, v, g, beta):
-    """The state a chunk hands on, and nothing of its output."""
-    pd = v.dtype
-    *_, kd, U, W, _, decay = _prepare(q, k, v, g, beta)
-    vn = _written(S, U, W, pd)
-    return decay[..., None, None] * S + jnp.einsum(
-        "...ik,...iv->...kv", kd, vn.astype(pd), preferred_element_type=jnp.float32)
-
-
-def _chunk(S, q, k, v, g, beta):
-    """One chunk of every row: ``S`` [b, H, Dk, Dv] float32 enters, ``q``/``k``
-    [b, Q, Hk, Dk], ``v`` [b, Q, H, Dv], ``g``/``beta`` [b, Q, H]; returns the
-    state handed on and ``o`` [b, Q, H, Dv] in ``v``'s type."""
-    pd = v.dtype
-    qh, kh, qg, kd, U, W, gam, decay = _prepare(q, k, v, g, beta)
-    vn = _written(S, U, W, pd)
     # inside the chunk: (q_i . k_j) Gam_ij for j <= i
-    qk = jnp.einsum("...id,...jd->...ij", qh.astype(pd), kh.astype(pd),
-                    preferred_element_type=jnp.float32) * gam
-    o = (jnp.einsum("...ik,...kv->...iv", qg, S.astype(pd), preferred_element_type=jnp.float32)
-         + jnp.einsum("...ij,...jv->...iv", qk.astype(pd), vn.astype(pd),
-                      preferred_element_type=jnp.float32))
+    qk = (jnp.einsum("...id,...jd->...ij", qh.astype(pd), kh.astype(pd),
+                     preferred_element_type=jnp.float32) * gam).astype(pd)
+    return U, W, qg, kd, jnp.exp(G[..., -1]), qk
+
+
+def _step(S, U, W, qg, kd, decay):
+    """What of one chunk reads the state ``S`` [b, H, Dk, Dv] (float32) that
+    enters it: the state handed on, the values the chunk writes ``V' = U - W
+    S`` in ``v``'s type, and the part of the output the state gives, ``qg S``
+    (float32). The state is rounded to ``v``'s type once for each product and
+    ``V'`` once for each use, as operands."""
+    pd = W.dtype
+    vn = U - jnp.einsum("...ik,...kv->...iv", W, S.astype(pd), preferred_element_type=jnp.float32)
+    inter = jnp.einsum("...ik,...kv->...iv", qg, S.astype(pd), preferred_element_type=jnp.float32)
     S = decay[..., None, None] * S + jnp.einsum(
         "...ik,...iv->...kv", kd, vn.astype(pd), preferred_element_type=jnp.float32)
-    return S, jnp.moveaxis(o, -3, -2).astype(pd)
+    return S, vn.astype(pd), inter
+
+
+def _intra(qk, vn):
+    """The output a chunk's own positions give: ``((Q K^T) * Gam)_causal V'``,
+    float32."""
+    return jnp.einsum("...ij,...jv->...iv", qk, vn, preferred_element_type=jnp.float32)
 
 
 def _by_chunk(a, chunk):
@@ -152,9 +162,15 @@ def _state0(k, v):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def gated_delta_rule_xla(q, k, v, g, beta, chunk=DEFAULT_CHUNK):
     S = v.shape[1]
-    xs = tuple(_by_chunk(a, chunk) for a in (q, k, v, g, beta))
-    _, o = lax.scan(lambda state, c: _chunk(state, *c), _state0(k, v), xs)
-    return _rows(o, S)
+    *loop, qk = _prepare(*(_by_chunk(a, chunk) for a in (q, k, v, g, beta)))
+
+    def step(state, c):
+        state, vn, inter = _step(state, *c)
+        return state, (vn, inter)
+
+    _, (vn, inter) = lax.scan(step, _state0(k, v), loop)
+    o = inter + _intra(qk, vn)
+    return _rows(jnp.moveaxis(o, -3, -2).astype(v.dtype), S)
 
 
 def _fwd(q, k, v, g, beta, chunk):
@@ -164,23 +180,31 @@ def _fwd(q, k, v, g, beta, chunk):
 def _bwd(chunk, res, do):
     q, k, v, g, beta = res
     S = v.shape[1]
-    xs = tuple(_by_chunk(a, chunk) for a in res)
+    (*loop, qk), prepared = jax.vjp(_prepare, *(_by_chunk(a, chunk) for a in res))
 
-    # the states that enter the chunks, carried forward again
+    # the states that enter the chunks and the values each writes, carried
+    # forward again
     def carry(state, c):
-        return _handed_on(state, *c), state
+        nxt, vn, _ = _step(state, *c)
+        return nxt, (state, vn)
 
-    _, entered = lax.scan(carry, _state0(k, v), xs)
+    _, (entered, vn) = lax.scan(carry, _state0(k, v), loop)
 
-    # last chunk first, each differentiated on its own
+    # the output's cotangent, [chunks, b, H, Q, Dv]: the intra-chunk
+    # product's part for every chunk at once
+    do = jnp.moveaxis(_by_chunk(do, chunk), -2, -3)
+    d_qk, d_vn = jax.vjp(_intra, qk, vn)[1](do.astype(jnp.float32))
+
+    # last chunk first, each chunk's state-dependent part differentiated on
+    # its own with the gradient of the state it hands on
     def step(d_state, c):
-        state, xc, doc = c
-        _, vjp = jax.vjp(_chunk, state, *xc)
-        d_state, *grads = vjp((d_state, doc))
+        state, xc, d_vn, doc = c
+        _, vjp = jax.vjp(_step, state, *xc)
+        d_state, *grads = vjp((d_state, d_vn, doc.astype(jnp.float32)))
         return d_state, tuple(grads)
 
-    _, grads = lax.scan(step, _state0(k, v), (entered, xs, _by_chunk(do, chunk)), reverse=True)
-    return tuple(_rows(gr, S).astype(a.dtype) for gr, a in zip(grads, res))
+    _, grads = lax.scan(step, _state0(k, v), (entered, loop, d_vn, do), reverse=True)
+    return tuple(_rows(gr, S).astype(a.dtype) for gr, a in zip(prepared((*grads, d_qk)), res))
 
 
 gated_delta_rule_xla.defvjp(_fwd, _bwd)
@@ -191,6 +215,12 @@ GATED_DELTA_RULE = registry.register_op(registry.KernelOp(
     xla=gated_delta_rule_xla,
     parity="the jax.numpy form alone (pinned against a position-by-position "
            "loop in tests/test_gated_delta.py)",
+    bench_shapes=(
+        # the Gated DeltaNet cell's folded step: 2 clients x 1 row of 8192,
+        # 32 value heads over 16 key heads of 128, bfloat16 operands
+        {"label": "gdn-B2-S8192-Hk16-H32-D128", "B": 2, "S": 8192, "Hk": 16, "H": 32,
+         "D": 128, "chunk": DEFAULT_CHUNK, "dtype": "bfloat16"},
+    ),
 ))
 
 

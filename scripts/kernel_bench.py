@@ -41,9 +41,23 @@ forward: a sum's gradient needs no forward result) and
 ``forward_device_ms`` the forward pass alone, both by the device's clock; a
 round of the cell runs both.
 
+The ``gated_delta_rule`` rows draw ``q``, ``k``, ``v``, ``g`` and ``beta`` as
+the Gated DeltaNet mixer hands them over (l2-normalised keys and queries,
+``g`` and ``beta`` from the published draws of ``A`` and ``dt``) and time the
+forward pass (``forward_device_ms``) and, with ``--backward``, ``jax.grad``
+of a sum (``device_ms``), both by the device's clock. ``--rule-forms
+"default;product;PATH"`` gives one row for each entry: the op as it stands,
+the op with its ``chunk x chunk`` triangular solve replaced by the product
+form of the same inverse (``_product_inverse``), or the
+``gated_delta_rule_xla`` of another module's file (such as an older
+commit's ``bcfl_tpu/ops/gated_delta.py``). Each row counts the triangular
+solves in its programs' jaxprs, and its parity is read against the first
+row's.
+
 Usage: python scripts/kernel_bench.py [--out results/kernel_bench.json]
        [--ops int8_quantize,topk_select] [--iters N] [--backward]
        [--flash-blocks "bq,bk[;bq,bk...]"] [--scan-heads "h[;h...]"]
+       [--rule-forms "default;product;PATH"]
 """
 
 from __future__ import annotations
@@ -51,6 +65,7 @@ from __future__ import annotations
 import argparse
 import contextlib
 import glob
+import importlib.util
 import json
 import math
 import os
@@ -63,9 +78,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
+from jax import lax  # noqa: E402
 
 # importing these registers the ops
 import bcfl_tpu.ops.flash  # noqa: E402,F401
+import bcfl_tpu.ops.gated_delta as gated_delta  # noqa: E402
 import bcfl_tpu.ops.grouped_matmul  # noqa: E402,F401
 import bcfl_tpu.ops.pallas_codec  # noqa: E402,F401
 import bcfl_tpu.ops.ssm_scan  # noqa: E402,F401
@@ -122,6 +139,27 @@ def _build(op_name: str, row: dict):
                                         math.log(1e-3), math.log(1e-1)))
         A = -jax.random.uniform(ks[4], (H,), jnp.float32, 1.0, 16.0)
         return (x, dt, A, Bm, Cm, jnp.ones((H,), jnp.float32)), {"chunk": row["chunk"]}
+    if op_name == "gated_delta_rule":
+        B, S, Hk, H, D = row["B"], row["S"], row["Hk"], row["H"], row["D"]
+        dtype = jnp.dtype(row.get("dtype", "float32"))
+        ks = [jax.random.fold_in(key, i) for i in range(7)]
+
+        def unit(x):
+            return x * lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+        # as the mixer hands them over: l2-normalised queries (scaled by
+        # D^-1/2) and keys, v a silu of the convolution's output
+        q = (unit(jax.random.normal(ks[0], (B, S, Hk, D))) * D ** -0.5).astype(dtype)
+        k = unit(jax.random.normal(ks[1], (B, S, Hk, D))).astype(dtype)
+        v = jax.nn.silu(jax.random.normal(ks[2], (B, S, H, D))).astype(dtype)
+        # beta = sigmoid(b), g = -A softplus(a + dt_bias): A uniform in
+        # (0, 16), dt log-uniform in [0.001, 0.1] (the model's draws)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[3], (B, S, H)))
+        A = jax.random.uniform(ks[4], (H,), jnp.float32, 0.0, 16.0)
+        dt = jnp.exp(jax.random.uniform(ks[5], (H,), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        dt_bias = dt + jnp.log(-jnp.expm1(-dt))
+        g = -A * jax.nn.softplus(jax.random.normal(ks[6], (B, S, H)) + dt_bias)
+        return (q, k, v, g, beta), {"chunk": row["chunk"]}
     raise SystemExit(f"no arg builder for op {op_name!r}; add one here")
 
 
@@ -176,6 +214,138 @@ def _device_ms(fn, args, iters: int):
         busy += max(0.0, e - max(s, end))
         end = max(end, e)
     return round(busy / iters / 1e6, 4)
+
+
+def _product_form(a, block: int = 16):
+    """``(I + a)^-1`` for ``a`` [..., Q, Q] strictly lower, in the product
+    form: the ``block x block`` diagonal blocks inverted by forward
+    substitution, then merged two at a time by ``[[L11, 0], [L21, L22]]^-1 =
+    [[L11^-1, 0], [-L22^-1 L21 L11^-1, L22^-1]]``. Every product at
+    ``HIGHEST``: a float32 product at the default precision is one bfloat16
+    pass on a TPU."""
+    hi = lax.Precision.HIGHEST
+    m = a.shape[-1] // block
+    diag = jnp.stack([a[..., i * block:(i + 1) * block, i * block:(i + 1) * block]
+                      for i in range(m)], -3)
+    x = jnp.broadcast_to(jnp.eye(block, dtype=a.dtype), diag.shape)
+    for i in range(1, block):  # row i: e_i - sum over j < i of a_ij x_j
+        x = x.at[..., i, :].add(-jnp.einsum("...j,...jc->...c", diag[..., i, :], x, precision=hi))
+    inv, s = [x[..., i, :, :] for i in range(m)], block
+    while len(inv) > 1:
+        merged = []
+        for p in range(0, len(inv), 2):
+            r = p * s
+            x11, x22 = inv[p], inv[p + 1]
+            l21x11 = jnp.einsum("...ij,...jk->...ik", a[..., r + s:r + 2 * s, r:r + s], x11,
+                                precision=hi)
+            x21 = -jnp.einsum("...ij,...jk->...ik", x22, l21x11, precision=hi)
+            merged.append(jnp.concatenate([jnp.concatenate([x11, jnp.zeros_like(x11)], -1),
+                                           jnp.concatenate([x21, x22], -1)], -2))
+        inv, s = merged, 2 * s
+    return inv[0]
+
+
+@jax.custom_vjp
+def _product_inverse(a):
+    """``_product_form``, differentiated as an inverse: ``T = (I + a)^-1``
+    sends ``dT`` back as ``-T^T dT T^T`` (two products at ``HIGHEST``), so
+    the backward pass keeps ``T`` alone and not the substitution's steps."""
+    return _product_form(a)
+
+
+def _product_inverse_fwd(a):
+    t = _product_form(a)
+    return t, t
+
+
+def _product_inverse_bwd(t, dt):
+    hi = lax.Precision.HIGHEST
+    tdt = jnp.einsum("...ji,...jk->...ik", t, dt, precision=hi)
+    return (-jnp.einsum("...ij,...kj->...ik", tdt, t, precision=hi),)
+
+
+_product_inverse.defvjp(_product_inverse_fwd, _product_inverse_bwd)
+
+
+def _rule_forms(spec: str):
+    """``--rule-forms`` -> its entries, ``default`` alone when empty."""
+    return [e.strip() for e in (spec or "default").split(";")]
+
+
+@contextlib.contextmanager
+def _rule_form(form: str):
+    """The gated delta rule as ``form`` names it, ``(q, k, v, g, beta,
+    chunk)``: ``default``, the op as it stands; ``product``, the op with
+    ``_product_inverse`` in place of its solve while the context lasts (the
+    op reads it when it is traced); else the path of a module file whose
+    ``gated_delta_rule_xla`` is timed, loaded without registering its op."""
+    if form == "default":
+        yield gated_delta.gated_delta_rule_xla
+    elif form == "product":
+        kept = gated_delta._unit_lower_inverse
+        gated_delta._unit_lower_inverse = _product_inverse
+        try:
+            yield gated_delta.gated_delta_rule_xla
+        finally:
+            gated_delta._unit_lower_inverse = kept
+    else:
+        spec = importlib.util.spec_from_file_location("rule_form", form)
+        if spec is None:
+            raise SystemExit(f"--rule-forms: {form!r} is neither 'default', 'product' "
+                             "nor a module file")
+        module = importlib.util.module_from_spec(spec)
+        kept = registry.register_op
+        registry.register_op = lambda op: op
+        try:
+            spec.loader.exec_module(module)
+        finally:
+            registry.register_op = kept
+        yield module.gated_delta_rule_xla
+
+
+def _rule_fn(fn, chunk: int, backward: bool):
+    """The timed rule program: the forward pass, or with ``backward``
+    ``jax.grad`` of a sum (forward and backward)."""
+    def rule(*a):  # positional: custom_vjp functions take no keywords
+        return fn(*a, chunk)
+
+    if backward:
+        return jax.jit(jax.grad(lambda *a: rule(*a).astype(jnp.float32).sum(), argnums=range(5)))
+    return jax.jit(rule)
+
+
+def _solves(jfn, args) -> int:
+    """Triangular solves in the jaxpr of a timed program."""
+    return str(jax.make_jaxpr(jfn)(*args)).count("triangular_solve")
+
+
+def _rule_rows(op, call_args, kw, forms, backward, iters, base):
+    """One row for each ``--rule-forms`` entry; parity against the first."""
+    rows, ref = [], None
+    for form in forms:
+        row = dict(base, rule_form=form)
+        rows.append(row)
+        with _rule_form(form) as fn:
+            fwd = _rule_fn(fn, kw["chunk"], False)
+            jfn = _rule_fn(fn, kw["chunk"], True) if backward else fwd
+            row["triangular_solves"] = _solves(jfn, call_args)
+            if backward:
+                row["timed"] = "grad of a sum (forward + backward)"
+            out = jfn(*call_args)
+            fence(out)
+            if ref is None:
+                ref = out
+            else:
+                row["parity_ok"] = _parity_ok(op, ref, out)
+                if not row["parity_ok"]:
+                    row["status"] = "parity_violation"
+                    continue  # never time a wrong form
+            row["wall_ms"] = _time_ms(jfn, call_args, iters)
+            row["device_ms"] = _device_ms(jfn, call_args, iters)
+            if backward:
+                row["forward_device_ms"] = _device_ms(fwd, call_args, iters)
+        row["status"] = "ok"
+    return rows
 
 
 def _flash_variants(spec: str):
@@ -284,8 +454,9 @@ def main() -> int:
                     help="timed iterations (default: 3 on TPU, 1 off-TPU "
                          "plumbing)")
     ap.add_argument("--backward", action="store_true",
-                    help="flash_attention, ssm_scan: time jax.grad of a sum "
-                         "through the op (flash: and each Pallas kernel alone)")
+                    help="flash_attention, ssm_scan, gated_delta_rule: time "
+                         "jax.grad of a sum through the op (flash: and each "
+                         "Pallas kernel alone)")
     ap.add_argument("--flash-blocks", default="",
                     help="flash_attention: 'bq,bk[;bq,bk...]', an entry "
                          "'f,f/k,k/q,q' names forward/dKV/dQ apart, "
@@ -293,6 +464,10 @@ def main() -> int:
     ap.add_argument("--scan-heads", default="",
                     help="ssm_scan: 'h[;h...]' heads a grid step takes, "
                          "'default' is pallas_ssm.HEAD_BLOCK")
+    ap.add_argument("--rule-forms", default="",
+                    help="gated_delta_rule: 'default', 'product' (the product "
+                         "form of the solve) or a module file's "
+                         "gated_delta_rule_xla, ';'-separated")
     args = ap.parse_args()
 
     compile_cache()
@@ -310,6 +485,14 @@ def main() -> int:
         scan = name == "ssm_scan"
         for shape in op.bench_shapes:
             call_args, kw = _build(name, shape)
+            if name == "gated_delta_rule":
+                rows += _rule_rows(op, call_args, kw, _rule_forms(args.rule_forms),
+                                   args.backward, iters, {
+                                       "op": name, "label": shape["label"],
+                                       "shape": {k: v for k, v in shape.items() if k != "label"},
+                                       "impl": "xla", "parity": op.parity, "backend": backend,
+                                       "plumbing_only": plumbing})
+                continue
             ref = None
             # one Pallas row, or one for each --flash-blocks or
             # --scan-heads entry

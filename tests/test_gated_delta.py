@@ -1,5 +1,6 @@
 """The gated delta rule in its chunked form (``ops/gated_delta.py``) against
-the definition, a position at a time, in float32 on the CPU.
+the definition, a position at a time, in float32 on the CPU, and the
+chunk-parallel split: what reads no state outside the loop over the chunks.
 
 Tolerance 2e-4 of the largest value, for values and every gradient. Both
 sides are float32; the chunked form adds a chunk's positions through a
@@ -9,6 +10,7 @@ own: the cases here read under 2e-6, and 2e-4 leaves a factor of a hundred
 for the fast heads, whose decays inside a chunk span e^-1000."""
 
 import jax
+import jax.extend as jex
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -76,6 +78,63 @@ def test_the_chunked_rule_against_a_position_by_position_loop(S, chunk, Hk, H, d
     for name, a, b in zip(("q", "k", "v", "g", "beta"), g_got, g_want):
         assert a.shape == b.shape and bool(jnp.isfinite(a).all()), name
         np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()), err_msg=name)
+
+
+@pytest.mark.parametrize("decay", [-16.0, -0.002], ids=["fast", "slow"])
+def test_beta_near_one_at_the_published_chunk_against_the_loop(decay):
+    """``beta`` near 1 (0.92 to 1) over four chunks of 64: the strictly lower
+    ``A`` at its largest, so ``T = (I + A)^-1``, solved once for every chunk
+    of every row, at its worst conditioned; values and the gradients of q, k,
+    v, g and beta against the loop at the file's 2e-4 (fast heads: the
+    decays inside a chunk span e^-1000)."""
+    q, k, v, g, _ = _inputs(256, decay, seed=3)
+    beta = jax.nn.sigmoid(jax.random.normal(jax.random.key(9), g.shape) + 6.0)
+    args = (q, k, v, g, beta)
+    t = jax.random.normal(jax.random.key(7), v.shape)
+    with jax.default_matmul_precision("highest"):
+        got = gated_delta_rule(*args, chunk=64)
+        want = _loop(*args)
+        g_got = jax.grad(lambda *a: (gated_delta_rule(*a, chunk=64) * t).sum(), range(5))(*args)
+        g_want = jax.grad(lambda *a: (_loop(*a) * t).sum(), range(5))(*args)
+    assert float(beta.min()) > 0.9 and bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, atol=2e-4 * float(jnp.abs(want).max()))
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), g_got, g_want):
+        assert a.shape == b.shape and bool(jnp.isfinite(a).all()), name
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()), err_msg=name)
+
+
+def _primitives(jaxpr, in_loop=False):
+    """``(primitive name, inside a scan's body)`` of every equation of
+    ``jaxpr`` and of the jaxprs its equations hold."""
+    out = []
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name, in_loop))
+        inner = in_loop or eqn.primitive.name == "scan"
+        for p in eqn.params.values():
+            for x in p if isinstance(p, (tuple, list)) else (p,):
+                if isinstance(x, jex.core.ClosedJaxpr):
+                    out += _primitives(x.jaxpr, inner)
+                elif isinstance(x, jex.core.Jaxpr):
+                    out += _primitives(x, inner)
+    return out
+
+
+def test_the_chunk_loops_carry_the_state_alone():
+    """The chunk-parallel form: no loop over the chunks holds a triangular
+    solve, forward or backward. The solve runs once for all chunks: once in
+    the forward pass, and in the backward pass once again with its
+    transpose; the forward's loop holds the three products that read the
+    state or the values it wrote (``W S``, ``(exp(G) Q) S``, ``K^T V'``)."""
+    args = _inputs(256, -0.05)
+    fwd = _primitives(jax.make_jaxpr(lambda *a: gated_delta_rule(*a, chunk=64))(*args).jaxpr)
+    grad = _primitives(jax.make_jaxpr(jax.grad(
+        lambda *a: gated_delta_rule(*a, chunk=64).sum(), range(5)))(*args).jaxpr)
+    for prims in (fwd, grad):
+        assert ("triangular_solve", True) not in prims
+    assert fwd.count(("triangular_solve", False)) == 1
+    # the forward pass's, the backward pass's own and its transpose
+    assert grad.count(("triangular_solve", False)) == 3
+    assert fwd.count(("scan", False)) == 1 and fwd.count(("dot_general", True)) == 3
 
 
 def test_one_state_is_carried_over_several_chunks():

@@ -429,3 +429,52 @@ def test_kernel_bench_scan_rows_run_what_the_model_hands_over():
     assert y.shape == (2, 32, 4, 8)
     grads = kb._scan_fn(fn, tiny, 32, True)(*args)
     assert grads[0].shape == args[0].shape and bool(jnp.isfinite(grads[0].astype(jnp.float32)).all())
+
+
+def test_kernel_bench_rule_rows_run_what_the_mixer_hands_over(tmp_path):
+    """scripts/kernel_bench.py: a rule row's operands are drawn as the Gated
+    DeltaNet mixer hands them over; ``--rule-forms`` entries run the op as it
+    stands, the op with the product form of its inverse in place of the
+    solve (put back afterwards), or another module file's rule, loaded
+    without touching the registry; every form is timed against the first."""
+    import importlib.util
+    import pathlib
+    import shutil
+
+    from bcfl_tpu.ops import gated_delta, registry
+
+    root = pathlib.Path(__file__).resolve().parents[1]
+    spec = importlib.util.spec_from_file_location("kernel_bench", root / "scripts" / "kernel_bench.py")
+    kb = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(kb)
+
+    assert kb._rule_forms("") == ["default"]
+    assert kb._rule_forms("default; product") == ["default", "product"]
+    op = registry.get_op("gated_delta_rule")
+    (cell,) = op.bench_shapes
+    assert (cell["B"], cell["S"], cell["Hk"], cell["H"], cell["D"]) == (2, 8192, 16, 32, 128)
+    tiny = dict(cell, S=96, Hk=1, H=2, D=16)
+    args, kw = kb._build("gated_delta_rule", tiny)
+    q, k, v, g, beta = args
+    assert [a.dtype for a in args] == [jnp.bfloat16] * 3 + [jnp.float32] * 2 and kw == {"chunk": 64}
+    assert q.shape == (2, 96, 1, 16) and v.shape == (2, 96, 2, 16) and g.shape == (2, 96, 2)
+    np.testing.assert_allclose(jnp.linalg.norm(k.astype(jnp.float32), axis=-1), 1.0, atol=1e-2)
+    assert bool((g < 0).all()) and bool(((beta > 0) & (beta < 1)).all())
+
+    a = jnp.tril(0.3 * jax.random.normal(jax.random.key(0), (3, 64, 64)), -1)
+    np.testing.assert_allclose(kb._product_form(a), gated_delta._unit_lower_inverse(a), atol=1e-5)
+    kept = gated_delta._unit_lower_inverse
+    with kb._rule_form("product") as fn:
+        assert gated_delta._unit_lower_inverse is kb._product_inverse
+        assert kb._solves(kb._rule_fn(fn, 64, True), args) == 0
+    assert gated_delta._unit_lower_inverse is kept
+    other = tmp_path / "other_rule.py"
+    shutil.copy(root / "bcfl_tpu" / "ops" / "gated_delta.py", other)
+    with kb._rule_form(str(other)) as fn:
+        assert fn is not gated_delta.gated_delta_rule_xla
+    assert registry.get_op("gated_delta_rule") is op
+
+    rows = kb._rule_rows(op, args, kw, ["default", "product", str(other)], True, 1, {})
+    assert [r["status"] for r in rows] == ["ok"] * 3
+    assert [r.get("parity_ok") for r in rows] == [None, True, True]
+    assert [r["triangular_solves"] for r in rows] == [3, 0, 3]

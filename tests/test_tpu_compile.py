@@ -212,13 +212,16 @@ def test_one_mamba_layers_round_program_at_published_widths(one_chip, for_the_ch
 
 def test_a_gated_deltanet_and_a_gated_attention_layers_round_program(one_chip, for_the_chip, monkeypatch):
     """The fused round program of the Gated DeltaNet cell at TWO layers (one
-    Gated DeltaNet mixer with its chunked rule in ``jax.numpy``, one gated
-    attention layer with heads of 256 through the flash kernels, each with
-    the expert layer at 64 of 512 held; published widths, the cell's rows of
-    8192, ``remat``, LoRA r16, ``donate``): it compiles for the described
-    chip, its kernels are the flash kernels' three and the grouped products'
-    three forward and five backward calls a layer (no forward kernel again),
-    and the compiler rematerialised nothing on its own."""
+    Gated DeltaNet mixer with its chunked rule in ``jax.numpy``, in the
+    chunk-parallel form: the triangular solves and every product that reads
+    no state batched over all chunks, the loops over the chunks carrying the
+    state alone; one gated attention layer with heads of 256 through the
+    flash kernels, each with the expert layer at 64 of 512 held; published
+    widths, the cell's rows of 8192, ``remat``, LoRA r16, ``donate``): it
+    compiles for the described chip, its kernels are the flash kernels' three
+    and the grouped products' three forward and five backward calls a layer
+    (no forward kernel again), and the compiler rematerialised nothing on its
+    own."""
     from bcfl_tpu.core.mesh import client_mesh
     from bcfl_tpu.fed.client_step import build_programs
     from bcfl_tpu.models import build, lora, lora_policy

@@ -192,6 +192,16 @@ class FedEngine:
         info_source: int = 1,
         fused_tamper: Optional[Callable] = None,
     ):
+        # the construction's own span tree (OBSERVABILITY.md §7): held
+        # until the first ``run`` has a stream to send it to
+        self._setup_clock = StepClock(hold=True)
+        with self._setup_clock.phase("setup"):
+            self._build(cfg, tamper_hook, info_source, fused_tamper)
+
+    def _build(self, cfg: FedConfig, tamper_hook: Optional[Callable],
+               info_source: int, fused_tamper: Optional[Callable]) -> None:
+        """The construction proper, inside the ``setup`` phase."""
+        clock = self._setup_clock
         self.cfg = cfg
         # ``tamper_hook`` (host-tree byte tampering, forces the per-round
         # path) and ``fused_tamper`` ((rnd) -> [C] scales, in-graph transport
@@ -204,7 +214,7 @@ class FedEngine:
             warnings.warn(
                 "tamper_hook/fused_tamper are deprecated shims — schedule "
                 "corruption via FedConfig.faults (bcfl_tpu.faults.FaultPlan)",
-                DeprecationWarning, stacklevel=2)
+                DeprecationWarning, stacklevel=3)
         # --- cohort-batched scale-out (SCALING.md "Cohort mode") ---
         # self.C = the stacked client-axis width (the per-round cohort);
         # self.R = the client registry size. Sampling off: R == C ==
@@ -251,16 +261,18 @@ class FedEngine:
         self._client_key = jax.random.fold_in(self.root_key, 4)
 
         # --- data (tokenize once; SURVEY.md §3.2 fixes the 200x re-tokenize) ---
-        self.dataset = load_dataset(
-            cfg.dataset, num_labels=cfg.num_labels,
-            text_col=cfg.text_col, label_col=cfg.label_col)
-        self.tokenizer = get_tokenizer(cfg.tokenizer, cfg.vocab_size)
-        self.cache = TokenCache.build(self.dataset, self.tokenizer, cfg.seq_len)
-        self.num_labels = max(cfg.num_labels, self.cache.num_labels)
-        self.partitioner = Partitioner(
-            cfg.partition, self.dataset.n_train, self.dataset.n_test,
-            jax.random.fold_in(self.root_key, 1),
-        )
+        with clock.span("data"):
+            self.dataset = load_dataset(
+                cfg.dataset, num_labels=cfg.num_labels,
+                text_col=cfg.text_col, label_col=cfg.label_col)
+            self.tokenizer = get_tokenizer(cfg.tokenizer, cfg.vocab_size)
+            self.cache = TokenCache.build(self.dataset, self.tokenizer,
+                                          cfg.seq_len)
+            self.num_labels = max(cfg.num_labels, self.cache.num_labels)
+            self.partitioner = Partitioner(
+                cfg.partition, self.dataset.n_train, self.dataset.n_test,
+                jax.random.fold_in(self.root_key, 1),
+            )
 
         # --- mesh (before the model: sp injects the mesh into attention) ---
         # pod=True spans every host's devices (hosts-major, DCN-outermost);
@@ -317,78 +329,80 @@ class FedEngine:
             dtype_overrides["use_flash"] = False
         from bcfl_tpu.models import lora_policy
 
-        if cfg.hf_checkpoint is not None:
-            if cfg.task == "causal_lm":
-                raise ValueError(
-                    "task='causal_lm' needs a decoder; the HF import path "
-                    "builds encoder classifiers")
-            from bcfl_tpu.models.hf_import import import_pretrained
+        with clock.span("model_init"):
+            if cfg.hf_checkpoint is not None:
+                if cfg.task == "causal_lm":
+                    raise ValueError(
+                        "task='causal_lm' needs a decoder; the HF import path "
+                        "builds encoder classifiers")
+                from bcfl_tpu.models.hf_import import import_pretrained
 
-            model_cfg, variables = import_pretrained(
-                cfg.hf_checkpoint, num_labels=self.num_labels,
-                reinit_classifier=True,
-            )
-            model_cfg = dataclasses.replace(model_cfg, **dtype_overrides)
-            self.model = TextClassifier(model_cfg)
-            self._lora_policy = lora_policy(self.model)
-            # the importer materializes float32; the configured param dtype
-            # must apply to the ARRAYS, not just the config record
-            params = jax.tree.map(
-                lambda x: x.astype(model_cfg.param_dtype)
-                if jnp.issubdtype(x.dtype, jnp.floating) else x,
-                variables["params"])
-        else:
-            from bcfl_tpu.models import build as build_model
-
-            self.model = build_model(
-                cfg.model, num_labels=self.num_labels,
-                vocab_size=self.tokenizer.vocab_size,
-                head="lm" if cfg.task == "causal_lm" else "classifier",
-                **dtype_overrides,
-            )
-            self._lora_policy = lora_policy(self.model)
-            ids = jnp.ones((2, cfg.seq_len), jnp.int32)
-            key = jax.random.fold_in(self.root_key, 2)
-            init = self.model.init
-            if model_size_gb(
-                    jax.eval_shape(init, key, ids, ids)["params"]) >= 1.0:
-                # a large base: the draw alone, jitted (init's forward pass
-                # is dead code there), and born with the steady-state
-                # sharding, so that pinning it makes no second copy of a
-                # base that fills half a chip. Smaller ones keep the eager
-                # draw (its values differ from the jitted one's in the last
-                # bits, and seeded runs are pinned to them)
-                init = jax.jit(init, out_shardings=self.mesh.replicated())
-            params = init(key, ids, ids)["params"]
-
-        if cfg.lora_rank > 0:
-            policy = self._lora_policy
-            how = dict(targets=policy.targets,
-                       head_modules=policy.head_modules,
-                       dtype=policy.adapter_dtype, tied=policy.tied)
-            self.frozen = params
-            ranks = cfg.client_lora_ranks
-            if ranks is not None and len(set(ranks)) > 1:
-                # heterogeneous fleet: each client's adapters initialize AT
-                # ITS OWN rank (own gaussian/sqrt(r_c) scale), zero-padded
-                # to the cohort max; the round-0 global is their RBLA mean
-                # (b starts at zeros everywhere, so the collapse only
-                # blends the per-rank-normalized 'a' factors)
-                from bcfl_tpu.parallel import gspmd
-
-                stacked0 = lora_lib.init_lora_ranks(
-                    jax.random.fold_in(self.root_key, 3), params, ranks,
-                    targets=policy.targets, head_modules=policy.head_modules)
-                self.trainable0 = gspmd.rank_aware_weighted_mean(
-                    stacked0, jnp.ones((len(ranks),), jnp.float32),
-                    lora_lib.rank_mask(ranks))
+                model_cfg, variables = import_pretrained(
+                    cfg.hf_checkpoint, num_labels=self.num_labels,
+                    reinit_classifier=True,
+                )
+                model_cfg = dataclasses.replace(model_cfg, **dtype_overrides)
+                self.model = TextClassifier(model_cfg)
+                self._lora_policy = lora_policy(self.model)
+                # the importer materializes float32; the configured param dtype
+                # must apply to the ARRAYS, not just the config record
+                params = jax.tree.map(
+                    lambda x: x.astype(model_cfg.param_dtype)
+                    if jnp.issubdtype(x.dtype, jnp.floating) else x,
+                    variables["params"])
             else:
-                self.trainable0 = lora_lib.init_lora(
-                    jax.random.fold_in(self.root_key, 3), params,
-                    cfg.lora_rank, **how)
-        else:
-            self.frozen = None
-            self.trainable0 = params
+                from bcfl_tpu.models import build as build_model
+
+                self.model = build_model(
+                    cfg.model, num_labels=self.num_labels,
+                    vocab_size=self.tokenizer.vocab_size,
+                    head="lm" if cfg.task == "causal_lm" else "classifier",
+                    **dtype_overrides,
+                )
+                self._lora_policy = lora_policy(self.model)
+                ids = jnp.ones((2, cfg.seq_len), jnp.int32)
+                key = jax.random.fold_in(self.root_key, 2)
+                init = self.model.init
+                if model_size_gb(
+                        jax.eval_shape(init, key, ids, ids)["params"]) >= 1.0:
+                    # a large base: the draw alone, jitted (init's forward pass
+                    # is dead code there), and born with the steady-state
+                    # sharding, so that pinning it makes no second copy of a
+                    # base that fills half a chip. Smaller ones keep the eager
+                    # draw (its values differ from the jitted one's in the last
+                    # bits, and seeded runs are pinned to them)
+                    init = jax.jit(init, out_shardings=self.mesh.replicated())
+                params = init(key, ids, ids)["params"]
+
+            if cfg.lora_rank > 0:
+                policy = self._lora_policy
+                how = dict(targets=policy.targets,
+                           head_modules=policy.head_modules,
+                           dtype=policy.adapter_dtype, tied=policy.tied)
+                self.frozen = params
+                ranks = cfg.client_lora_ranks
+                if ranks is not None and len(set(ranks)) > 1:
+                    # heterogeneous fleet: each client's adapters initialize AT
+                    # ITS OWN rank (own gaussian/sqrt(r_c) scale), zero-padded
+                    # to the cohort max; the round-0 global is their RBLA mean
+                    # (b starts at zeros everywhere, so the collapse only
+                    # blends the per-rank-normalized 'a' factors)
+                    from bcfl_tpu.parallel import gspmd
+
+                    stacked0 = lora_lib.init_lora_ranks(
+                        jax.random.fold_in(self.root_key, 3), params, ranks,
+                        targets=policy.targets,
+                        head_modules=policy.head_modules)
+                    self.trainable0 = gspmd.rank_aware_weighted_mean(
+                        stacked0, jnp.ones((len(ranks),), jnp.float32),
+                        lora_lib.rank_mask(ranks))
+                else:
+                    self.trainable0 = lora_lib.init_lora(
+                        jax.random.fold_in(self.root_key, 3), params,
+                        cfg.lora_rank, **how)
+            else:
+                self.frozen = None
+                self.trainable0 = params
 
         # --- programs ---
         if cfg.tp > 1:
@@ -431,7 +445,8 @@ class FedEngine:
             # (or None) to the plain programs
             lora_ranks=cfg.client_lora_ranks,
         )
-        self.remat_saved = self._remat_saved()
+        with clock.span("remat_saved"):
+            self.remat_saved = self._remat_saved()
         # per-round rank-collapse guard (arXiv 2602.13486): mean effective
         # rank of the global adapter tree, one tiny separate jit (compiles
         # once — the round programs stay untouched); None when LoRA is off
@@ -458,7 +473,7 @@ class FedEngine:
             warnings.warn(
                 "donate=True has no effect on the async/faithful paths — "
                 "they run only undonated split-phase programs, so peak HBM "
-                "is unchanged", stacklevel=2)
+                "is unchanged", stacklevel=3)
         # Pin the global trees to their steady-state shardings NOW: the round
         # programs return replicated trees, so a single-device-committed
         # trainable0 would make round 2's input sharding differ from round
@@ -512,9 +527,11 @@ class FedEngine:
             self._struct_cache["payload"] = fp_lib.struct_digest(
                 jax.eval_shape(_payload_shape, self.trainable0),
                 cfg.ledger.use_native)
-        self.eval_batches = jax.tree.map(
-            jnp.asarray, central_eval_batches(self.cache, cfg.batch_size,
-                                              max_batches=cfg.max_eval_batches))
+        with clock.span("eval_batches"):
+            self.eval_batches = jax.tree.map(
+                jnp.asarray,
+                central_eval_batches(self.cache, cfg.batch_size,
+                                     max_batches=cfg.max_eval_batches))
         self._static_batches = None  # cache when the partition is round-static
         # the run's StepClock: a fresh one per ``run``; None until then, so
         # the dist runtime's calls into the wire seam open no spans
@@ -1116,6 +1133,8 @@ class FedEngine:
             telemetry.emit("run.start", role="engine", resume=resume,
                            clients=self.C, rounds=cfg.num_rounds,
                            **self.remat_saved)
+            # the construction's spans, at the first run's start only
+            self._setup_clock.emit_held()
         status = "crashed"
         try:
             with trace(cfg.profile_dir):

@@ -1,11 +1,14 @@
 """The round's span tree (OBSERVABILITY.md, "Round spans and device
 scopes"): ``StepClock`` spans with a parent, a start and counts; the
 engine's spans inside ``round_program``, ``ledger`` and ``control_plane``;
-the ``phase`` events' new extras; and the ``fed.*`` named scopes of the round
-programs, which change an operation's metadata and nothing else."""
+the ``phase`` events' new extras; the ``fed.*`` named scopes of the round
+programs, which change an operation's metadata and nothing else; and the
+construction's ``setup`` spans with JAX's compile counts on whatever span is
+open."""
 
 import json
 import os
+import threading
 import time
 
 import numpy as np
@@ -392,3 +395,180 @@ def test_scopes_survive_the_program_cache():
         text = e.progs.server_rounds_static_fp.lower(
             *_fused_args(e)).as_text(debug_info=True)
         assert "transpose(jvp(fed.forward))" in text and "fed.optimizer" in text
+
+
+# --------------------------------------- (e) set-up spans and compile counts
+
+SETUP_CHILDREN = {"data", "model_init", "remat_saved", "eval_batches"}
+COMPILE_KEYS = ("trace_us", "lower_us", "compile_us")
+
+
+def test_setup_spans_reach_the_stream_once(tmp_path):
+    tdir = str(tmp_path / "tel")
+    e = FedEngine(_tiny(num_rounds=2, telemetry_dir=tdir))
+    e.run()
+    e.run()  # a second run sends no construction span again
+    events = [json.loads(x)
+              for x in open(os.path.join(tdir, "events_engine.jsonl"))]
+    starts = [ev for ev in events if ev["ev"] == "run.start"]
+    setup = [ev for ev in events if ev["ev"] == "phase"
+             and (ev["name"] == "setup" or ev["name"].startswith("setup/"))]
+    assert len(starts) == 2
+    names = [ev["name"] for ev in setup]
+    assert sorted(names) == sorted(
+        ["setup"] + ["setup/" + c for c in SETUP_CHILDREN])
+    by = {ev["name"]: ev for ev in setup}
+    assert by["setup"]["parent"] is None
+    assert all(by["setup/" + c]["parent"] == "setup" for c in SETUP_CHILDREN)
+    # their own starts, before the run that sent them; sent right after it
+    first = events.index(starts[0])
+    assert all(events.index(ev) > first for ev in setup)
+    assert all(events.index(ev) < events.index(starts[1]) for ev in setup)
+    for ev in setup:
+        assert ev["t0_ns"] < starts[0]["t_wall"] * 1e9
+        assert ev["t0_ns"] >= by["setup"]["t0_ns"]
+    assert sum(by["setup/" + c]["wall_s"] for c in SETUP_CHILDREN) \
+        <= by["setup"]["wall_s"]
+
+
+@pytest.mark.parametrize("which", ["fused", "per_round"])
+def test_first_enqueue_carries_its_compile(which, request):
+    _, events, _ = request.getfixturevalue(which)
+    enq = [e for e in events if e["ev"] == "phase"
+           and e["name"] == "round_program/enqueue"]
+    first = {}
+    for e in enq:
+        first.setdefault(e["program"], e)
+    for e in first.values():
+        assert all(e.get(k, 0) > 0 for k in COMPILE_KEYS), e
+    later = [e for e in enq if e is not first[e["program"]]]
+    assert later
+    for e in later:
+        assert not any(k in e for k in COMPILE_KEYS + ("cache_misses",)), e
+
+
+def test_compile_counts_go_to_the_innermost_span_on_its_own_thread():
+    clock = StepClock()
+
+    def compile_fresh(scale):
+        jax.jit(lambda x: x * scale + 1.0).lower(
+            jnp.ones((3,), jnp.float32)).compile()
+
+    with clock.phase("round_program"):
+        with clock.span("inputs"):
+            compile_fresh(2.0)
+        with clock.span("wait"):
+            t = threading.Thread(target=compile_fresh, args=(3.0,))
+            t.start()
+            t.join(timeout=120)
+            assert not t.is_alive()
+    compile_fresh(4.0)  # no span open: counted nowhere
+    kids = clock.summary()["round_program"]["children"]
+    assert all(kids["inputs"].get(k, 0) > 0 for k in COMPILE_KEYS)
+    assert not any(k in kids["wait"] for k in COMPILE_KEYS)
+
+
+def test_a_stage_nested_in_another_counts_once():
+    """A jit traced inside another's trace reports its own duration, inside
+    the outer one's: only the outermost is counted. The cache's miss has no
+    start of its own and counts as reported; events with no count of their
+    own (the cache's load time, its hits) add nothing."""
+    trace_ev = "/jax/core/compile/jaxpr_trace_duration"
+    clock = StepClock()
+    with clock.phase("round_program"), clock.span("enqueue") as counts:
+        jax.monitoring.record_scalar(trace_ev, 0.0)  # the outer opens
+        jax.monitoring.record_scalar(trace_ev, 0.0)  # the inner opens
+        jax.monitoring.record_event_duration_secs(trace_ev, 0.25)
+        jax.monitoring.record_event_duration_secs(trace_ev, 1.0)
+        jax.monitoring.record_event_duration_secs(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.5)
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        jax.monitoring.record_event("/jax/some/other_event")
+    assert counts == {"trace_us": 1_000_000, "cache_misses": 1}
+
+
+def test_cache_hit_and_miss_are_counted(tmp_path):
+    """A miss counts ``cache_misses``; a hit counts none and its
+    ``compile_us`` is the key and the load (the cache's own hit event, seen
+    here by a listener of the test's, has no count of its own)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    hits = []
+
+    def on_event(event, **_kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            hits.append(event)
+
+    def first_enqueue():
+        client_step.clear_program_cache()
+        hits.clear()
+        res = FedEngine(_tiny(num_rounds=2, rounds_per_dispatch=2)).run()
+        enq = res.metrics.phases["round_program"]["children"]["enqueue"]
+        return len(hits), {k: enq.get(k, 0)
+                           for k in ("cache_misses", "compile_us")}
+
+    old = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    jax.monitoring.register_event_listener(on_event)
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        cc.reset_cache()
+        jax.clear_caches()
+        miss_hits, miss = first_enqueue()
+        jax.clear_caches()
+        hit_hits, hit = first_enqueue()
+    finally:
+        jax.monitoring.unregister_event_listener(on_event)
+        for k, v in old.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+        client_step.clear_program_cache()
+    assert miss["cache_misses"] == 1 and miss["compile_us"] > 0
+    assert hit_hits > 0 and hit["cache_misses"] == 0 and hit["compile_us"] > 0
+
+
+def test_the_compile_listeners_leave_the_lowered_program_unchanged():
+    """The round program lowered with the compile listeners unregistered,
+    and again with them registered and a span open that takes their
+    counts: the same module. (That the construction's spans leave the
+    program the parent commit's is a comparison across two trees, made
+    outside these tests.)"""
+    from bcfl_tpu.metrics import tracing
+
+    e = _programs()
+    args = _fused_args(e)
+
+    def lower():
+        client_step.clear_program_cache()
+        progs = client_step.build_programs(
+            e.model, e.mesh, optimizer=e.cfg.optimizer,
+            learning_rate=e.cfg.learning_rate,
+            max_grad_norm=e.cfg.max_grad_norm, task=e.cfg.task,
+            prng_impl=e.cfg.resolved_prng_impl, donate=e.cfg.donate)
+        assert progs is not e.progs
+        return progs.server_rounds_static_fp.lower(*args).as_text()
+
+    listeners = (
+        (jax.monitoring.register_scalar_listener,
+         jax.monitoring.unregister_scalar_listener, tracing._on_stage_start),
+        (jax.monitoring.register_event_duration_secs_listener,
+         jax.monitoring.unregister_event_duration_listener,
+         tracing._on_duration),
+        (jax.monitoring.register_event_listener,
+         jax.monitoring.unregister_event_listener, tracing._on_event))
+    for _, unregister, fn in listeners:
+        unregister(fn)
+    try:
+        plain = lower()
+    finally:
+        for register, _, fn in listeners:
+            register(fn)
+    clock = StepClock()
+    with clock.phase("round_program"), clock.span("enqueue") as counts:
+        again = lower()
+    client_step.clear_program_cache()
+    assert counts["lower_us"] > 0
+    assert again == plain
